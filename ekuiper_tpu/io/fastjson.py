@@ -12,15 +12,15 @@ Reference analogue: the schema-aware fastjson converter
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..data.types import DataType, Schema
+from ..utils import nativebuild
 from ..utils.infra import logger
 
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_LIB = "ekjsoncol.so"
+_SOURCES = ("jsoncol.cpp",)
 _lock = threading.Lock()
 _mod = None
 _tried = False
@@ -35,42 +35,27 @@ _FIELD_TYPES = {
 
 
 def _build() -> bool:
-    try:
-        native = os.path.abspath(_NATIVE_DIR)
-        scratch = f"build.tmp.jc.{os.getpid()}"
-        import sys
-
-        subprocess.run(
-            ["make", "-C", native, f"BUILD={scratch}",
-             f"PYTHON={sys.executable}", f"{scratch}/ekjsoncol.so"],
-            capture_output=True, timeout=180, check=True,
-        )
-        os.makedirs(os.path.join(native, "build"), exist_ok=True)
-        os.replace(os.path.join(native, scratch, "ekjsoncol.so"),
-                   os.path.join(native, "build", "ekjsoncol.so"))
-        try:
-            os.rmdir(os.path.join(native, scratch))
-        except OSError:
-            pass
-        return True
-    except Exception as e:
-        logger.warning("ekjsoncol build failed (%s); python decode path", e)
-        return False
+    """False (logged there) leaves ingest on the python decode path."""
+    return nativebuild.build(_LIB, _SOURCES)
 
 
-def ensure_native(background: bool = True) -> None:
-    """Kick off the native build once per process; never blocks ingest."""
+def ensure_native(background: bool = True) -> bool:
+    """Kick off the native build once per process when native/build holds
+    no decoder built from the current sources; never blocks ingest unless
+    `background` is False. Returns whether a current decoder is in place
+    on return."""
     global _build_started
-    so = os.path.abspath(os.path.join(_NATIVE_DIR, "build", "ekjsoncol.so"))
     with _lock:
-        if os.path.exists(so) or _tried or _build_started:
-            return
+        if nativebuild.is_current(_LIB, _SOURCES):
+            return True
+        if _tried or _build_started:
+            return False
         _build_started = True
     if background:
         threading.Thread(target=_build, daemon=True,
                          name="ekjsoncol-build").start()
-    else:
-        _build()
+        return False
+    return _build()
 
 
 def _load():
@@ -78,14 +63,15 @@ def _load():
     with _lock:
         if _tried:
             return _mod
-        so = os.path.abspath(
-            os.path.join(_NATIVE_DIR, "build", "ekjsoncol.so"))
-        if not os.path.exists(so):
-            return None  # keep probing; a background build may land
+        if not nativebuild.is_current(_LIB, _SOURCES):
+            # missing, or built from other sources than the tree holds:
+            # keep probing; a background build may land
+            return None
         try:
             import importlib.util
 
-            spec = importlib.util.spec_from_file_location("ekjsoncol", so)
+            spec = importlib.util.spec_from_file_location(
+                "ekjsoncol", nativebuild.lib_path(_LIB))
             mod = importlib.util.module_from_spec(spec)
             spec.loader.exec_module(mod)
             _mod = mod

@@ -58,26 +58,18 @@ def _default_sampling() -> Dict[str, int]:
 #: 0 disables sampling for that kind (cost capture still runs)
 DEFAULT_SAMPLING = _default_sampling()
 
-#: per-device peak specs for the roofline: f32-class peak FLOP/s (the
-#: engine's folds are f32 elementwise/scatter — for TPUs the bf16 MXU
-#: number is listed because XLA's flop estimate counts MXU-eligible ops
-#: against it), HBM/memory bandwidth, and host→device link bandwidth.
-#: Keyed by a lowercase substring of `jax.devices()[0].device_kind`;
-#: first match wins, unknown kinds report utilization as None. CPU
-#: numbers are order-of-magnitude (CI realism, not marketing).
+#: per-device peak specs for the roofline: peak FLOP/s (the bf16 MXU
+#: number — XLA's flop estimate counts MXU-eligible ops against it), HBM
+#: bandwidth, and host→device link bandwidth. Keyed by a lowercase
+#: substring of `jax.devices()[0].device_kind`; first match wins. Only
+#: devices this repo has run on are listed (TPU v5e: Google Cloud "TPU
+#: v5e" documentation); any other kind — the CPU included — has no spec
+#: and reports utilization as None rather than against an invented peak.
 PEAK_SPECS: Tuple[Tuple[str, Dict[str, float]], ...] = (
     ("v5 lite", {"name": "TPU v5e", "peak_flops": 197e12,
                  "hbm_gbs": 819.0, "h2d_gbs": 32.0}),
     ("v5e", {"name": "TPU v5e", "peak_flops": 197e12,
              "hbm_gbs": 819.0, "h2d_gbs": 32.0}),
-    ("v5p", {"name": "TPU v5p", "peak_flops": 459e12,
-             "hbm_gbs": 2765.0, "h2d_gbs": 32.0}),
-    ("v4", {"name": "TPU v4", "peak_flops": 275e12,
-            "hbm_gbs": 1228.0, "h2d_gbs": 32.0}),
-    ("v3", {"name": "TPU v3", "peak_flops": 123e12,
-            "hbm_gbs": 900.0, "h2d_gbs": 16.0}),
-    ("cpu", {"name": "host CPU", "peak_flops": 200e9,
-             "hbm_gbs": 20.0, "h2d_gbs": 10.0}),
 )
 
 _device_spec_cache: List[Optional[Dict[str, Any]]] = []  # [(kind, spec)]

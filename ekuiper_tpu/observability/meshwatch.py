@@ -39,17 +39,16 @@ import numpy as np
 
 from ..utils import timex
 
-# ICI / interconnect bandwidth class per chip generation, GB/s per link
-# direction — order-of-magnitude figures for the attribution estimate,
+# Chip-to-chip interconnect bandwidth, GB/s, for the attribution estimate,
 # matched by lowercase substring against kernwatch.device_spec()["kind"].
-# The CPU row prices host-emulated "collectives" (memcpy class) so the
-# 8-virtual-device CI meshes produce a nonzero, stable split.
+# Only the installed device is listed (TPU v5e: 1,600 Gbit/s, Google Cloud
+# "TPU v5e" documentation); an unknown kind gets no estimate. The CPU row
+# is not a device figure: it prices host-emulated "collectives" (memcpy
+# class) so the 8-virtual-device CI meshes produce a nonzero, stable split
+# for the tier-1 observatory tests.
 MESH_LINK_GBS: Tuple[Tuple[str, float], ...] = (
-    ("v5p", 600.0),
+    ("v5 lite", 200.0),
     ("v5e", 200.0),
-    ("v4", 300.0),
-    ("v3", 140.0),
-    ("tpu", 200.0),
     ("cpu", 8.0),
 )
 
@@ -190,7 +189,7 @@ class MeshWatch:
         for sub, gbs in MESH_LINK_GBS:
             if sub in kind:
                 return gbs
-        return MESH_LINK_GBS[-1][1]
+        return 0.0  # unknown device: no collective estimate
 
     def collective_split(self) -> Dict[Tuple[str, str], Dict[str, Any]]:
         """Collective-vs-compute estimate for every sampled `sharded.*`

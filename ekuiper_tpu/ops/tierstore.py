@@ -640,10 +640,7 @@ class TierManager:
                 cs = slots[start:start + D]
                 s = np.asarray(cs, dtype=np.int32)
                 state, packed_dev = self.ts.demote(state, s)
-                try:
-                    packed_dev.copy_to_host_async()
-                except AttributeError:
-                    pass
+                packed_dev.copy_to_host_async()
                 self.kt.retire(cs, ck)
                 self.demoted_total += len(ck)
                 with self._mu:
@@ -664,10 +661,7 @@ class TierManager:
             # delete the leaf out from under the worker's fetch — the
             # same class as bench.py's _block_marker slice
             touch_dev = state["touch"] + jnp.uint32(0)
-            try:
-                touch_dev.copy_to_host_async()
-            except AttributeError:
-                pass
+            touch_dev.copy_to_host_async()
             self._dispatch(("scan", touch_dev, self.kt.n_keys,
                             list(self.kt.free_slots()), now))
         return state

@@ -40,44 +40,25 @@ def make_mesh(
 
 
 def ensure_devices(n: int):
-    """Return at least n jax devices, provisioning virtual CPU devices when
-    the host has fewer physical chips.
+    """Return the first n devices of the default platform, or raise.
 
-    Order of preference: real devices of the default platform; an existing
-    CPU backend with >= n devices; a fresh CPU backend forced to n devices
-    via the jax_num_cpu_devices config (only possible before the CPU
-    backend initializes — tests/conftest.py and the dryrun subprocess set
-    JAX_PLATFORMS=cpu + --xla_force_host_platform_device_count up front).
-
-    This function NEVER resets initialized backends: a running rule's
-    state lives on those backends, and clearing them invalidates every
-    live device array process-wide (it also broke the driver dryrun twice
-    — a cleared TPU client re-initialized into a libtpu version mismatch).
-    Callers that need an n-device mesh the current process cannot provide
-    must run in a fresh subprocess instead (see __graft_entry__.
-    dryrun_multichip)."""
+    Never another platform's: a mesh asked for on a TPU host with fewer
+    chips than the geometry needs must fail, not run quietly on host CPU
+    devices. More CPU devices than the process started with can only come
+    from a fresh process configured up front (tests/conftest.py sets
+    JAX_PLATFORMS=cpu + --xla_force_host_platform_device_count) — this
+    function never resets initialized backends: a running rule's state
+    lives on them."""
     import jax
 
     if n < 1:
         raise ValueError(f"need a positive device count, got {n}")
     devs = jax.devices()
-    if len(devs) >= n:
-        return devs[:n]
-    try:
-        cpus = jax.devices("cpu")
-        if len(cpus) >= n:
-            return cpus[:n]
-    except RuntimeError:
-        pass
-    # the probes above initialized the backends, so the CPU device count is
-    # locked in for this process — more devices can only come from a fresh
-    # process configured up front
-    raise RuntimeError(
-        f"host has {len(devs)} devices and the jax backend is already "
-        f"initialized; cannot provision {n} virtual CPU devices in-process "
-        f"— run in a subprocess with JAX_PLATFORMS=cpu and "
-        f"--xla_force_host_platform_device_count={n}"
-    )
+    if len(devs) < n:
+        raise RuntimeError(
+            f"{n} devices asked for, the default platform "
+            f"({devs[0].platform}) has {len(devs)}")
+    return devs[:n]
 
 
 def mesh_cfg_from_env() -> Optional[Dict[str, Any]]:

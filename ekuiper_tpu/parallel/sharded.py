@@ -210,10 +210,7 @@ class ShardedGroupBy(DeviceGroupBy):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         comp_specs = self.comp_specs
         plan = self.plan
@@ -359,10 +356,7 @@ class ShardedGroupBy(DeviceGroupBy):
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax import shard_map
-        except ImportError:  # older jax
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         comp_specs = self.comp_specs
         plan = self.plan

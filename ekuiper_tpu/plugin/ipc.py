@@ -21,11 +21,11 @@ import ctypes
 import os
 import socket as pysocket
 import struct
-import subprocess
 import threading
 import time
 from typing import List, Optional, Tuple
 
+from ..utils import nativebuild
 from ..utils.infra import logger
 
 PAIR, PUSH, PULL = 0, 1, 2
@@ -42,7 +42,8 @@ class IpcClosed(Exception):
 
 
 # --------------------------------------------------------------------- native
-_NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_LIB = "libekipc.so"
+_SOURCES = ("ekipc.cpp",)
 _lib = None
 _lib_tried = False
 _lib_lock = threading.Lock()
@@ -52,24 +53,10 @@ _build_started = False
 
 
 def _build_native() -> bool:
-    """Compile libekipc.so into a scratch dir, then atomically install it so
-    _load_native never CDLLs a half-written file. Runs in a background thread
-    via ensure_native, never on a request path."""
-    try:
-        native = os.path.abspath(_NATIVE_DIR)
-        scratch = f"build.tmp.{os.getpid()}"
-        subprocess.run(
-            ["make", "-C", native, f"BUILD={scratch}"],
-            capture_output=True, timeout=120, check=True,
-        )
-        os.makedirs(os.path.join(native, "build"), exist_ok=True)
-        os.replace(os.path.join(native, scratch, "libekipc.so"),
-                   os.path.join(native, "build", "libekipc.so"))
-        os.rmdir(os.path.join(native, scratch))
-        return True
-    except Exception as e:  # toolchain unavailable — fall back
-        logger.warning("ekipc native build failed (%s); using pure-python ipc", e)
-        return False
+    """Compile and install libekipc.so (utils/nativebuild.py). Runs in a
+    background thread via ensure_native, never on a request path. False
+    (toolchain unavailable, logged there) leaves the pure-python ipc."""
+    return nativebuild.build(_LIB, _SOURCES, timeout=120)
 
 
 def ensure_native(background: bool = True) -> None:
@@ -77,9 +64,9 @@ def ensure_native(background: bool = True) -> None:
     the first plugin request never blocks on the compiler. Idempotent: only
     one build is ever started per process."""
     global _build_started
-    so = os.path.abspath(os.path.join(_NATIVE_DIR, "build", "libekipc.so"))
     with _lib_lock:
-        if os.path.exists(so) or _lib_tried or _build_started:
+        if (nativebuild.is_current(_LIB, _SOURCES) or _lib_tried
+                or _build_started):
             return
         _build_started = True
     if background:
@@ -94,10 +81,11 @@ def _load_native() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib_tried:
             return _lib
-        so = os.path.abspath(os.path.join(_NATIVE_DIR, "build", "libekipc.so"))
-        if not os.path.exists(so):
-            # not built yet: use the pure fallback for now, but keep probing —
-            # a background ensure_native build may finish later
+        so = nativebuild.lib_path(_LIB)
+        if not nativebuild.is_current(_LIB, _SOURCES):
+            # not built from the current sources (yet): use the pure
+            # fallback for now, but keep probing — a background
+            # ensure_native build may finish later
             return None
         _lib_tried = True
         try:

@@ -323,10 +323,17 @@ class _AotJit:
                     or meta.get("op") != self.rec.op
                     or meta.get("signature") != sig):
                 raise ValueError("cache entry metadata mismatch")
+            import jax
             from jax.experimental import serialize_executable
 
+            # load onto the devices the executable was compiled for: the
+            # loader's default is EVERY device of the backend, and a
+            # one-chip program loaded across a 4- or 8-device host then
+            # refuses its own arguments ("expected N shards")
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                blob["payload"], blob["in_tree"], blob["out_tree"])
+                blob["payload"], blob["in_tree"], blob["out_tree"],
+                execution_devices=[by_id[i] for i in meta["device_ids"]])
         except Exception:
             try:
                 os.unlink(path)
@@ -358,6 +365,9 @@ class _AotJit:
                 "meta": {
                     "op": self.rec.op, "signature": sig,
                     "fingerprint": fingerprint(),
+                    "device_ids": [
+                        d.id for d in
+                        compiled.runtime_executable().local_devices()],
                     "compile_s": round(compile_s, 4), "cost": cost,
                 },
             }

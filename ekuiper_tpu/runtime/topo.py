@@ -196,6 +196,13 @@ class Topo:
             for name, sm in subtopo.status().items():
                 stats.setdefault(name, sm)
         out = flatten_status(stats)
+        # which path answered each emitted window, cumulatively (fused
+        # window nodes): device fetch / host backstop / sync finalize
+        for n in self.all_nodes():
+            srcs = getattr(n, "emit_sources", None)
+            if srcs:
+                out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
+                    "_emit_sources"] = dict(srcs)
         # rule-level SLO summary: the ingest→emit distribution percentiles
         out["e2e_latency_ms"] = self.e2e_hist.snapshot()
         # engine-health views (observability/devwatch.py): per-op XLA

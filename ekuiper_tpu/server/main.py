@@ -25,6 +25,10 @@ def start_up(config_path: str | None = None, block: bool = True):
         level=getattr(logging, cfg.basic.log_level.upper(), logging.INFO),
         format="%(asctime)s %(levelname)s %(name)s: %(message)s",
     )
+    # before anything touches jax: where compiled programs persist
+    from ..utils import jaxcache
+
+    logger.info("jax compilation cache at %s", jaxcache.setup())
     if cfg.cluster.enabled:
         # validate BEFORE the (blocking) init — a half-filled cluster
         # section must fail loudly, not hang a silent boot

@@ -133,14 +133,14 @@ def test_block_marker_tolerates_donation_only():
 
     bench._block_marker(DonationRace())  # the benign race class
 
-    class TunnelFault:
+    class LinkFault:
         def is_deleted(self):
             raise RuntimeError("socket closed")
 
     import pytest
 
     with pytest.raises(RuntimeError, match="socket closed"):
-        bench._block_marker(TunnelFault())
+        bench._block_marker(LinkFault())
 
 
 # -------------------------------------- child watchdog dump harvest (r05)

@@ -182,6 +182,31 @@ class TestShardedGroupBy:
         devs = ensure_devices(8)
         assert len(devs) == 8
 
+    def test_ensure_devices_never_borrows_another_platform(
+            self, eight_devices, monkeypatch):
+        """More devices than the default platform has is an error — on a
+        TPU host that used to hand back the host's CPU devices, i.e. a
+        sharded plan quietly running off the chips."""
+        import jax
+
+        with pytest.raises(RuntimeError, match="9 devices asked"):
+            ensure_devices(9)
+
+        class Chip:
+            platform = "tpu"
+
+        asked = []
+
+        def devices(backend=None):
+            asked.append(backend)
+            return [Chip()]  # one chip; the CPU backend is never consulted
+
+        monkeypatch.setattr(jax, "devices", devices)
+        with pytest.raises(RuntimeError, match="tpu"):
+            ensure_devices(4)
+        assert asked == [None]
+        assert ensure_devices(1)[0].platform == "tpu"
+
 
 class TestPlannerMeshIntegration:
     """A real rule with planOptimizeStrategy.mesh runs sharded end-to-end

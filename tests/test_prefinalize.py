@@ -256,7 +256,7 @@ def _node_bits():
                           "temp": rng.normal(20, 5, n).astype(np.float32)},
             timestamps=np.zeros(n, dtype=np.int64), emitter="s")
 
-    def mknode(prefinalize, tail_mode="device"):
+    def mknode(prefinalize, tail_mode="device", backstop=True):
         plan = extract_kernel_plan(stmt)
         node = FusedWindowAggNode(
             "t", stmt.window, plan,
@@ -264,7 +264,7 @@ def _node_bits():
             micro_batch=32,
             direct_emit=build_direct_emit(stmt, plan, ["deviceId"]),
             prefinalize_lead_ms=250 if prefinalize else 0,
-            tail_mode=tail_mode,
+            tail_mode=tail_mode, prefinalize_backstop=backstop,
         )
         node.state = node.gb.init_state()
         got = []
@@ -340,6 +340,108 @@ class TestNodePrefinalize:
         assert len(got) == len(sync_got) == 4
         for a, b in zip(got, sync_got):
             assert _flat([a]) == _flat([b])
+
+    def test_emit_sources_add_up_over_boundaries(self):
+        """The cumulative twin of last_emit_info: one count per emitted
+        window by the path that answered it, kept after the next boundary
+        overwrites the per-boundary record, and shown in the rule status."""
+        import time
+
+        from ekuiper_tpu.ops.prefinalize import IdentityFinalize
+        from ekuiper_tpu.runtime.events import PreTrigger, Trigger
+        from ekuiper_tpu.runtime.topo import Topo
+
+        _, mkbatch, mknode = _node_bits()
+        node, got = mknode(True, "device")
+        topo = Topo("r_emit")
+        topo.add_op(node)
+        assert node.emit_sources == {}
+        # pre-issue at boundaries 1 and 3 only; boundary 2 finds nothing
+        # but the identity entry and is answered by the host backstop
+        for w, pre_issue in enumerate([True, False, True]):
+            end = 10_000 * (w + 1)
+            node.process(mkbatch(40))
+            if pre_issue:
+                node.on_pre_trigger(PreTrigger(ts=end))
+                real = [p for p, _ in node._pipeline
+                        if not isinstance(p, IdentityFinalize)]
+                deadline = time.time() + 10
+                while not real[0].ready() and time.time() < deadline:
+                    time.sleep(0.005)
+                assert real[0].ready()
+            node.process(mkbatch(40))
+            node.on_trigger(Trigger(ts=end))
+        node._drain_async_emits()
+        assert len(got) == 3
+        assert node.emit_sources == {"device": 2, "backstop": 1}
+        assert node.last_emit_info["source"] == "device"  # the newest only
+        assert topo.status()["op_t_0_emit_sources"] == {
+            "device": 2, "backstop": 1}
+
+    def test_no_backstop_boundary_waits_for_the_device(self):
+        """prefinalize_backstop=False (what the planner builds): a boundary
+        whose fetch has not landed is delivered by the emit worker from the
+        device snapshot, never from the host shadow alone, and the windows
+        equal a sync node's."""
+        from ekuiper_tpu.ops.prefinalize import PendingFinalize
+        from ekuiper_tpu.runtime.events import PreTrigger, Trigger
+
+        _, mkbatch, mknode = _node_bits()
+        batches = [mkbatch(40) for _ in range(6)]
+        node, got = mknode(True, "device", backstop=False)
+        sync_node, sync_got = mknode(False, "device")
+
+        class LandsLate(PendingFinalize):
+            def ready(self):
+                return False
+
+        orig = node.gb.prefinalize_begin
+        node.gb.prefinalize_begin = lambda state, panes=None: LandsLate(
+            orig(state, panes).stacked, node.gb.capacity,
+            node.gb._components_layout())
+        # boundary 2 has no pre-issue at all; 1 and 3 have one in flight
+        for w, pre_issue in enumerate([True, False, True]):
+            end = 10_000 * (w + 1)
+            for n in (node, sync_node):
+                n.process(batches[2 * w])
+            if pre_issue:
+                node.on_pre_trigger(PreTrigger(ts=end))
+            for n in (node, sync_node):
+                n.process(batches[2 * w + 1])
+                n.on_trigger(Trigger(ts=end))
+            assert len(node._pipeline) == 0  # no identity entry re-armed
+        node._drain_async_emits()
+        sync_node._drain_async_emits()
+        assert node.emit_sources == {"device-async-late": 2,
+                                     "device-async": 1}
+        assert len(got) == len(sync_got) == 3
+        for a, b in zip(got, sync_got):
+            assert _flat([a]) == _flat([b])
+
+    def test_planner_builds_the_node_without_backstop(self):
+        """Through the normal entry point a tumbling boundary is answered
+        by the device: the planner passes prefinalize_backstop=False."""
+        from ekuiper_tpu.planner.planner import RuleDef, plan_rule
+        from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
+        from ekuiper_tpu.server.processors import StreamProcessor
+        from ekuiper_tpu.store import kv
+        from ekuiper_tpu.utils.infra import PlanError
+
+        store = kv.get_store()
+        try:
+            StreamProcessor(store).exec_stmt(
+                'CREATE STREAM pf_s (deviceId STRING, temp FLOAT) WITH '
+                '(DATASOURCE="pf/in", TYPE="memory", FORMAT="JSON")')
+        except PlanError:
+            pass
+        rule = RuleDef(
+            id="pf_r", sql="SELECT deviceId, count(*) AS c FROM pf_s "
+            "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)",
+            actions=[{"nop": {}}], options={"sharedFold": False})
+        topo = plan_rule(rule, store)
+        fused = next(n for n in topo.ops
+                     if isinstance(n, FusedWindowAggNode))
+        assert fused._backstop_ok and not fused._backstop
 
     def test_inflight_fetch_cap(self):
         """No more than two un-landed device fetches may stack: each is a

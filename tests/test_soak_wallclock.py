@@ -2,7 +2,7 @@
 with REAL time — continuous file-source traffic, short checkpoint
 intervals, repeated kill/restore cycles, and a flapping sink buffered by
 the CacheNode — asserting the at-least-once contract (no loss) and
-bounded memory. Marked slow; run summary documented in docs/PERF_NOTES.md.
+bounded memory. Marked slow.
 """
 import json
 import os

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """benchdiff — the bench-trajectory regression gate.
 
-Compares two or more driver bench artifacts (`BENCH_r*.json`)
+Compares two or more driver bench artifacts
 metric-by-metric: the LAST file is the candidate round, the metric
 baseline is the most recent EARLIER round carrying that metric (phases
 come and go across rounds; a metric new in the candidate has no baseline
@@ -9,9 +9,9 @@ and is reported as such, never gated). Each artifact is the driver's
 record: `{n, cmd, rc, tail, parsed}` where `parsed` is bench.py's final
 stdout JSON line (`{metric, value, unit, vs_baseline, phases: {...}}`).
 
-Why this exists: BENCH_r05 came back `rc=124, parsed: null` and nothing
+Why this exists: a round once came back `rc=124, parsed: null` and nothing
 noticed — the perf trajectory was blind, so no PR could prove it didn't
-regress the 2.8M rows/s headline. This gate makes two failure classes
+regress the headline. This gate makes two failure classes
 loud and machine-checkable:
 
 - a candidate round that FAILED to produce an artifact (`parsed` null /
@@ -25,8 +25,8 @@ the report, but only headline metrics gate (phase metrics on a shared CI
 box are noisy; the gate must not cry wolf).
 
 Usage:
-  python tools/benchdiff.py BENCH_r04.json BENCH_r06.json
-  python tools/benchdiff.py BENCH_r0*.json          # trajectory view
+  python tools/benchdiff.py OLD.json NEW.json
+  python tools/benchdiff.py ROUND_*.json            # trajectory view
   python tools/benchdiff.py --tolerance 0.15 A.json B.json
   python tools/benchdiff.py --smoke                 # tier-1 self-test
 
@@ -338,7 +338,7 @@ def smoke() -> int:
 def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("artifacts", nargs="*",
-                    help="BENCH_r*.json driver artifacts, oldest first; "
+                    help="driver bench artifacts (JSON), oldest first; "
                          "the last is the candidate")
     ap.add_argument("--tolerance", type=float, default=DEFAULT_TOLERANCE,
                     help="noise tolerance for non-headline metrics "

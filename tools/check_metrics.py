@@ -183,6 +183,12 @@ def _synthetic_scrape() -> str:
     # utilization) render samples
     watch.kern.set_cost(flops=2e6, bytes_=1.12e7)
     watch.kern.record_sample(dispatch_us=50.0, total_us=850.0)
+    # the roofline family renders only against a device peak spec, and
+    # the CPU this lint runs on has none — pin a synthetic device for the
+    # synthetic site (kernwatch.reset() below drops it)
+    kernwatch._device_spec_cache[:] = [{"kind": "lintdev", "spec": {
+        "name": "lint", "peak_flops": 1e12, "hbm_gbs": 100.0,
+        "h2d_gbs": 10.0}}]
 
     class MemOwner:
         pass

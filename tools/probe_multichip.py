@@ -4,7 +4,7 @@
 
 Runs the full-pipe parity check on an 8-virtual-device CPU mesh (the
 same `--xla_force_host_platform_device_count` recipe as
-tests/conftest.py and __graft_entry__.dryrun_multichip) and asserts:
+tests/conftest.py) and asserts:
 
   1. planner selection: `shards=auto` under KUIPER_MESH plans the rule
      onto the sharded kernel, and explain() carries the "shards"
