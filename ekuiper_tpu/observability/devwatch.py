@@ -75,6 +75,10 @@ class OpWatch:
         from . import kernwatch
 
         self.op = op
+        # name of the host span a profiler capture shows around each
+        # dispatch of this site: the site's own name without the kernel
+        # family prefix (`groupby.fold` -> `kuiper:jit:fold`)
+        self.trace_name = "kuiper:jit:" + op.rsplit(".", 1)[-1]
         self.rule = rule  # attributed lazily from the rule thread context
         self.calls = 0
         self.traces = 0
@@ -99,6 +103,15 @@ class OpWatch:
             pass  # interpreter teardown: registry may already be gone
 
     # ------------------------------------------------------------- recording
+    def annotate(self):
+        """Context manager for ONE dispatch of this site: a
+        `kuiper:jit:<site>` event on the profiler's host plane (nothing
+        without a profiler session), so every device program in a capture
+        has the host span that enqueued it."""
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(self.trace_name)
+
     def on_compile(self, us: float, args: tuple, kwargs: dict) -> None:
         if self.rule is None:
             # attribution rides the compile path only (compiles are rare;
@@ -189,7 +202,8 @@ class _WatchedJit:
         kern = rec.kern
         sampled = kern.tick()
         t0 = _time.perf_counter()
-        out = self._jitted(*args, **kwargs)
+        with rec.annotate():
+            out = self._jitted(*args, **kwargs)
         t1 = _time.perf_counter()
         rec.calls += 1
         compiled = rec._trace_pending

@@ -85,9 +85,10 @@ STAGES = ("decode", "upload", "fold", "emit_combine", "sink",
           "host_expr", "shard_skew", "other")
 
 #: node-local stage labels → canonical taxonomy
-_STAGE_CANON = {"decode": "decode", "ring": "decode",
+_STAGE_CANON = {"decode": "decode", "ring": "decode", "ingest": "decode",
                 "upload": "upload", "prep": "upload",
-                "fold": "fold", "host_expr": "host_expr"}
+                "fold": "fold", "host_expr": "host_expr",
+                "emit": "emit_combine", "sink": "sink"}
 
 #: classes whose UNSTAGED busy time is boundary work (finalize + window
 #: combine + emission) rather than row processing

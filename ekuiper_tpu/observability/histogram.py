@@ -256,17 +256,32 @@ E2E_BOUNDS_MS = (1, 2, 5, 10, 25, 50, 100, 250, 500,
                  1000, 2500, 5000, 10000, 30000, 60000)
 
 
+#: `le` ladder (µs) of the per-boundary phase histogram, rendered in ms:
+#: 0.1 ms (a timer's lateness) to 60 s (a million-group sink conversion),
+#: six steps a decade so a quantile read off the buckets is at most a
+#: step's width off
+BOUNDARY_BOUNDS_US = tuple(
+    int(m * 10 ** e) for e in range(2, 7)
+    for m in (1, 1.5, 2, 3, 5, 7.5)) + (
+    10_000_000, 15_000_000, 20_000_000, 30_000_000, 60_000_000)
+
+
 def render_prom_histogram(out: List[str], name: str, labels: str,
                           hist: Optional[LatencyHistogram],
-                          bounds: Sequence[int] = E2E_BOUNDS_MS) -> None:
+                          bounds: Sequence[int] = E2E_BOUNDS_MS,
+                          scale: int = 1) -> None:
     """Append `{name}_bucket/_sum/_count` exposition lines for one labeled
-    histogram (labels = pre-escaped `key="value"` pairs, no braces)."""
+    histogram (labels = pre-escaped `key="value"` pairs, no braces).
+    `scale` divides bounds and sum on the way out: a histogram recorded in
+    microseconds renders in milliseconds with `scale=1000`."""
     if hist is None:
         return
     sep = "," if labels else ""
     cum, count, total = hist.export(bounds)
     for b, c in zip(bounds, cum):
-        out.append(f'{name}_bucket{{{labels}{sep}le="{b}"}} {c}')
+        le = b if scale == 1 else b / scale
+        out.append(f'{name}_bucket{{{labels}{sep}le="{le:g}"}} {c}')
     out.append(f'{name}_bucket{{{labels}{sep}le="+Inf"}} {count}')
-    out.append(f"{name}_sum{{{labels}}} {total}")
+    out.append(f"{name}_sum{{{labels}}} "
+               f"{total if scale == 1 else total / scale}")
     out.append(f"{name}_count{{{labels}}} {count}")

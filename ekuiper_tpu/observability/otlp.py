@@ -99,13 +99,15 @@ def encode_span(span) -> bytes:
     """observability.tracer.Span -> opentelemetry.proto.trace.v1.Span bytes.
     Field numbers: trace_id=1, span_id=2, parent_span_id=4, name=5, kind=6,
     start_time_unix_nano=7, end_time_unix_nano=8, attributes=9."""
-    start_ns = span.start_ms * 1_000_000
+    start_ns = span.start_ns
     end_ns = start_ns + span.duration_us * 1_000
+    stage = getattr(span, "stage", "")
     out = _ld(1, _trace_id_bytes(span.trace_id))
     out += _ld(2, _span_id_bytes(span.span_id))
     if span.parent_id:
         out += _ld(4, _span_id_bytes(span.parent_id))
-    out += _str(5, f"{span.rule_id}/{span.op}")
+    out += _str(5, f"{span.rule_id}/{span.op}" + (f":{stage}" if stage
+                                                  else ""))
     out += _vint(6, _KIND_INTERNAL)
     out += _u64(7, start_ns)
     out += _u64(8, end_ns)

@@ -18,7 +18,11 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, List, Tuple
 
-from .histogram import E2E_BOUNDS_MS, render_prom_histogram
+from .histogram import (
+    BOUNDARY_BOUNDS_US,
+    E2E_BOUNDS_MS,
+    render_prom_histogram,
+)
 
 _STATE_VALUES = {"running": 1, "stopped": 0}
 
@@ -29,6 +33,10 @@ _COUNTERS = (
     ("records_in_total", "items received by the op"),
     ("records_out_total", "items emitted by the op"),
     ("exceptions_total", "per-item errors swallowed by the op"),
+    ("idle_us_total", "time the op's worker waited in its empty input "
+     "queue: starved (us)"),
+    ("backpressure_us_total", "time senders waited for room in the op's "
+     "full input queue, counted on their threads: blocked (us)"),
 )
 _GAUGES = (
     ("buffer_length", "input queue occupancy"),
@@ -36,6 +44,9 @@ _GAUGES = (
 )
 _STAGES = (
     ("stage_us_total", "total_us", "cumulative wall time per pipeline stage"),
+    ("stage_cpu_us_total", "cpu_us",
+     "cumulative thread-CPU time per pipeline stage: wall minus this is "
+     "time the stage was open with its thread off the core"),
     ("stage_calls_total", "calls", "invocations per pipeline stage"),
     ("stage_rows_total", "rows", "rows handled per pipeline stage"),
 )
@@ -70,6 +81,7 @@ def render(rule_registry) -> str:
     rows: List[Tuple[str, Any]] = []
     shared_nodes: Dict[int, Any] = {}  # id(node) -> node, emitted ONCE
     e2e_rows: List[Tuple[str, Any]] = []  # (rule_id, LatencyHistogram)
+    boundary_rows: List[Tuple[str, Dict[str, Any]]] = []  # by phase
     for entry in rule_registry.list():
         rule_id = entry["id"]
         out.append(
@@ -84,6 +96,7 @@ def render(rule_registry) -> str:
                 for node in subtopo.nodes:
                     shared_nodes.setdefault(id(node), node)
             e2e_rows.append((rule_id, topo.e2e_hist))
+            boundary_rows.append((rule_id, topo.boundary_hists))
     rows.extend((SHARED_RULE_LABEL, node) for node in shared_nodes.values())
     snaps = [(rule_id, node, node.stats.snapshot()) for rule_id, node in rows]
 
@@ -211,6 +224,18 @@ def render(rule_registry) -> str:
         render_prom_histogram(
             out, "kuiper_rule_e2e_latency_ms", f'rule="{_esc(rule_id)}"',
             hist, E2E_BOUNDS_MS)
+    # ... and the engine's side of it per window boundary, by phase: timer
+    # lateness + queue (trigger_delay), finalize/fetch/merge (emit), sink
+    # queue + convert + deliver (sink) — fed by the boundary's own spans
+    _family(out, "kuiper_boundary_ms", "histogram",
+            "engine time per window boundary by phase: trigger_delay, "
+            "emit, sink (ms)")
+    for rule_id, hists in boundary_rows:
+        for phase, hist in hists.items():
+            render_prom_histogram(
+                out, "kuiper_boundary_ms",
+                f'rule="{_esc(rule_id)}",phase="{phase}"', hist,
+                BOUNDARY_BOUNDS_US, scale=1000)
     # engine-health planes (devwatch: XLA trace-vs-hit accounting;
     # kernwatch: sampled device time + roofline; memwatch: per-component
     # device/host byte probes) — module-global registries, so they render

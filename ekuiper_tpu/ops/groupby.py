@@ -345,9 +345,14 @@ class DeviceGroupBy:
         return state
 
     def _fold_impl(self, state, cols, slots, n_valid, pane_idx):
+        import jax
         import jax.numpy as jnp
 
-        base = jnp.arange(self.micro_batch, dtype=jnp.int32) < n_valid
+        # kuiper/<kernel>/<section> scopes name the ops in a device trace
+        # past the compiler's fusion numbering; the PROGRAM names
+        # (jit__fold_impl, ...) are what the benchmark matches, and stay
+        with jax.named_scope("kuiper/fold/pad"):
+            base = jnp.arange(self.micro_batch, dtype=jnp.int32) < n_valid
         return self._fold_core(state, cols, slots, base, pane_idx)
 
     def _fold_masked_impl(self, state, cols, slots, mask, pane_idx):
@@ -365,79 +370,91 @@ class DeviceGroupBy:
                             jnp.asarray(pane_idx, dtype=jnp.int32))
 
     def _fold_core(self, state, cols, slots, base, pane_idx):
+        import jax
         import jax.numpy as jnp
 
-        slots = slots.astype(jnp.int32)
-        pane_idx = pane_idx.astype(jnp.int32)  # scalar or per-row vector
-        if self.plan.filter is not None:
-            base = jnp.logical_and(base, self.plan.filter(cols))
+        with jax.named_scope("kuiper/fold/slot_prep"):
+            slots = slots.astype(jnp.int32)
+            pane_idx = pane_idx.astype(jnp.int32)  # scalar or per-row vector
+            if self.plan.filter is not None:
+                base = jnp.logical_and(base, self.plan.filter(cols))
         # per-column validity composes into per-spec masks below
-        state["act"] = state["act"].at[pane_idx, slots].add(
-            base.astype(jnp.float32)
-        )
-        if "touch" in state:
-            # tier placement signal (ops/tierstore.py): per-slot touched-
-            # row count, cumulative — the policy worker diffs successive
-            # async fetches for recency/frequency, so the fold itself
-            # never syncs
-            state["touch"] = state["touch"].at[slots].add(
-                base.astype(jnp.uint32))
+        with jax.named_scope("kuiper/fold/scatter_act"):
+            state["act"] = state["act"].at[pane_idx, slots].add(
+                base.astype(jnp.float32)
+            )
+            if "touch" in state:
+                # tier placement signal (ops/tierstore.py): per-slot touched-
+                # row count, cumulative — the policy worker diffs successive
+                # async fetches for recency/frequency, so the fold itself
+                # never syncs
+                state["touch"] = state["touch"].at[slots].add(
+                    base.astype(jnp.uint32))
         per_spec: List[Tuple[Any, Any]] = []
-        for spec in self.plan.specs:
-            if spec.arg is None:
-                v = jnp.ones_like(base, dtype=jnp.float32)
-                m = base
-            else:
-                v = spec.arg(cols).astype(jnp.float32)
-                m = base
-                for col in spec.arg.columns:
-                    vm = cols.get("__valid_" + col)
-                    if vm is not None:
-                        m = jnp.logical_and(m, vm)
-                m = jnp.logical_and(m, jnp.logical_not(jnp.isnan(v)))
-            if spec.filter is not None:
-                m = jnp.logical_and(m, spec.filter(cols))
-            per_spec.append((v, m))
+        with jax.named_scope("kuiper/fold/values"):
+            for spec in self.plan.specs:
+                if spec.arg is None:
+                    v = jnp.ones_like(base, dtype=jnp.float32)
+                    m = base
+                else:
+                    v = spec.arg(cols).astype(jnp.float32)
+                    m = base
+                    for col in spec.arg.columns:
+                        vm = cols.get("__valid_" + col)
+                        if vm is not None:
+                            m = jnp.logical_and(m, vm)
+                    m = jnp.logical_and(m, jnp.logical_not(jnp.isnan(v)))
+                if spec.filter is not None:
+                    m = jnp.logical_and(m, spec.filter(cols))
+                per_spec.append((v, m))
         for comp, spec_idxs in self.comp_specs.items():
-            arr = state[comp]
-            for k, si in enumerate(spec_idxs):
-                v, m = per_spec[si]
-                mf = m.astype(jnp.float32)
-                if comp == "n":
-                    arr = arr.at[pane_idx, slots, k].add(mf)
-                elif comp == "s1":
-                    arr = arr.at[pane_idx, slots, k].add(jnp.where(m, v, 0.0))
-                elif comp == "s2":
-                    arr = arr.at[pane_idx, slots, k].add(jnp.where(m, v * v, 0.0))
-                elif comp == "mn":
-                    arr = arr.at[pane_idx, slots, k].min(
-                        jnp.where(m, v, jnp.inf)
-                    )
-                elif comp == "mx":
-                    arr = arr.at[pane_idx, slots, k].max(
-                        jnp.where(m, v, -jnp.inf)
-                    )
-                elif comp == "hll":
-                    from .sketches import hll_parts
-
-                    reg, rho = hll_parts(v)
-                    arr = arr.at[pane_idx, slots, k, reg].max(
-                        jnp.where(m, rho, 0.0)
-                    )
-                elif comp == "hist":
-                    from .sketches import hist_bin
-
-                    b = hist_bin(v)
-                    arr = arr.at[pane_idx, slots, k, b].add(mf)
-                elif comp == "hh":
-                    from .sketches import hh_update_parts
-
-                    idx, wts = hh_update_parts(v, mf)  # (mb, J)
-                    p = (pane_idx[:, None]
-                         if getattr(pane_idx, "ndim", 0) == 1 else pane_idx)
-                    arr = arr.at[p, slots[:, None], k, idx].add(wts)
-            state[comp] = arr
+            with jax.named_scope(f"kuiper/fold/scatter_{comp}"):
+                state[comp] = self._scatter_comp(
+                    comp, state[comp], spec_idxs, slots, pane_idx, per_spec)
         return state
+
+    @staticmethod
+    def _scatter_comp(comp, arr, spec_idxs, slots, pane_idx, per_spec):
+        """The scatters of one micro-batch into one state component."""
+        import jax.numpy as jnp
+
+        for k, si in enumerate(spec_idxs):
+            v, m = per_spec[si]
+            mf = m.astype(jnp.float32)
+            if comp == "n":
+                arr = arr.at[pane_idx, slots, k].add(mf)
+            elif comp == "s1":
+                arr = arr.at[pane_idx, slots, k].add(jnp.where(m, v, 0.0))
+            elif comp == "s2":
+                arr = arr.at[pane_idx, slots, k].add(jnp.where(m, v * v, 0.0))
+            elif comp == "mn":
+                arr = arr.at[pane_idx, slots, k].min(
+                    jnp.where(m, v, jnp.inf)
+                )
+            elif comp == "mx":
+                arr = arr.at[pane_idx, slots, k].max(
+                    jnp.where(m, v, -jnp.inf)
+                )
+            elif comp == "hll":
+                from .sketches import hll_parts
+
+                reg, rho = hll_parts(v)
+                arr = arr.at[pane_idx, slots, k, reg].max(
+                    jnp.where(m, rho, 0.0)
+                )
+            elif comp == "hist":
+                from .sketches import hist_bin
+
+                b = hist_bin(v)
+                arr = arr.at[pane_idx, slots, k, b].add(mf)
+            elif comp == "hh":
+                from .sketches import hh_update_parts
+
+                idx, wts = hh_update_parts(v, mf)  # (mb, J)
+                p = (pane_idx[:, None]
+                     if getattr(pane_idx, "ndim", 0) == 1 else pane_idx)
+                arr = arr.at[p, slots[:, None], k, idx].add(wts)
+        return arr
 
     # --------------------------------------------------------------- finalize
     def _merged(self, state, comp: str, pane_mask):
@@ -462,21 +479,25 @@ class DeviceGroupBy:
         return self._finalize_body(state, pane_mask)
 
     def _finalize_body(self, state, pane_mask):
+        import jax
         import jax.numpy as jnp
 
-        merged = {
-            comp: self._merged(state, comp, pane_mask) for comp in self.comp_specs
-        }
-        act = self._merged(state, "act", pane_mask)
-        outs = []
-        for i, spec in enumerate(self.plan.specs):
-            col = {
-                comp: merged[comp][:, self.comp_specs[comp].index(i)]
-                for comp in spec.components
+        with jax.named_scope("kuiper/finalize/pane_merge"):
+            merged = {
+                comp: self._merged(state, comp, pane_mask)
+                for comp in self.comp_specs
             }
-            outs.append(self._final_value(spec, col))
-        # one stacked array -> one transfer
-        return jnp.stack(outs + [act], axis=0)
+            act = self._merged(state, "act", pane_mask)
+        with jax.named_scope("kuiper/finalize/values"):
+            outs = []
+            for i, spec in enumerate(self.plan.specs):
+                col = {
+                    comp: merged[comp][:, self.comp_specs[comp].index(i)]
+                    for comp in spec.components
+                }
+                outs.append(self._final_value(spec, col))
+            # one stacked array -> one transfer
+            return jnp.stack(outs + [act], axis=0)
 
     @staticmethod
     def _final_value(spec: AggSpec, c):
@@ -563,15 +584,18 @@ class DeviceGroupBy:
         return begin_pending(out, self.capacity, self._components_layout())
 
     def _components_body(self, state, pane_mask):
+        import jax
         import jax.numpy as jnp
 
-        parts = []
-        for comp in sorted(self.comp_specs):
-            m = self._merged(state, comp, pane_mask)
-            parts.append(m.reshape(m.shape[0], -1))
-        act = self._merged(state, "act", pane_mask)
-        parts.append(act.reshape(-1, 1))
-        return jnp.concatenate(parts, axis=1)
+        with jax.named_scope("kuiper/components/pane_merge"):
+            parts = []
+            for comp in sorted(self.comp_specs):
+                m = self._merged(state, comp, pane_mask)
+                parts.append(m.reshape(m.shape[0], -1))
+            act = self._merged(state, "act", pane_mask)
+            parts.append(act.reshape(-1, 1))
+        with jax.named_scope("kuiper/components/stack"):
+            return jnp.concatenate(parts, axis=1)
 
     def _pane_mask(self, panes: Optional[List[int]]) -> Tuple[bool, ...]:
         pane_mask = np.zeros(self.n_panes, dtype=np.bool_)
@@ -760,14 +784,17 @@ class DeviceGroupBy:
 
     # ------------------------------------------------------------------ reset
     def _reset_pane_impl(self, state, pane_idx):
+        import jax
         import jax.numpy as jnp
 
-        for comp in list(state.keys()):
-            if comp == "touch":
-                continue  # per-slot recency survives pane expiry
-            init = _INIT[comp]
-            arr = state[comp]
-            state[comp] = arr.at[pane_idx].set(jnp.full(arr.shape[1:], init, dtype=arr.dtype))
+        with jax.named_scope("kuiper/reset_pane/fill"):
+            for comp in list(state.keys()):
+                if comp == "touch":
+                    continue  # per-slot recency survives pane expiry
+                init = _INIT[comp]
+                arr = state[comp]
+                state[comp] = arr.at[pane_idx].set(
+                    jnp.full(arr.shape[1:], init, dtype=arr.dtype))
         return state
 
     def reset_pane(self, state: Dict[str, Any], pane_idx: int) -> Dict[str, Any]:

@@ -497,7 +497,8 @@ class _AotJit:
                 return self._ensure_fallback()(*args, **kwargs)
         t0 = _time.perf_counter()
         try:
-            out = compiled(*self._strip_static(args), **kwargs)
+            with rec.annotate():
+                out = compiled(*self._strip_static(args), **kwargs)
         except TypeError as exc:
             # calling-convention drift (args/kwargs split differs from
             # the lowered structure) surfaces as a pytree mismatch BEFORE

@@ -38,6 +38,7 @@ import threading
 import time as _time
 from typing import Any, Callable, Dict, Optional, Tuple
 
+from ..observability.tracer import Tracer
 from ..utils.infra import logger
 
 
@@ -83,8 +84,12 @@ class DecodePool:
         self._job_ready = threading.Condition(self._lock)
         self._slot_free = threading.Condition(self._lock)
         self._drained = threading.Condition(self._lock)
-        self._jobs: list = []  # [(seq, job)] pending pickup
-        self._results: dict = {}  # seq -> result, decoded awaiting its turn
+        # a job carries the trace context of the span that submitted it
+        # (None unless a rule is traced): the worker and the drainer
+        # install it, so decode/upload stage spans and the emitted batch
+        # name that span as their parent
+        self._jobs: list = []  # [(seq, job, ctx)] pending pickup
+        self._results: dict = {}  # seq -> (result, ctx), awaiting its turn
         self._next_seq = 0  # next submit() sequence number
         self._emit_seq = 0  # next sequence to emit
         self._in_flight = 0  # submitted - emitted
@@ -122,7 +127,7 @@ class DecodePool:
                 self._slot_free.wait(timeout=1.0)
             if self._closed:
                 raise RuntimeError("decode pool is closed")
-            self._jobs.append((self._next_seq, job))
+            self._jobs.append((self._next_seq, job, Tracer.current()))
             self._next_seq += 1
             self._in_flight += 1
             self._job_ready.notify()
@@ -194,7 +199,8 @@ class DecodePool:
                     if self._closed:
                         return
                     continue
-                seq, job = self._jobs.pop(0)
+                seq, job, ctx = self._jobs.pop(0)
+            Tracer.set_current(ctx)
             try:
                 result = self._decode(job)
             except Exception as exc:
@@ -213,14 +219,15 @@ class DecodePool:
                     self._stats.inc_dropped("decode_error", n=n_lost,
                                             detail="decode pool job failed")
                 result = None
-            self._finish(seq, result)
+            self._finish(seq, result, ctx)
+            Tracer.set_current(None)
 
     def _ring_bytes(self) -> int:
         """Host bytes held by decoded-but-unemitted ring results."""
         with self._lock:
             results = list(self._results.values())
         total = 0
-        for r in results:
+        for r, _ctx in results:
             cols = getattr(r, "columns", None)
             if not cols:
                 continue
@@ -229,14 +236,14 @@ class DecodePool:
                 total += int(nb or 0)
         return total
 
-    def _finish(self, seq: int, result: Any) -> None:
+    def _finish(self, seq: int, result: Any, ctx=None) -> None:
         """Deposit a finished decode; if the emit cursor's result is ready
         and nobody is draining, become the drainer. Emission runs OUTSIDE
         the lock (emit lands in the fused node's queue, which can block on
         backpressure) but the `_emitting` flag keeps it single-threaded, so
         order stays total."""
         with self._lock:
-            self._results[seq] = result
+            self._results[seq] = (result, ctx)
             if self._stats is not None:
                 self._ready_ts[seq] = _time.perf_counter()
             if self._emitting or self._emit_seq not in self._results:
@@ -247,13 +254,14 @@ class DecodePool:
                 if self._emit_seq not in self._results:
                     self._emitting = False
                     return
-                head = self._results.pop(self._emit_seq)
+                head, ctx = self._results.pop(self._emit_seq)
                 t_ready = self._ready_ts.pop(self._emit_seq, None)
                 self._emit_seq += 1
             if t_ready is not None and self._stats is not None:
                 self._stats.observe_stage(
                     "ring", (_time.perf_counter() - t_ready) * 1e6,
                     getattr(head, "n", 0) if head is not None else 0)
+            Tracer.set_current(ctx)  # the drained job's, not the drainer's
             try:
                 if head is not None:
                     if self._prepare is not None:

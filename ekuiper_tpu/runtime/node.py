@@ -19,7 +19,6 @@ from typing import Any, Callable, List, Optional
 from ..observability.tracer import Tracer, item_stats
 from ..utils.infra import logger, safe_run
 from ..utils.metrics import StatManager
-from ..utils.timex import now_ms as timex_now_ms
 from .events import EOF, Barrier, ErrorEvent, PreTrigger, Trigger, Watermark
 
 
@@ -39,32 +38,35 @@ _emit_ctx = threading.local()
 _NO_OVERRIDE = object()
 
 
-def _item_ingest_ms(item: Any) -> Optional[int]:
+def _item_stamp(item: Any, attr: str = "ingest_ms") -> Optional[int]:
     """Ingest timestamp riding an item, if any. Bare lists (multi-row
     project output) can't carry attributes, so their first element speaks
-    for the emission — rows of one emission share provenance."""
-    ing = getattr(item, "ingest_ms", None)
+    for the emission — rows of one emission share provenance. `attr` names
+    another provenance stamp carried the same way (`boundary_ns`: when a
+    window's result was handed downstream, perf-clock ns)."""
+    ing = getattr(item, attr, None)
     if ing is None and type(item) is list and item:
-        ing = getattr(item[0], "ingest_ms", None)
+        ing = getattr(item[0], attr, None)
     return ing
 
 
-def _stamp_ingest_ms(item: Any, ing: int) -> None:
-    """Attach the ingest timestamp to an outgoing item when it can hold
-    one (dataclasses take ad-hoc attributes; list elements are stamped
-    individually; bytes/str/dict silently can't — their e2e sample is
-    recorded at the last attributable hop)."""
+def _stamp_item(item: Any, ing: int, attr: str = "ingest_ms") -> None:
+    """Attach the ingest timestamp (or the stamp `attr` names) to an
+    outgoing item when it can hold one (dataclasses take ad-hoc
+    attributes; list elements are stamped individually; bytes/str/dict
+    silently can't — their e2e sample is recorded at the last attributable
+    hop)."""
     try:
-        if getattr(item, "ingest_ms", None) is None:
-            item.ingest_ms = ing
+        if getattr(item, attr, None) is None:
+            setattr(item, attr, ing)
         return
     except (AttributeError, TypeError):
         pass
     if type(item) is list:
         for x in item:
             try:
-                if getattr(x, "ingest_ms", None) is None:
-                    x.ingest_ms = ing
+                if getattr(x, attr, None) is None:
+                    setattr(x, attr, ing)
             except (AttributeError, TypeError):
                 return  # homogeneous lists: first failure ends the walk
 
@@ -136,9 +138,15 @@ class Node:
         # outgoing items so sinks can record true end-to-end latency even
         # for window emissions that happen on trigger/worker dispatches.
         self._cur_ingest_ms: Optional[int] = None
+        # boundary provenance: when the window result being dispatched was
+        # handed downstream by its window node (perf-clock ns); re-stamped
+        # onto what this node emits for it, so the sink can close the
+        # boundary's `sink` phase. None for anything but a window result.
+        self._cur_boundary_ns: Optional[int] = None
         # span attributes for the CURRENT dispatch (set by subclasses,
         # e.g. the sink's e2e latency), attached to the recorded span
         self._span_attrs: Optional[dict] = None
+        self._tracing_now = False  # the current dispatch has an open span
         # QoS shed gate (runtime/control.py): fraction of incoming DATA
         # items discarded before enqueue when this rule is breaching its
         # SLO. Deterministic accumulator pattern (not random) so tests
@@ -181,11 +189,7 @@ class Node:
         # orphan the FIFO pairing for every later item
         self._enq_times.append(_time.perf_counter())
         if self.disable_buffer_full_discard:
-            self.inq.put(entry)
-            # enqueue-time high-water mark: a backpressure spike that
-            # drains before the next Prometheus scrape / evaluator tick
-            # must still be visible to the health plane's burn-rate math
-            self.stats.note_queue_depth(self.inq.qsize())
+            self._put_blocking(entry)
             return
         while True:
             try:
@@ -213,7 +217,23 @@ class Node:
         queue-wait clock FIFO-paired with the queue (a bare inq.put would
         desync every later wait sample)."""
         self._enq_times.append(_time.perf_counter())
-        self.inq.put(item)
+        self._put_blocking(item)
+
+    def _put_blocking(self, entry: Any) -> None:
+        """Enqueue, waiting for room; a wait that began on a full queue is
+        this node's backpressure on its senders
+        (kuiper_op_backpressure_us_total), counted here on the sender's
+        thread."""
+        if self.inq.full():
+            t0 = _time.perf_counter_ns()
+            self.inq.put(entry)
+            self.stats.add_backpressure(
+                (_time.perf_counter_ns() - t0) // 1000)
+        else:
+            self.inq.put(entry)
+        # enqueue-time high-water mark: a backpressure spike that drains
+        # before the next Prometheus scrape / evaluator tick must still be
+        # visible to the health plane's burn-rate math
         self.stats.note_queue_depth(self.inq.qsize())
 
     def send_to(self, out: "Node", item: Any) -> None:
@@ -267,11 +287,18 @@ class Node:
         set_rule_context(getattr(self._topo, "rule_id", None))
         self.on_worker_start()
         try:
+            stats = self.stats
             while not self._stop.is_set():
+                # starved time (kuiper_op_idle_us_total): what the worker
+                # spends in get() with nothing to dispatch
+                t_idle = _time.perf_counter_ns()
                 try:
                     entry = self.inq.get(timeout=0.2)
                 except queue.Empty:
                     continue
+                finally:
+                    stats.idle_us_total += (
+                        _time.perf_counter_ns() - t_idle) // 1000
                 if self._enq_times:
                     try:
                         self.stats.observe_queue_wait(
@@ -320,27 +347,15 @@ class Node:
         if isinstance(item, Barrier):
             self._handle_barrier(item, from_name)
             return
-        # tracing fast path: one attribute check when disabled
-        tracer = Tracer._instance
-        traced = (
-            tracer is not None and tracer.any_enabled
-            and self._topo is not None
-            and tracer.is_enabled(getattr(self._topo, "rule_id", ""))
-        )
-        if traced:
-            tid = tracer.lookup(item)
-            if tid is not None:
-                tracer.set_current(tid)
-            elif self.op_type == "source" or tracer.current_trace() is None:
-                tracer.new_trace()
-            t0 = _time.perf_counter()
-        self._tracing_now = traced
-        ing = _item_ingest_ms(item)
+        span = self._span_begin(item)
+        self._tracing_now = span is not None
+        ing = _item_stamp(item)
         if ing is not None:
             # keep the LAST seen provenance (not reset on control events):
             # window emissions fire on trigger dispatches, where the freshest
             # contributing batch's ingest time is exactly the right stamp
             self._cur_ingest_ms = ing
+        self._cur_boundary_ns = _item_stamp(item, "boundary_ns")
         self.stats.inc_in()
         self.stats.process_begin()
         try:
@@ -360,14 +375,30 @@ class Node:
             self.on_error(exc, item)
         finally:
             self.stats.process_end()
-            if traced:
-                kind, rows = item_stats(item)
-                attrs, self._span_attrs = self._span_attrs, None
-                tracer.record(
-                    self._topo.rule_id, self.name, timex_now_ms(),
-                    int((_time.perf_counter() - t0) * 1e6), kind, rows,
-                    attrs=attrs)
+            if span is not None:
                 self._tracing_now = False
+                self._span_end(span)
+
+    def _span_begin(self, item: Any, kind: Optional[str] = None):
+        """Open a span of this node around `item`'s handling when the rule
+        is traced (one attribute check when no rule is): child of the span
+        that emitted `item`, else of the span open on this thread, else a
+        root (a source's input, a timer's Trigger). `kind` names what was
+        handled where no item says it."""
+        tracer = Tracer._instance
+        if not (tracer is not None and tracer.any_enabled
+                and self._topo is not None
+                and tracer.is_enabled(getattr(self._topo, "rule_id", ""))):
+            return None
+        rows = 0
+        if kind is None:
+            kind, rows = item_stats(item)
+        return tracer.begin(self._topo.rule_id, self.name, kind, rows,
+                            ctx=tracer.lookup(item))
+
+    def _span_end(self, span) -> None:
+        attrs, self._span_attrs = self._span_attrs, None
+        span.end(attrs)
 
     # ------------------------------------------------------------- overridables
     def on_open(self) -> None:
@@ -486,13 +517,16 @@ class Node:
 
     # ------------------------------------------------------------------ output
     def emit(self, item: Any, count: int = 1) -> None:
-        if getattr(self, "_tracing_now", False):
-            Tracer.global_instance().tag(item)  # trace follows the item
+        tracer = Tracer._instance
+        if tracer is not None and tracer.any_enabled:
+            tracer.tag(item)  # the open span's context follows the item
         ing = getattr(_emit_ctx, "ingest_ms", _NO_OVERRIDE)
         if ing is _NO_OVERRIDE:
             ing = self._cur_ingest_ms
         if ing is not None:
-            _stamp_ingest_ms(item, ing)  # provenance follows the item too
+            _stamp_item(item, ing)  # provenance follows the item too
+        if self._cur_boundary_ns is not None:
+            _stamp_item(item, self._cur_boundary_ns, "boundary_ns")
         self.stats.inc_out(count)
         self.broadcast(item)
 

@@ -31,10 +31,11 @@ from ..ops.aggspec import (
 from ..ops.groupby import DeviceGroupBy
 from ..ops.keytable import KeyTable
 from ..sql import ast
+from ..observability.tracer import Tracer
 from ..utils import timex
 from ..utils.infra import logger
 from .events import EOF, PreTrigger, Trigger
-from .node import Node
+from .node import _NO_OVERRIDE, Node, _emit_ctx, _stamp_item
 
 
 def _host_mask(ce, columns: Dict[str, np.ndarray], n: int) -> np.ndarray:
@@ -1019,38 +1020,35 @@ class FusedWindowAggNode(Node):
         the jitted fold dispatch (which carries the implicit H2D copy when
         inputs weren't pre-uploaded) — together with the source's "decode"
         these expose the ingest-pipeline balance per node."""
-        import time as _time
-
         frozen = self._device_frozen and bool(self._pipeline)
-        t0 = _time.perf_counter()
-        cols, valid, slots = self._build_kernel_inputs(sub, frozen)
-        dev = None
+        with self.stats.stage("upload", sub.n):
+            cols, valid, slots = self._build_kernel_inputs(sub, frozen)
+            dev = None
+            if not frozen:
+                if self.gb.capacity < self.kt.capacity:
+                    # deferred grow (keys first seen in an earlier frozen
+                    # span)
+                    self.state = self.gb.grow(self.state, self.kt.capacity)
+                if self.tier is not None:
+                    # admission point: returning demoted keys (this batch's
+                    # new-key log) get their spilled partials merged back
+                    # into their fresh slots before the fold lands
+                    self.state = self.tier.admit(self.state)
+                dev = self._shared_device_inputs(sub, cols, valid, slots)
         if not frozen:
-            if self.gb.capacity < self.kt.capacity:
-                # deferred grow (keys first seen in an earlier frozen span)
-                self.state = self.gb.grow(self.state, self.kt.capacity)
-            if self.tier is not None:
-                # admission point: returning demoted keys (this batch's
-                # new-key log) get their spilled partials merged back
-                # into their fresh slots before the fold lands
-                self.state = self.tier.admit(self.state)
-            dev = self._shared_device_inputs(sub, cols, valid, slots)
-        t1 = _time.perf_counter()
-        self.stats.observe_stage("upload", (t1 - t0) * 1e6, sub.n)
-        if not frozen:
-            if dev is not None:
-                # shared uploads: device columns/slots computed once serve
-                # every fan-out consumer; host copies still feed the shadows
-                dcols, dvalid, dslots = dev
-                self.state = self.gb.fold(
-                    self.state, {**cols, **dcols},
-                    dslots if dslots is not None else slots,
-                    {**valid, **dvalid}, pane_arg, n_rows=sub.n)
-            else:
-                self.state = self.gb.fold(self.state, cols, slots, valid,
-                                          pane_arg)
-            self.stats.observe_stage(
-                "fold", (_time.perf_counter() - t1) * 1e6, sub.n)
+            with self.stats.stage("fold", sub.n):
+                if dev is not None:
+                    # shared uploads: device columns/slots computed once
+                    # serve every fan-out consumer; host copies still feed
+                    # the shadows
+                    dcols, dvalid, dslots = dev
+                    self.state = self.gb.fold(
+                        self.state, {**cols, **dcols},
+                        dslots if dslots is not None else slots,
+                        {**valid, **dvalid}, pane_arg, n_rows=sub.n)
+                else:
+                    self.state = self.gb.fold(self.state, cols, slots,
+                                              valid, pane_arg)
             if hasattr(self.gb, "note_rows"):
                 # per-shard accounting (kuiper_shard_*): the kernel counts
                 # host slot vectors itself; the prep path hands it DEVICE
@@ -1160,12 +1158,7 @@ class FusedWindowAggNode(Node):
         panes = sorted({(x % self.n_panes) for x in window_buckets})
         if has_data and n_keys:
             outs, act = self.gb.finalize(self.state, n_keys, panes=panes)
-            active = np.nonzero(act > 0)[0]
-            if len(active):
-                if self.direct_emit is not None:
-                    self._emit_direct(outs, active, wr)
-                else:
-                    self._emit_grouped(outs, active, wr)
+            self._emit_active(outs, act, wr, counted=False)
         # spilled keys demoted with data in this window's buckets emit
         # host-side (their pane epochs gate validity)
         self._emit_tier_extras(wr, panes=panes)
@@ -1204,6 +1197,9 @@ class FusedWindowAggNode(Node):
             self._rows_in_window += take
             pos += take
             if self._rows_in_window >= self.count_len:
+                # a count window has no Trigger: its boundary starts at
+                # the dispatch of the batch that fills it
+                _emit_ctx.boundary_t0 = self.stats.started_perf_ns
                 wr = WindowRange(0, timex.now_ms())
                 if self._async_count:
                     self._emit_count_async(wr)
@@ -1376,14 +1372,12 @@ class FusedWindowAggNode(Node):
         """Dispatch the device finalize on the (immutable) current state and
         hand the fetch+emit to the worker thread; the fold stream continues
         without waiting a device round trip."""
-        import time as _time
-
         if self.kt.n_keys == 0:
             self.last_emit_info = None
             return
         self._emit_async(
-            "count",
-            self.gb._finalize(self.state, (True,) * self.gb.n_panes), wr)
+            "count", lambda: self.gb._finalize(
+                self.state, (True,) * self.gb.n_panes), wr)
 
     def _emit_hh_async(self, wr: WindowRange) -> None:
         """Heavy-hitters boundary: dispatch the compact device recovery on
@@ -1392,9 +1386,8 @@ class FusedWindowAggNode(Node):
             self.last_emit_info = None
             return
         self._emit_async(
-            "hh",
-            self.gb._hh_fin(self.state,
-                            np.ones(self.gb.n_panes, dtype=np.bool_)), wr)
+            "hh", lambda: self.gb._hh_fin(
+                self.state, np.ones(self.gb.n_panes, dtype=np.bool_)), wr)
 
     def _keys_snapshot(self):
         """Slot->key decode snapshot for a DEFERRED delivery: tiered
@@ -1409,20 +1402,30 @@ class FusedWindowAggNode(Node):
             return None
         return self.kt.decode_all()
 
-    def _emit_async(self, kind: str, stacked_dev, wr: WindowRange) -> None:
-        """Shared async-emit protocol: start the device→host copy, enqueue
-        for the worker. The dispatched program sees an immutable snapshot,
-        so the caller is free to reset panes immediately after."""
-        import time as _time
+    def _emit_async(self, kind: str, dispatch, wr: WindowRange) -> None:
+        """Shared async-emit protocol: `dispatch()` the finalize program,
+        start the device→host copy, enqueue for the worker. The dispatched
+        program sees an immutable snapshot, so the caller is free to reset
+        panes immediately after."""
+        with self.stats.stage("emit"):
+            with self.stats.span("finalize"):
+                stacked_dev = dispatch()
+                stacked_dev.copy_to_host_async()
+            self._emit_submit(kind, stacked_dev, self.kt.n_keys, wr,
+                              self._keys_snapshot())
 
-        stacked_dev.copy_to_host_async()
+    def _emit_submit(self, kind: str, payload, n_keys: int, wr,
+                     keys_snap=None) -> None:
+        """Enqueue one deferred delivery for the emit worker with what it
+        needs from THIS (the dispatch) thread, captured at issue: the
+        ingest provenance (the worker must not read the live
+        _cur_ingest_ms, which keeps advancing with post-boundary folds),
+        the boundary's start and the trace context."""
         self._ensure_emit_worker()
-        # ingest provenance captured AT ISSUE (this is the dispatch
-        # thread): the worker must not read the live _cur_ingest_ms,
-        # which keeps advancing with post-boundary folds
-        self._emit_q.put((kind, stacked_dev, self.kt.n_keys, wr,
-                          _time.perf_counter(), self._cur_ingest_ms,
-                          self._keys_snapshot()))
+        self._emit_q.put((kind, payload, n_keys, wr, time.perf_counter(),
+                          self._cur_ingest_ms, keys_snap,
+                          getattr(_emit_ctx, "boundary_t0", None),
+                          Tracer.current()))
 
     def _ensure_emit_worker(self) -> None:
         import queue
@@ -1437,92 +1440,36 @@ class FusedWindowAggNode(Node):
             self._emit_worker.start()
 
     def _emit_worker_loop(self) -> None:
-        import time as _time
-
-        from ..ops.groupby import apply_int_semantics
-
-        from .node import _NO_OVERRIDE, _emit_ctx
-
         while True:
             item = self._emit_q.get()
             if item is None:
                 break
-            (kind, stacked_dev, n_keys, wr, t_issue, issue_ing,
-             keys_snap) = item
+            (kind, payload, n_keys, wr, t_issue, issue_ing, keys_snap,
+             boundary_t0, ctx) = item
             # install the issue-time provenance for every emit() this
             # delivery makes (node.py reads it ahead of _cur_ingest_ms;
             # issue_ing=None means "stamp nothing", not "read live");
             # keys_snap pins the slot->key decode to dispatch time so a
             # tiered boundary's slot retire/recycle between dispatch and
-            # delivery cannot misattribute the window
+            # delivery cannot misattribute the window. The boundary's start
+            # and the trace context of the issuing dispatch come along, so
+            # the delivery's phases and spans belong to that boundary.
             _emit_ctx.ingest_ms = issue_ing
+            _emit_ctx.boundary_t0 = boundary_t0
+            Tracer.set_current(ctx)
             self._kt_keys_override = keys_snap
             try:
                 if kind == "tier":
                     # tiered-state maintenance (ops/tierstore.py): harvest
                     # a landed demote block / run the placement scan —
                     # off the fold thread, by design
-                    self.tier.worker_task(stacked_dev)
+                    self.tier.worker_task(payload)
                     continue
-                if kind == "pf":
-                    pipeline, frozen, backup = stacked_dev
-                    self._deliver_pf(pipeline, frozen, backup, n_keys, wr,
-                                     t_issue)
-                    continue
-                if kind == "ring":
-                    # sliding DABA trigger: fetch the O(1) body combine,
-                    # merge the host edge shadow, final values in numpy —
-                    # the same component tail as the prefinalize emit
-                    pending, shadow = stacked_dev
-                    outs, act = self.gb.prefinalize_merge(
-                        pending, shadow, n_keys)
-                    self.last_emit_info = {
-                        "source": "device-ring",
-                        "fetch_ms": (pending.fetch_ms()
-                                     if hasattr(pending, "fetch_ms") else
-                                     (_time.perf_counter() - t_issue)
-                                     * 1000.0),
-                        "ages_ms": [],
-                    }
-                    active = np.nonzero(act > 0)[0]
-                    if len(active):
-                        self._count_emit_source()
-                        if self.direct_emit is not None:
-                            self._emit_direct(outs, active, wr)
-                        else:
-                            self._emit_grouped(outs, active, wr)
-                    continue
-                # kuiperlint: ignore[host-sync]: emit worker thread — THE intended sync point; the fold thread already dispatched and moved on
-                arr = np.asarray(stacked_dev)
-                if kind == "mr":
-                    self._deliver_mr(arr, n_keys, wr)
-                    self.last_emit_info = {
-                        "source": "device-async",
-                        "fetch_ms": (_time.perf_counter() - t_issue) * 1000.0,
-                        "ages_ms": [],
-                    }
-                    self._count_emit_source()
-                    continue
-                if kind == "hh":
-                    outs, act = self.gb.hh_assemble(arr, n_keys)
-                else:
-                    outs = [arr[i][:n_keys]
-                            for i in range(len(self.plan.specs))]
-                    outs = apply_int_semantics(self.plan.specs, outs)
-                    # kuiperlint: ignore[host-sync]: `arr` already landed on host two lines up
-                    act = np.asarray(arr[-1][:n_keys])
-                self.last_emit_info = {
-                    "source": "device-async",
-                    "fetch_ms": (_time.perf_counter() - t_issue) * 1000.0,
-                    "ages_ms": [],
-                }
-                active = np.nonzero(act > 0)[0]
-                if len(active):
-                    self._count_emit_source()
-                    if self.direct_emit is not None:
-                        self._emit_direct(outs, active, wr)
-                    else:
-                        self._emit_grouped(outs, active, wr)
+                # `emit` accrues off the node's worker here, as the
+                # pool's `decode` does off the source's
+                with self.stats.stage("emit") as st:
+                    st.rows = self._deliver_async(kind, payload, n_keys, wr,
+                                                  t_issue)
             except Exception as exc:
                 logger.error("async %s emit failed on %s: %s",
                              kind, self.name, exc)
@@ -1532,8 +1479,95 @@ class FusedWindowAggNode(Node):
                 self.stats.inc_exception(f"async {kind} emit failed: {exc}")
             finally:
                 _emit_ctx.ingest_ms = _NO_OVERRIDE
+                _emit_ctx.boundary_t0 = None
+                Tracer.set_current(None)
                 self._kt_keys_override = None
                 self._emit_q.task_done()
+
+    def _deliver_async(self, kind: str, payload, n_keys: int, wr,
+                       t_issue: float) -> int:
+        """One deferred delivery on the emit worker: wait for the device→
+        host copy (`fetch`), assemble the window (`merge`), hand it down;
+        returns the groups emitted."""
+        from ..ops.groupby import apply_int_semantics
+
+        if kind == "pf":
+            return self._deliver_pf(*payload, n_keys, wr, t_issue)
+        if kind == "ring":
+            # sliding DABA trigger: fetch the O(1) body combine, merge the
+            # host edge shadow, final values in numpy — the same component
+            # tail as the prefinalize emit
+            pending, shadow = payload
+            outs, act = self._fetch_and_merge(pending, shadow, n_keys)
+            self.last_emit_info = {
+                "source": "device-ring",
+                "fetch_ms": (pending.fetch_ms()
+                             if hasattr(pending, "fetch_ms") else
+                             (time.perf_counter() - t_issue) * 1000.0),
+                "ages_ms": [],
+            }
+            return self._emit_active(outs, act, wr)
+        with self.stats.span("fetch"):
+            # kuiperlint: ignore[host-sync]: emit worker thread — THE intended sync point; the fold thread already dispatched and moved on
+            arr = np.asarray(payload)
+        self.last_emit_info = {
+            "source": "device-async",
+            "fetch_ms": (time.perf_counter() - t_issue) * 1000.0,
+            "ages_ms": [],
+        }
+        if kind == "mr":
+            self._deliver_mr(arr, n_keys, wr)
+            self._count_emit_source()
+            return n_keys
+        with self.stats.span("merge"):
+            if kind == "hh":
+                outs, act = self.gb.hh_assemble(arr, n_keys)
+            else:
+                outs = [arr[i][:n_keys]
+                        for i in range(len(self.plan.specs))]
+                outs = apply_int_semantics(self.plan.specs, outs)
+                # kuiperlint: ignore[host-sync]: `arr` already landed on host above
+                act = np.asarray(arr[-1][:n_keys])
+        return self._emit_active(outs, act, wr)
+
+    def _fetch_and_merge(self, pending, shadow, n_keys: int):
+        """Complete a pre-issued finalize on this thread: wait for its
+        fetch to land (`fetch` sub-stage), then merge the tail shadow and
+        compute the final values (`merge`)."""
+        with self.stats.span("fetch"):
+            pending.get()
+        with self.stats.span("merge"):
+            return self.gb.prefinalize_merge(pending, shadow, n_keys)
+
+    def _emit_active(self, outs, act, wr: WindowRange,
+                     counted: bool = True) -> int:
+        """Build the output of the window's active groups, if any, along
+        the path the plan chose (the tail of the `merge` sub-stage) and
+        hand it downstream; returns the number of active groups, 0 for an
+        empty window. `counted` bumps the per-source window count first."""
+        active = np.nonzero(act > 0)[0]
+        if len(active) == 0:
+            return 0
+        if counted:
+            self._count_emit_source()
+        with self.stats.span("merge", len(active)):
+            built = (self._build_direct(outs, active, wr)
+                     if self.direct_emit is not None
+                     else self._build_grouped(outs, active, wr))
+        if built is not None:
+            self._hand_down(*built)
+        return len(active)
+
+    def _hand_down(self, item: Any, count: int = 1) -> None:
+        """Hand a window's result downstream: here the boundary's `emit`
+        phase ends (dispatch of what closed the window -> now) and its
+        `sink` phase begins — the stamp rides the item to the sink."""
+        t0 = getattr(_emit_ctx, "boundary_t0", None)
+        if t0 is not None and self._topo is not None:
+            now = time.perf_counter_ns()
+            self._topo.observe_boundary("emit", (now - t0) / 1000.0)
+            _stamp_item(item, now, "boundary_ns")
+        self.emit(item, count)
 
     # bounded drain deadline; tests shrink it to exercise the abort path
     drain_deadline_s: float = 30.0
@@ -1580,11 +1614,7 @@ class FusedWindowAggNode(Node):
         """Hand a tier task (demote harvest / policy scan) to the
         prefinalize/emit worker — the policy and the packed-row fetch
         never run on the fold thread."""
-        import time as _time
-
-        self._ensure_emit_worker()
-        self._emit_q.put(("tier", payload, 0, None, _time.perf_counter(),
-                          None, None))
+        self._emit_submit("tier", payload, 0, None)
 
     def _on_tier_event(self, kind: str, n: int = 0) -> None:
         """Tier transition hook: demotions/promotions invalidate the
@@ -1915,34 +1945,26 @@ class FusedWindowAggNode(Node):
             # would otherwise grow the deque for the life of the stream
             self._dev_ring_fifo = type(self._dev_ring_fifo)(
                 t for t in self._dev_ring_fifo if t[0] >= floor_b)
-        import time as _time
-
         daba = self.sliding_impl == "daba"
-        t0 = _time.perf_counter()
-        cols, valid, slots = self._build_kernel_inputs(sub)
-        if self.tier is not None:
-            self.state = self.tier.admit(self.state)
-        # the DABA path needs no device batch cache: triggers combine
-        # running partials, edges fold on host from the row ring
-        dev = (None if daba
-               else self._upload_sliding_inputs(cols, valid, slots))
-        pane_vec = (buckets % self.n_ring_panes).astype(np.uint8)
-        fold_cols, fold_valid, fold_slots, n_rows = (
-            (dev[0], dev[1], dev[2], sub.n) if dev is not None
-            else (cols, valid, slots, None))
-        t1 = _time.perf_counter()
-        self.stats.observe_stage("upload", (t1 - t0) * 1e6, sub.n)
-        if len(np.unique(pane_vec)) == 1:
+        with self.stats.stage("upload", sub.n):
+            cols, valid, slots = self._build_kernel_inputs(sub)
+            if self.tier is not None:
+                self.state = self.tier.admit(self.state)
+            # the DABA path needs no device batch cache: triggers combine
+            # running partials, edges fold on host from the row ring
+            dev = (None if daba
+                   else self._upload_sliding_inputs(cols, valid, slots))
+            pane_vec = (buckets % self.n_ring_panes).astype(np.uint8)
+            fold_cols, fold_valid, fold_slots, n_rows = (
+                (dev[0], dev[1], dev[2], sub.n) if dev is not None
+                else (cols, valid, slots, None))
+        with self.stats.stage("fold", sub.n):
             # single-bucket batch: scalar-pane fast path (the common case —
             # a batch spans far less time than one pane)
+            pane_arg = (int(pane_vec[0]) if len(np.unique(pane_vec)) == 1
+                        else pane_vec)
             self.state = self.gb.fold(self.state, fold_cols, fold_slots,
-                                      fold_valid, int(pane_vec[0]),
-                                      n_rows=n_rows)
-        else:
-            self.state = self.gb.fold(self.state, fold_cols, fold_slots,
-                                      fold_valid, pane_vec, n_rows=n_rows)
-        self.stats.observe_stage(
-            "fold", (_time.perf_counter() - t1) * 1e6, sub.n)
+                                      fold_valid, pane_arg, n_rows=n_rows)
         if hasattr(self.gb, "note_rows"):
             self.gb.n_keys_hint = self.kt.n_keys  # fold counted host slots
         for b in np.unique(buckets).tolist():
@@ -2182,13 +2204,7 @@ class FusedWindowAggNode(Node):
         if panes and getattr(self.gb, "_host_finalize_only", False):
             # host-only components: keep the exact synchronous path
             outs, act = self.gb.finalize(self.state, n_keys, panes=panes)
-            active = np.nonzero(act > 0)[0]
-            if len(active):
-                wr = WindowRange(lo, hi)
-                if self.direct_emit is not None:
-                    self._emit_direct(outs, active, wr)
-                else:
-                    self._emit_grouped(outs, active, wr)
+            self._emit_active(outs, act, WindowRange(lo, hi), counted=False)
         elif panes:
             # dispatch-and-defer: the finalize launches here, IN ORDER on
             # the device stream (after the scratch folds, before the
@@ -2200,7 +2216,8 @@ class FusedWindowAggNode(Node):
             pane_mask = np.zeros(self.gb.n_panes, dtype=np.bool_)
             pane_mask[panes] = True
             self._emit_async(
-                "count", self.gb._finalize_dyn(self.state, pane_mask),
+                "count",
+                lambda: self.gb._finalize_dyn(self.state, pane_mask),
                 WindowRange(lo, hi))
         if used_scratch:
             self._reset_pane_tiered(self._scratch_pane)
@@ -2215,8 +2232,6 @@ class FusedWindowAggNode(Node):
         window-length pane merge. Exactness matches the refold path: the
         panes remain the ground truth and every off-discipline shape
         (delay, recycled panes, restores) takes an exact fallback."""
-        import time as _time
-
         from ..ops.prefinalize import HostShadow, IdentityFinalize
 
         n_keys = self.kt.n_keys
@@ -2245,10 +2260,8 @@ class FusedWindowAggNode(Node):
         pending = self._ring_body_query(body, include_head, b_hi, shadow)
         if pending is None:
             pending = IdentityFinalize(self.gb.comp_specs, self.kt.capacity)
-        self._ensure_emit_worker()
-        self._emit_q.put(("ring", (pending, shadow), n_keys,
-                          WindowRange(lo, hi), _time.perf_counter(),
-                          self._cur_ingest_ms, None))
+        self._emit_submit("ring", (pending, shadow), n_keys,
+                          WindowRange(lo, hi))
 
     def _shadow_ring_rows(self, shadow, b: int, lo_excl: Optional[int] = None,
                           hi_incl: Optional[int] = None) -> None:
@@ -2421,21 +2434,26 @@ class FusedWindowAggNode(Node):
         # fetches lag the stream by whole windows (r02 bench post-mortem)
         if len(self._pipeline) >= 4 or len(real) >= 2:
             return
-        if real and self._device_frozen:
-            # device state unchanged since the first real pre-issue (frozen
-            # span rows are host-only): retry the fetch on the same
-            # snapshot, sharing that span's shadow
+        # device state unchanged since the first real pre-issue (frozen span
+        # rows are host-only): retry the fetch on the same snapshot, sharing
+        # that span's shadow
+        retry = bool(real) and self._device_frozen
+        with self.stats.stage("emit"), self.stats.span("finalize"):
             self._pipeline.append((
-                self.gb.prefinalize_begin(self.state), real[0][1],
+                self.gb.prefinalize_begin(self.state),
+                real[0][1] if retry else HostShadow(
+                    self.plan, self.gb.comp_specs, self.kt.capacity),
             ))
-            return
-        self._pipeline.append((
-            self.gb.prefinalize_begin(self.state),
-            HostShadow(self.plan, self.gb.comp_specs, self.kt.capacity),
-        ))
-        self._device_frozen = self._tail_host_only
+        if not retry:
+            self._device_frozen = self._tail_host_only
 
     def on_trigger(self, trig: Trigger) -> None:
+        # the boundary begins with this dispatch; how late it is against
+        # the tick's own time is timer lateness plus the queue behind folds
+        _emit_ctx.boundary_t0 = self.stats.started_perf_ns
+        if self._topo is not None:
+            self._topo.observe_boundary(
+                "trigger_delay", (timex.now_ms() - trig.ts) * 1000.0)
         if self.wt == ast.WindowType.SLIDING_WINDOW:
             # delayed sliding emission scheduled at trigger-row time + delay
             if isinstance(trig.tag, tuple) and trig.tag[0] == "sliding":
@@ -2555,40 +2573,36 @@ class FusedWindowAggNode(Node):
         ready_any = any(p.ready() for p, _ in self._pipeline)
         if not backlog and (ready_any or not self.kt.n_keys):
             return self._emit(wr)
-        import time as _time
-
         n_keys = self.kt.n_keys
         pipeline, self._pipeline = self._pipeline, []
         frozen, self._device_frozen = self._device_frozen, False
-        self._ensure_emit_worker()
         if pipeline:
-            # backup finalize dispatched NOW, before on_trigger's
-            # reset_pane donates the state buffers: if the deferred merge
-            # later fails (wedged fetch), the worker recovers from this
-            # snapshot — a device launch whose transfer happens only on
-            # that fallback
-            backup = self.gb._finalize(self.state, (True,) * self.gb.n_panes)
-            self._emit_q.put(("pf", (pipeline, frozen, backup), n_keys, wr,
-                              _time.perf_counter(), self._cur_ingest_ms,
-                              self._keys_snapshot()))
+            with self.stats.stage("emit"):
+                # backup finalize dispatched NOW, before on_trigger's
+                # reset_pane donates the state buffers: if the deferred
+                # merge later fails (wedged fetch), the worker recovers
+                # from this snapshot — a device launch whose transfer
+                # happens only on that fallback
+                with self.stats.span("finalize"):
+                    backup = self.gb._finalize(
+                        self.state, (True,) * self.gb.n_panes)
+                self._emit_submit("pf", (pipeline, frozen, backup), n_keys,
+                                  wr, self._keys_snapshot())
         else:
             # no pre-issue in flight: dispatch the finalize on the
             # immutable state and let the worker fetch + deliver
             self._emit_async(
-                "count",
-                self.gb._finalize(self.state, (True,) * self.gb.n_panes),
-                wr)
+                "count", lambda: self.gb._finalize(
+                    self.state, (True,) * self.gb.n_panes), wr)
 
     def _deliver_pf(self, pipeline, frozen, backup, n_keys: int,
-                    wr: WindowRange, t_issue: float) -> None:
+                    wr: WindowRange, t_issue: float) -> int:
         """Emit-worker delivery of a deferred boundary: wait for the best
         pre-issue to land, merge, emit. Runs off the fold thread; touches
         only the immutable pre-issue snapshots and the closed window's
         shadow, never self.state. `backup` is a full finalize dispatched
         on the pre-reset snapshot — the recovery path when the merge
         fails, mirroring the sync path's finalize fallback."""
-        import time as _time
-
         from ..ops.groupby import apply_int_semantics
         from ..ops.prefinalize import IdentityFinalize
 
@@ -2597,7 +2611,7 @@ class FusedWindowAggNode(Node):
             ((p, s) for p, s in reversed(real) if p.ready()), None,
         ) or (real[0] if real else pipeline[0])
         try:
-            outs, act = self.gb.prefinalize_merge(chosen[0], chosen[1], n_keys)
+            outs, act = self._fetch_and_merge(chosen[0], chosen[1], n_keys)
         except Exception as exc:
             logger.warning("%s: deferred boundary merge failed (%s) — "
                            "recovering from the backup finalize", self.name,
@@ -2616,22 +2630,15 @@ class FusedWindowAggNode(Node):
                     "lost to the sink", self.name, exc2, wr.window_start,
                     wr.window_end)
                 self.stats.inc_exception(f"deferred emit failed: {exc2}")
-                return
+                return 0
         self.last_emit_info = {
             "source": "device-async-late",
             "fetch_ms": (chosen[0].fetch_ms()
                          if hasattr(chosen[0], "fetch_ms")
-                         else (_time.perf_counter() - t_issue) * 1000.0),
+                         else (time.perf_counter() - t_issue) * 1000.0),
             "ages_ms": [],
         }
-        active = np.nonzero(act > 0)[0]
-        if len(active) == 0:
-            return
-        self._count_emit_source()
-        if self.direct_emit is not None:
-            self._emit_direct(outs, active, wr)
-        else:
-            self._emit_grouped(outs, active, wr)
+        return self._emit_active(outs, act, wr)
 
     def _count_emit_source(self) -> None:
         """Bump the cumulative per-source window count from the record
@@ -2640,67 +2647,74 @@ class FusedWindowAggNode(Node):
         self.emit_sources[src] = self.emit_sources.get(src, 0) + 1
 
     def _emit(self, wr: WindowRange) -> None:
+        """Synchronous emission on the calling (fold) thread: the `emit`
+        stage with its `finalize`/`fetch`/`merge` sub-stages."""
         pipeline, self._pipeline = self._pipeline, []
         frozen, self._device_frozen = self._device_frozen, False
         n_keys = self.kt.n_keys
         if n_keys == 0:
             self.last_emit_info = None  # no stale record for empty windows
             return
-        if pipeline:
-            from ..ops.prefinalize import IdentityFinalize
+        with self.stats.stage("emit") as st:
+            if pipeline:
+                outs, act = self._merge_pipeline(pipeline, frozen, n_keys)
+            else:
+                outs, act = self._finalize_sync(n_keys)
+                self.last_emit_info = {"source": "sync", "fetch_ms": 0.0,
+                                       "ages_ms": []}
+            st.rows = self._emit_active(outs, act, wr)
+            if not st.rows:
+                self.last_emit_info = None  # nothing emitted this boundary
 
-            # newest READY pre-issue wins (prefer real device fetches over
-            # the backstop identity); if nothing is ready, wait on the
-            # oldest (its fetch was registered first, it completes first)
-            real = [e for e in pipeline
-                    if not isinstance(e[0], IdentityFinalize)]
-            chosen = next(
-                ((p, s) for p, s in reversed(real) if p.ready()), None,
-            ) or next(
-                ((p, s) for p, s in reversed(pipeline) if p.ready()),
-                pipeline[0],
-            )
-            self._storm = self._backstop_ok and bool(real) and not any(
-                p.ready() for p, _ in real
-            )
-            # engine-clock ms, matching PendingFinalize.t_created — ages
-            # are deterministic under the mock clock
-            now = timex.now_ms()
-            self.last_emit_info = {
-                "source": ("backstop"
-                           if isinstance(chosen[0], IdentityFinalize)
-                           else "device"),
-                "fetch_ms": (chosen[0].fetch_ms()
-                             if hasattr(chosen[0], "fetch_ms") else 0.0),
-                "ages_ms": [float(now - p.t_created)
-                            for p, _ in real if hasattr(p, "t_created")],
-            }
-            try:
-                outs, act = self.gb.prefinalize_merge(
-                    chosen[0], chosen[1], n_keys)
-                if hasattr(chosen[0], "fetch_ms"):
-                    # merge may have blocked on an un-landed fetch; record
-                    # the real issue→landed latency, not the -1 sentinel
-                    self.last_emit_info["fetch_ms"] = chosen[0].fetch_ms()
-            except Exception as exc:
-                logger.warning("prefinalize merge failed, sync fallback: %s", exc)
-                if frozen and real:
-                    self._flush_shadow(real[0][1])
-                outs, act = self.gb.finalize(self.state, n_keys)
-                self.last_emit_info["source"] = "sync"
-        else:
-            outs, act = self.gb.finalize(self.state, n_keys)
-            self.last_emit_info = {"source": "sync", "fetch_ms": 0.0,
-                                   "ages_ms": []}
-        active = np.nonzero(act > 0)[0]
-        if len(active) == 0:
-            self.last_emit_info = None  # nothing emitted this boundary
-            return
-        self._count_emit_source()
-        if self.direct_emit is not None:
-            self._emit_direct(outs, active, wr)
-            return
-        self._emit_grouped(outs, active, wr)
+    def _finalize_sync(self, n_keys: int):
+        """Dispatch the finalize and wait for it here: `finalize`."""
+        with self.stats.span("finalize"):
+            return self.gb.finalize(self.state, n_keys)
+
+    def _merge_pipeline(self, pipeline, frozen: bool, n_keys: int):
+        """Serve a boundary from its pre-issued finalizes."""
+        from ..ops.prefinalize import IdentityFinalize
+
+        # newest READY pre-issue wins (prefer real device fetches over
+        # the backstop identity); if nothing is ready, wait on the
+        # oldest (its fetch was registered first, it completes first)
+        real = [e for e in pipeline
+                if not isinstance(e[0], IdentityFinalize)]
+        chosen = next(
+            ((p, s) for p, s in reversed(real) if p.ready()), None,
+        ) or next(
+            ((p, s) for p, s in reversed(pipeline) if p.ready()),
+            pipeline[0],
+        )
+        self._storm = self._backstop_ok and bool(real) and not any(
+            p.ready() for p, _ in real
+        )
+        # engine-clock ms, matching PendingFinalize.t_created — ages
+        # are deterministic under the mock clock
+        now = timex.now_ms()
+        self.last_emit_info = {
+            "source": ("backstop"
+                       if isinstance(chosen[0], IdentityFinalize)
+                       else "device"),
+            "fetch_ms": (chosen[0].fetch_ms()
+                         if hasattr(chosen[0], "fetch_ms") else 0.0),
+            "ages_ms": [float(now - p.t_created)
+                        for p, _ in real if hasattr(p, "t_created")],
+        }
+        try:
+            outs, act = self._fetch_and_merge(chosen[0], chosen[1], n_keys)
+            if hasattr(chosen[0], "fetch_ms"):
+                # the fetch may have been waited for; record the real
+                # issue→landed latency, not the -1 sentinel
+                self.last_emit_info["fetch_ms"] = chosen[0].fetch_ms()
+        except Exception as exc:
+            logger.warning("prefinalize merge failed, sync fallback: %s",
+                           exc)
+            if frozen and real:
+                self._flush_shadow(real[0][1])
+            outs, act = self._finalize_sync(n_keys)
+            self.last_emit_info["source"] = "sync"
+        return outs, act
 
     def _decode_hh(self, outs):
         """Map heavy_hitters (code, count) pairs back to original values."""
@@ -2719,9 +2733,9 @@ class FusedWindowAggNode(Node):
             outs[i] = dec
         return outs
 
-    def _emit_grouped(self, outs, active: np.ndarray, wr: WindowRange) -> None:
-        """Row-path emit tail: build GroupedTuplesSet for downstream
-        HAVING/ORDER/PROJECT nodes."""
+    def _build_grouped(self, outs, active: np.ndarray, wr: WindowRange):
+        """Row-path emit tail: build the GroupedTuplesSet for downstream
+        HAVING/ORDER/PROJECT nodes; returns (item, count)."""
         outs = self._decode_hh(outs)
         # bulk-convert once (C speed) instead of per-slot numpy scalar access —
         # emit latency is dominated by this host loop at 10k+ groups
@@ -2756,11 +2770,12 @@ class FusedWindowAggNode(Node):
                     group_key=str(key), window_range=wr, agg_values=agg_values,
                 )
             )
-        self.emit(GroupedTuplesSet(groups=groups, window_range=wr))
+        return GroupedTuplesSet(groups=groups, window_range=wr), 1
 
-    def _emit_direct(self, outs, active: np.ndarray, wr: WindowRange) -> None:
+    def _build_direct(self, outs, active: np.ndarray, wr: WindowRange):
         """Vectorized tail: HAVING/ORDER/LIMIT/projection computed over the
-        finalize arrays; emits the final output messages directly."""
+        finalize arrays into the final output messages; returns (item,
+        count), or None when HAVING left nothing."""
         outs = self._decode_hh(outs)
         dim_names = [d.name for d in self.dims]
         dim_cols: Dict[str, np.ndarray] = {}
@@ -2783,18 +2798,15 @@ class FusedWindowAggNode(Node):
             cb = self.direct_emit.run_columnar(
                 dim_cols, agg_cols, wr.window_start, wr.window_end
             )
-            if cb is not None and cb.n:
-                self.emit(cb, count=cb.n)
-            return
+            return (cb, cb.n) if cb is not None and cb.n else None
         msgs = self.direct_emit.run(
             dim_cols, agg_cols, wr.window_start, wr.window_end
         )
-        if msgs:
-            # Fused direct-emit contract: always a list of message dicts,
-            # never a bare dict, so consumers of this path see one shape per
-            # mode (list here, ColumnBatch when emit_columnar) — ref
-            # internal/xsql/collection.go:70, WindowTuples is one type.
-            self.emit(msgs, count=len(msgs))
+        # Fused direct-emit contract: always a list of message dicts,
+        # never a bare dict, so consumers of this path see one shape per
+        # mode (list here, ColumnBatch when emit_columnar) — ref
+        # internal/xsql/collection.go:70, WindowTuples is one type.
+        return (msgs, len(msgs)) if msgs else None
 
     def _flush_shadow(self, shadow) -> None:
         """Fold frozen-span (host-only) rows back into the device state

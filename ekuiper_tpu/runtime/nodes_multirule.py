@@ -71,7 +71,8 @@ class MultiRuleFusedNode(FusedWindowAggNode):
         if n_keys == 0:
             self.last_emit_info = None
             return
-        self._emit_async("mr", self.gb.finalize_begin(self.state, n_keys), wr)
+        self._emit_async(
+            "mr", lambda: self.gb.finalize_begin(self.state, n_keys), wr)
 
     def _deliver_mr(self, arr: np.ndarray, n_keys: int,
                     wr: WindowRange) -> None:
@@ -120,7 +121,7 @@ class MultiRuleFusedNode(FusedWindowAggNode):
                 if msgs:
                     self.stats.inc_out(len(msgs))
                     # Always a list (same emission-type contract as
-                    # FusedWindowAggNode._emit_direct).
+                    # FusedWindowAggNode._build_direct).
                     self.send_to(out_node, msgs)
 
     # ------------------------------------------------------------------ state

@@ -37,28 +37,22 @@ class FilterNode(Node):
         # stage accounting: WHERE evaluation (vectorized or per-row) is
         # "host_expr" — the health plane's bottleneck attribution names
         # host expression eval instead of binning it as "other"
-        import time as _time
-
-        t0 = _time.perf_counter()
         if isinstance(item, ColumnBatch):
-            out = self._filter_batch(item)
-            self.stats.observe_stage(
-                "host_expr", (_time.perf_counter() - t0) * 1e6, item.n)
+            with self.stats.stage("host_expr", item.n):
+                out = self._filter_batch(item)
             if out is not None and out.n > 0:
                 self.emit(out, count=out.n)
             return
         if isinstance(item, WindowTuples):
-            kept = [r for r in item.rows() if self.ev.eval_condition(self.condition, r)]
-            self.stats.observe_stage(
-                "host_expr", (_time.perf_counter() - t0) * 1e6,
-                len(item.rows()))
+            with self.stats.stage("host_expr", len(item.rows())):
+                kept = [r for r in item.rows()
+                        if self.ev.eval_condition(self.condition, r)]
             if kept:
                 self.emit(WindowTuples(content=kept, window_range=item.window_range))
             return
         if isinstance(item, Row):
-            keep = self.ev.eval_condition(self.condition, item)
-            self.stats.observe_stage(
-                "host_expr", (_time.perf_counter() - t0) * 1e6, 1)
+            with self.stats.stage("host_expr", 1):
+                keep = self.ev.eval_condition(self.condition, item)
             if keep:
                 self.emit(item)
             return

@@ -426,40 +426,37 @@ class SharedFoldNode(Node):
         self._fold(item)
 
     def _fold(self, sub: ColumnBatch) -> None:
-        import time as _time
-
-        t0 = _time.perf_counter()
-        slots = self._encode(sub)
-        cols, valid = build_value_columns(self.plan, sub)
-        if self.is_event_time:
-            sub, cols, valid, slots, pane_arg = self._event_panes(
-                sub, cols, valid, slots)
-            if sub is None:
-                return  # every row was late (pane recycled)
-        else:
-            b = self._cur_bucket
-            pane = b % self.n_panes
-            held = self._pane_bucket.get(pane)
-            if held is not None and held != b:
-                # safety net — rotation resets ahead of reuse normally
-                self.store.reset_pane(pane)
-                self._dirty.discard(held)
-            self._pane_bucket[pane] = b
-            self._dirty.add(b)
-            pane_arg = pane
-        dev = self._device_inputs(sub, cols, valid, slots)
-        t1 = _time.perf_counter()
-        self.stats.observe_stage("upload", (t1 - t0) * 1e6, sub.n)
-        if dev is not None:
-            dcols, dvalid, dslots = dev
-            self.store.fold({**cols, **dcols},
-                            {**valid, **dvalid},
-                            dslots if dslots is not None else slots,
-                            pane_arg, n_rows=sub.n)
-        else:
-            self.store.fold(cols, valid, slots, pane_arg)
-        self.stats.observe_stage(
-            "fold", (_time.perf_counter() - t1) * 1e6, sub.n)
+        with self.stats.stage("upload", sub.n) as st:
+            slots = self._encode(sub)
+            cols, valid = build_value_columns(self.plan, sub)
+            if self.is_event_time:
+                sub, cols, valid, slots, pane_arg = self._event_panes(
+                    sub, cols, valid, slots)
+                if sub is None:
+                    st.counted = False  # nothing left to upload:
+                    return  # every row was late (pane recycled)
+            else:
+                b = self._cur_bucket
+                pane = b % self.n_panes
+                held = self._pane_bucket.get(pane)
+                if held is not None and held != b:
+                    # safety net — rotation resets ahead of reuse normally
+                    self.store.reset_pane(pane)
+                    self._dirty.discard(held)
+                self._pane_bucket[pane] = b
+                self._dirty.add(b)
+                pane_arg = pane
+            dev = self._device_inputs(sub, cols, valid, slots)
+            st.rows = sub.n
+        with self.stats.stage("fold", sub.n):
+            if dev is not None:
+                dcols, dvalid, dslots = dev
+                self.store.fold({**cols, **dcols},
+                                {**valid, **dvalid},
+                                dslots if dslots is not None else slots,
+                                pane_arg, n_rows=sub.n)
+            else:
+                self.store.fold(cols, valid, slots, pane_arg)
         if hasattr(self.store.gb, "note_rows"):
             # per-shard accounting (kuiper_shard_*): the kernel counts
             # host slot vectors itself; the prep path hands it DEVICE
@@ -745,8 +742,6 @@ class SharedFoldNode(Node):
         folds land in between, state is unchanged): members sharing a live
         pane set reuse one finalize+transfer, and the key table decodes
         once per dispatch instead of once per member."""
-        import time as _time
-
         n_keys = self.store.kt.n_keys
         if b_hi is None:
             b_hi = (end_ms - 1) // self.pane_ms
@@ -762,72 +757,70 @@ class SharedFoldNode(Node):
                 and self._pane_bucket.get(b % self.n_panes) == b]
         if n_keys == 0 or not live:
             return  # empty window: no device round trip, no emission
-        t0 = _time.perf_counter()
-        panes = sorted({b % self.n_panes for b in live})
-        ckey = ("combine", tuple(panes), n_keys)
-        if cache is not None and ckey in cache:
-            outs, act = cache[ckey]
-        else:
-            outs, act = self.store.combine(panes, n_keys)
-            if cache is not None:
-                cache[ckey] = (outs, act)
-        if m.spec.act_idx is not None:
-            # predicate-lifted member: group existence is this member's
-            # own `count(*) FILTER(WHERE <pred>)` column — a key whose
-            # rows all failed the member's predicate must not emit a
-            # group (byte parity with the private plan's post-WHERE act)
-            # kuiperlint: ignore[host-sync]: `outs` are HOST numpy arrays (store.combine already fetched+sliced them) — no device value in reach
-            act = np.asarray(outs[m.spec_map[int(m.spec.act_idx)]])
-        active = np.nonzero(act > 0)[0]
-        n_groups = len(active)
-        if n_groups:
-            wr = WindowRange(end_ms - m.spec.length_ms, end_ms)
-            dim_cols: Dict[str, np.ndarray] = {}
-            if self.dims:
-                if cache is not None:
-                    keys = cache.get("__keys__")
-                    if keys is None:
-                        keys = cache["__keys__"] = \
-                            self.store.kt.decode_all()
-                else:
-                    keys = self.store.kt.decode_all()
-                if len(self.dims) == 1:
-                    col = np.empty(n_groups, dtype=np.object_)
-                    col[:] = [keys[s] for s in active.tolist()]
-                    dim_cols[self.dims[0]] = col
-                else:
-                    sel = [keys[s] for s in active.tolist()]
-                    for i, dn in enumerate(self.dims):
-                        col = np.empty(n_groups, dtype=np.object_)
-                        col[:] = [k[i] for k in sel]
-                        dim_cols[dn] = col
-            agg_cols = [outs[u][active] for u in m.spec_map]
-            if m.spec.emit_columnar:
-                payload = m.spec.direct_emit.run_columnar(
-                    dim_cols, agg_cols, wr.window_start, wr.window_end)
-                count = payload.n if payload is not None else 0
-            else:
-                payload = m.spec.direct_emit.run(
-                    dim_cols, agg_cols, wr.window_start, wr.window_end)
-                count = len(payload) if payload else 0
-            if count:
-                # ingest→emit provenance (the PR 3 SLO layer): stamp the
-                # freshest contributing batch's ingest time, exactly what
-                # Node.emit() would do — send_to alone doesn't stamp, and
-                # an unstamped window never records an e2e sample at the
-                # member's sink
-                from .node import _stamp_ingest_ms
-
-                if self._cur_ingest_ms is not None:
-                    _stamp_ingest_ms(payload, self._cur_ingest_ms)
-                self.stats.inc_out(count)
-                self.send_to(m.entry, payload)
-            self.windows_emitted += 1
         # per-rule emit-combine latency, attributed under rule="__shared__"
         # (this node renders there) with the member in the stage label
-        self.stats.observe_stage(
-            f"emit[{m.spec.rule_id}]",
-            (_time.perf_counter() - t0) * 1e6, n_groups)
+        with self.stats.stage(f"emit[{m.spec.rule_id}]") as st:
+            panes = sorted({b % self.n_panes for b in live})
+            ckey = ("combine", tuple(panes), n_keys)
+            if cache is not None and ckey in cache:
+                outs, act = cache[ckey]
+            else:
+                outs, act = self.store.combine(panes, n_keys)
+                if cache is not None:
+                    cache[ckey] = (outs, act)
+            if m.spec.act_idx is not None:
+                # predicate-lifted member: group existence is this member's
+                # own `count(*) FILTER(WHERE <pred>)` column — a key whose
+                # rows all failed the member's predicate must not emit a
+                # group (byte parity with the private plan's post-WHERE act)
+                # kuiperlint: ignore[host-sync]: `outs` are HOST numpy arrays (store.combine already fetched+sliced them) — no device value in reach
+                act = np.asarray(outs[m.spec_map[int(m.spec.act_idx)]])
+            active = np.nonzero(act > 0)[0]
+            n_groups = len(active)
+            if n_groups:
+                wr = WindowRange(end_ms - m.spec.length_ms, end_ms)
+                dim_cols: Dict[str, np.ndarray] = {}
+                if self.dims:
+                    if cache is not None:
+                        keys = cache.get("__keys__")
+                        if keys is None:
+                            keys = cache["__keys__"] = \
+                                self.store.kt.decode_all()
+                    else:
+                        keys = self.store.kt.decode_all()
+                    if len(self.dims) == 1:
+                        col = np.empty(n_groups, dtype=np.object_)
+                        col[:] = [keys[s] for s in active.tolist()]
+                        dim_cols[self.dims[0]] = col
+                    else:
+                        sel = [keys[s] for s in active.tolist()]
+                        for i, dn in enumerate(self.dims):
+                            col = np.empty(n_groups, dtype=np.object_)
+                            col[:] = [k[i] for k in sel]
+                            dim_cols[dn] = col
+                agg_cols = [outs[u][active] for u in m.spec_map]
+                if m.spec.emit_columnar:
+                    payload = m.spec.direct_emit.run_columnar(
+                        dim_cols, agg_cols, wr.window_start, wr.window_end)
+                    count = payload.n if payload is not None else 0
+                else:
+                    payload = m.spec.direct_emit.run(
+                        dim_cols, agg_cols, wr.window_start, wr.window_end)
+                    count = len(payload) if payload else 0
+                if count:
+                    # ingest→emit provenance (the PR 3 SLO layer): stamp the
+                    # freshest contributing batch's ingest time, exactly what
+                    # Node.emit() would do — send_to alone doesn't stamp, and
+                    # an unstamped window never records an e2e sample at the
+                    # member's sink
+                    from .node import _stamp_item
+
+                    if self._cur_ingest_ms is not None:
+                        _stamp_item(payload, self._cur_ingest_ms)
+                    self.stats.inc_out(count)
+                    self.send_to(m.entry, payload)
+                self.windows_emitted += 1
+            st.rows = n_groups
 
     # ------------------------------------------------------------------ state
     def snapshot_state(self) -> Optional[dict]:

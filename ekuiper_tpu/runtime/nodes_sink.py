@@ -8,13 +8,14 @@ templates: {{.field}} substitution), sendSingle splitting, omitIfEmpty.
 from __future__ import annotations
 
 import re
+import time as _time
 from typing import Any, Dict, List, Optional
 
 from ..data.batch import ColumnBatch
 from ..data.rows import GroupedTuplesSet, Row, Tuple, WindowTuples
 from ..utils import timex
 from ..utils.infra import logger
-from .node import Node, _item_ingest_ms
+from .node import Node, _item_stamp
 
 _TMPL_RE = re.compile(r"\{\{\s*\.(\w+)\s*\}\}")
 
@@ -80,6 +81,7 @@ class SinkNode(Node):
         self.retry_count = retry_count
         self.retry_interval_ms = retry_interval_ms
         self._current: Any = None  # item being processed (cache ack/nack key)
+        self._retries = 0  # failed collects of the item being delivered
         self.results: List[Any] = []  # test/trial access
 
     def on_open(self) -> None:
@@ -97,6 +99,19 @@ class SinkNode(Node):
         # ack/nack to the cache always reference the PRE-transform item the
         # cache emitted, so its in-flight tracking matches on resends
         self._current = item
+        with self.stats.stage("sink") as st:
+            st.rows = self._deliver(item)
+        handed = self._cur_boundary_ns
+        if handed is not None and self._topo is not None:
+            # the boundary's last phase: handed downstream by the window
+            # node -> collect returned (queue wait, convert, deliver)
+            self._topo.observe_boundary(
+                "sink", (_time.perf_counter_ns() - handed) / 1000.0)
+
+    def _deliver(self, item: Any) -> int:
+        """Convert (`convert` sub-stage) and deliver (`deliver`) one item;
+        returns the messages delivered."""
+        single = False
         if (isinstance(item, ColumnBatch) and item.n
                 and getattr(self.sink, "accepts_batches", False)
                 and not (self.send_single or self.fields
@@ -105,37 +120,44 @@ class SinkNode(Node):
             # emission as-is — no per-row dict materialization (at 250+
             # rules x thousands of keys per boundary that conversion is
             # seconds of host time)
-            self._collect(item)
-            return
-        if isinstance(item, (bytes, bytearray, str)):
+            payload, n = item, item.n
+        elif isinstance(item, (bytes, bytearray, str)):
             # opaque payloads: post-encode/compress bytes, rendered template
             # strings — pass through untransformed
             # (reference: bytes-collector sink variant, sink_node.go:197)
-            self._collect(bytes(item) if isinstance(item, (bytes, bytearray))
-                          else item)
-            return
-        msgs = self._to_messages(item)
-        if not msgs and self.omit_if_empty:
-            return
-        msgs = [self._transform(m) for m in msgs]
-        if self.send_single:
-            # the cache tracks the PRE-split item: ack only after every
-            # message lands, and stop on the first nack so the whole item is
-            # parked exactly once (resend replays it from the start)
-            for m in msgs:
-                if not self._collect(m, ack=False):
-                    return
-            if self.cache_node is not None:
-                self.cache_node.ack(self._current)
+            payload = (bytes(item) if isinstance(item, (bytes, bytearray))
+                       else item)
+            n = 1
         else:
-            self._collect(msgs if len(msgs) != 1 else msgs[0])
+            with self.stats.span("convert") as sp:
+                msgs = [self._transform(m) for m in self._to_messages(item)]
+                sp.rows = n = len(msgs)
+            if not msgs and self.omit_if_empty:
+                return 0
+            single = self.send_single
+            payload = msgs if single or n != 1 else msgs[0]
+        with self.stats.span("deliver", n) as sp:
+            self._retries = 0
+            if single:
+                # the cache tracks the PRE-split item: ack only after every
+                # message lands, and stop on the first nack so the whole
+                # item is parked exactly once (resend replays it from the
+                # start)
+                if all(self._collect(m, ack=False) for m in payload) \
+                        and self.cache_node is not None:
+                    self.cache_node.ack(self._current)
+            else:
+                self._collect(payload)
+            if self._retries:
+                sp.attrs = {"retries": self._retries}
+        return n
 
     def _observe_e2e(self, item: Any) -> None:
         """Record the ingest→emit latency sample for items carrying their
         source ingest stamp (runtime/node.py provenance propagation) into
         the rule's end-to-end histogram — the paper's SLO (p99 emit < 50ms)
         measured where the result actually leaves the engine."""
-        ing = _item_ingest_ms(item)
+        ing = _item_stamp(item)
         if ing is None:
             return
         lat_ms = max(timex.now_ms() - ing, 0)
@@ -143,7 +165,7 @@ class SinkNode(Node):
         observe = getattr(topo, "observe_e2e", None)
         if observe is not None:
             observe(lat_ms)
-        if getattr(self, "_tracing_now", False):
+        if self._tracing_now:
             self._span_attrs = {"e2e_ms": lat_ms}
 
     def _to_messages(self, item: Any) -> List[Dict[str, Any]]:
@@ -167,6 +189,7 @@ class SinkNode(Node):
                 return True
             except Exception as exc:
                 attempts += 1
+                self._retries += 1
                 self.stats.inc_exception(str(exc))
                 if attempts > self.retry_count:
                     if self.cache_node is not None:

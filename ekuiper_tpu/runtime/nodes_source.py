@@ -138,7 +138,18 @@ class SourceNode(Node):
     def ingest(self, payload: Any, metadata: Optional[Dict[str, Any]] = None) -> None:
         """Connector callback: raw bytes (decoded here via the stream's
         FORMAT converter), a LIST of raw bytes payloads (a broker drain —
-        batch-decoded), dict, list of dicts, or Tuple."""
+        batch-decoded), dict, list of dicts, or Tuple. It runs on the
+        connector's thread, outside the node's dispatch loop, so under a
+        traced rule it opens the source's own span: the root of the trace
+        its rows travel in."""
+        span = self._span_begin(payload)
+        try:
+            self._ingest(payload, metadata)
+        finally:
+            if span is not None:
+                self._span_end(span)
+
+    def _ingest(self, payload: Any, metadata: Optional[Dict[str, Any]]) -> None:
         now = timex.now_ms()
         if self._fast_spec is not None and self.emit_batches:
             raws = None
@@ -238,8 +249,14 @@ class SourceNode(Node):
         """Timer-driven flush: stays micro-batch-aligned under sustained
         ingest (a large pending still emits exact micro_batch slices; only
         a sub-micro-batch tail flushes whole) and re-arms while a
-        remainder is pending so it drains within another linger period."""
-        self._flush(final=False)
+        remainder is pending so it drains within another linger period.
+        No item caused it, so under a traced rule it is a root span."""
+        span = self._span_begin(None, kind="LingerTimer")
+        try:
+            self._flush(final=False)
+        finally:
+            if span is not None:
+                self._span_end(span)
         with self._pending_lock:
             leftover = bool(self._pending_msgs or self._pending_raw)
         if leftover:
@@ -340,8 +357,33 @@ class SourceNode(Node):
         decoding) — the barrier path fails its checkpoint on that. The
         drain runs OUTSIDE the pending lock: appending new rows needs
         nothing from the ring, and a held lock would stall every
-        connector callback for the drain's duration."""
-        msgs = raws = None
+        connector callback for the drain's duration.
+
+        Cutting the micro-batch and handing it to the decode pool is the
+        `ingest` stage: the engine's work on the CALLER's thread (a
+        publisher's, for the memory bus; the linger timer's). The appends
+        between two flushes (a list extend each) are not timed: a stage
+        per connector call would cost more than the append it times."""
+        with self.stats.stage("ingest") as st:
+            inline, st.rows = self._hand_over(final)
+        for job in inline:
+            self._emit_decoded(self._decode_job(job))
+        if final and self._pool is not None:
+            if not self._pool.drain():
+                logger.error(
+                    "source %s: decode ring drain timed out on a final "
+                    "flush; decoded batches may trail stream-end events",
+                    self.name)
+                return False
+        return True
+
+    def _hand_over(self, final: bool) -> tuple:
+        """Take the pending rows as decode jobs and submit them to the
+        decode pool (which blocks while its ring is full: the backpressure
+        toward the connector). Returns (the jobs the caller must decode
+        inline — all of them without a pool — AFTER its `ingest` stage has
+        closed, so `decode` never nests inside it; the rows taken)."""
+        jobs = []
         with self._pending_lock:
             if self._pending_msgs or self._pending_raw:
                 msgs, self._pending_msgs = self._pending_msgs, []
@@ -360,18 +402,21 @@ class SourceNode(Node):
                     self._pending_raw = raws[cut:]
                     self._pending_raw_ts = rtss[cut:]
                     raws, rtss = raws[:cut], rtss[:cut]
-        if msgs:
-            self._dispatch_job(("msgs", msgs, tss))
-        if raws:
-            self._dispatch_job(("raw", raws, rtss))
-        if final and self._pool is not None:
-            if not self._pool.drain():
-                logger.error(
-                    "source %s: decode ring drain timed out on a final "
-                    "flush; decoded batches may trail stream-end events",
-                    self.name)
-                return False
-        return True
+                if msgs:
+                    jobs.append(("msgs", msgs, tss))
+                if raws:
+                    jobs.append(("raw", raws, rtss))
+        n_rows = sum(len(job[1]) for job in jobs)
+        if self.decode_pool_size <= 0:
+            return jobs, n_rows
+        # BOTH job kinds go through the ring when the pool is on, so a msg
+        # batch can never overtake an earlier raw batch still decoding
+        for i, job in enumerate(jobs):
+            try:
+                self._ensure_pool().submit(job)
+            except RuntimeError:
+                return jobs[i:], n_rows  # pool closed (shutdown race)
+        return [], n_rows
 
     def _ensure_pool(self):
         from .ingest import DecodePool
@@ -394,13 +439,9 @@ class SourceNode(Node):
         share-cache hits. Accrues to THIS node's `upload` stage — together
         with the fused node's (now residual) `upload` timing the pipeline
         balance stays observable per node."""
-        import time as _time
-
-        t0 = _time.perf_counter()
-        n_up = self.prep_ctx.precompute(batch)
-        if n_up:
-            self.stats.observe_stage(
-                "upload", (_time.perf_counter() - t0) * 1e6, batch.n)
+        with self.stats.stage("upload", batch.n) as st:
+            # nothing registered to build: no row of the stage for it
+            st.counted = bool(self.prep_ctx.precompute(batch))
 
     def pool_depths(self):
         """(ring occupancy, decode queue depth) for the Prometheus gauges;
@@ -444,20 +485,6 @@ class SourceNode(Node):
         if self.prep_ctx is not None:
             self.prep_ctx.register_tier_prefetch(fn)
 
-    def _dispatch_job(self, job) -> None:
-        """Decode+emit one flush unit: on the decode pool when configured
-        (shard-parallel native parse off the connector thread, ordered
-        ring emission — runtime/ingest.py), else inline as before. BOTH
-        job kinds go through the ring when the pool is on, so a msg batch
-        can never overtake an earlier raw batch still decoding."""
-        if self.decode_pool_size > 0:
-            try:
-                self._ensure_pool().submit(job)
-                return
-            except RuntimeError:
-                pass  # pool closed (shutdown race): decode inline
-        self._emit_decoded(self._decode_job(job))
-
     def _emit_decoded(self, batch: Optional[ColumnBatch]) -> None:
         if batch is not None and batch.n:
             self.emit(batch, count=batch.n)
@@ -466,25 +493,22 @@ class SourceNode(Node):
         """One decode unit: ("raw", payloads, tss) | ("msgs", msgs, tss)
         -> ColumnBatch | None. Runs on pool workers — touches only
         immutable config, the converter, and the (locked) StatManager."""
-        import time as _time
-
         from ..data.batch import from_messages
 
         kind, items, tss = job
-        t0 = _time.perf_counter()
-        if kind == "raw":
-            batch = self._decode_raw_to_batch(items, tss)
-        else:
-            batch, n_drop = from_messages(
-                items, tss, schema=self.schema, emitter=self.name,
-                strict=self.strict, timestamp_field=self.timestamp_field,
-                on_error=self.stats.inc_exception,
-                project=self.project_columns)
-            if n_drop:
-                logger.debug("source %s dropped %d rows at columnarize",
-                             self.name, n_drop)
-        self.stats.observe_stage(
-            "decode", (_time.perf_counter() - t0) * 1e6, len(items))
+        with self.stats.stage("decode", len(items)):
+            if kind == "raw":
+                batch = self._decode_raw_to_batch(items, tss)
+            else:
+                batch, n_drop = from_messages(
+                    items, tss, schema=self.schema, emitter=self.name,
+                    strict=self.strict,
+                    timestamp_field=self.timestamp_field,
+                    on_error=self.stats.inc_exception,
+                    project=self.project_columns)
+                if n_drop:
+                    logger.debug("source %s dropped %d rows at columnarize",
+                                 self.name, n_drop)
         if batch is not None and batch.ingest_ms is None and tss:
             # e2e provenance: the batch speaks for its OLDEST row (arrival
             # order == tss order), so micro-batch linger and every later

@@ -17,6 +17,10 @@ from .events import Barrier
 from .node import Node
 
 
+#: phases of a window boundary, in the order they happen
+BOUNDARY_PHASES = ("trigger_delay", "emit", "sink")
+
+
 class Topo:
     def __init__(self, rule_id: str, qos: int = 0, checkpoint_interval_ms: int = 300_000) -> None:
         self.rule_id = rule_id
@@ -41,6 +45,13 @@ class Topo:
         # Prometheus layer exports it as the kuiper_rule_e2e_latency_ms
         # histogram, the status JSON as a p50/p90/p99/max summary
         self.e2e_hist = LatencyHistogram()
+        # the engine's side of a window boundary, by phase (µs; rendered as
+        # the kuiper_boundary_ms histogram and as p50/p95 in the status):
+        # trigger_delay — the Trigger's ts → its dispatch on the window
+        # node; emit — that dispatch → the window's result handed
+        # downstream; sink — handed downstream → the sink's collect
+        # returned. A count window has no Trigger and records the last two.
+        self.boundary_hists = {p: LatencyHistogram() for p in BOUNDARY_PHASES}
 
     # ------------------------------------------------------------------ wiring
     def add_source(self, node: Node) -> Node:
@@ -117,6 +128,11 @@ class Topo:
     def observe_e2e(self, lat_ms: int) -> None:
         """One ingest→emit latency sample (ms), recorded by sink nodes."""
         self.e2e_hist.record(lat_ms)
+
+    def observe_boundary(self, phase: str, us: float) -> None:
+        """One boundary's time in `phase` (µs), recorded by the window
+        node (trigger_delay, emit) and the sink (sink)."""
+        self.boundary_hists[phase].record(us)
 
     # --------------------------------------------------------------- lifecycle
     def open(self) -> None:
@@ -205,6 +221,14 @@ class Topo:
                     "_emit_sources"] = dict(srcs)
         # rule-level SLO summary: the ingest→emit distribution percentiles
         out["e2e_latency_ms"] = self.e2e_hist.snapshot()
+        # ... and its engine-side phases per window boundary, ms
+        out["boundary_ms"] = {}
+        for phase, hist in self.boundary_hists.items():
+            if hist.count:
+                p50, p95 = hist.percentiles([50, 95])
+                out["boundary_ms"][phase] = {
+                    "count": hist.count, "p50": p50 / 1000.0,
+                    "p95": p95 / 1000.0}
         # engine-health views (observability/devwatch.py): per-op XLA
         # trace-vs-cache-hit counts — a steady-state rule should show
         # compiles flat while cache_hits climb; anything else is paying

@@ -9,25 +9,75 @@ from __future__ import annotations
 
 import threading
 import time as _time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, Optional
 
 from ..observability.histogram import LatencyHistogram
+from ..observability.tracer import Tracer
 from . import timex
+
+# jax.profiler.TraceAnnotation, bound at the first stage: importing this
+# module must not import jax, and a per-call import costs a stage 1 us
+_TraceAnnotation = None
+
+
+class _Stage:
+    """One timed piece of work, opened by `StatManager.stage()`/`span()`
+    where the work happens. Its body runs inside a profiler annotation
+    `kuiper:<name>` (free without a profiler session, an event on the
+    profiler's host plane with one); on exit a counted stage accrues wall
+    and thread-CPU microseconds, a call and `rows` to the node's stage
+    table, and under a traced dispatch it is a child span of whatever
+    span is open on the thread. `rows` may be set inside the body when
+    the count is known only afterwards."""
+
+    __slots__ = ("sm", "name", "rows", "attrs", "counted", "_ann", "_span",
+                 "_t0", "_c0")
+
+    def __init__(self, sm: "StatManager", name: str, rows: int,
+                 attrs: Optional[dict], counted: bool) -> None:
+        self.sm = sm
+        self.name = name
+        self.rows = rows
+        self.attrs = attrs
+        self.counted = counted
+
+    def __enter__(self) -> "_Stage":
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        sm = self.sm
+        self._ann = _TraceAnnotation(
+            "kuiper:" + self.name, rule=sm.rule_id, op=sm.op_id,
+            rows=self.rows)
+        self._ann.__enter__()
+        tracer = Tracer._instance
+        self._span = (
+            tracer.begin(sm.rule_id, sm.op_id, "stage", self.rows,
+                         stage=self.name)
+            if tracer is not None and tracer.current() is not None
+            and tracer.is_enabled(sm.rule_id) else None)
+        if self.counted:
+            # wall read outside the CPU read on both ends: the CPU interval
+            # lies inside the wall interval, so cpu <= wall per call. (The
+            # CPU clock is a system call; a sub-stage does not pay for it.)
+            self._t0 = _time.perf_counter_ns()
+            self._c0 = _time.thread_time_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self.counted:
+            cpu_ns = _time.thread_time_ns() - self._c0
+            wall_ns = _time.perf_counter_ns() - self._t0
+            self.sm.observe_stage(self.name, wall_ns // 1000, self.rows,
+                                  cpu_ns // 1000)
+        span = self._span
+        if span is not None:
+            span.rows = self.rows
+            span.end(self.attrs)
+        self._ann.__exit__(exc_type, exc, tb)
 
 
 class StatManager:
-    METRIC_NAMES = (
-        "records_in_total",
-        "records_out_total",
-        "messages_processed_total",
-        "process_latency_us",
-        "buffer_length",
-        "last_invocation",
-        "exceptions_total",
-        "last_exception",
-        "last_exception_time",
-    )
-
     def __init__(self, op_type: str, op_id: str, instance: int = 0) -> None:
         self.op_type = op_type
         self.op_id = op_id
@@ -55,8 +105,15 @@ class StatManager:
         # per-rule CPU-usage proxy (reference: /rules/usage/cpu)
         self.process_time_us_total: int = 0
         self.buffer_length: int = 0
+        # between the stages (runtime/node.py): time this node's worker
+        # waited in its empty input queue, and time senders waited in this
+        # node's put for room. Neither is busy time, so neither is a stage.
+        self.idle_us_total: int = 0  # one writer: the node's worker
+        self.backpressure_us_total: int = 0  # many writers: under _lock
         self._started_at: Optional[int] = None
-        self._started_perf: float = 0.0
+        # perf-clock ns at which the current dispatch began (a window node
+        # starts its boundary's `emit` phase from it)
+        self.started_perf_ns: int = 0
         # named pipeline-stage accounting (decode/upload/fold, ...): lets
         # operators see where ingest wall time goes per node — the balance
         # of the sharded ingest pipeline is tuned from these
@@ -113,10 +170,6 @@ class StatManager:
         with self._lock:
             self.records_out += n
 
-    def inc_processed(self, n: int = 1) -> None:
-        with self._lock:
-            self.messages_processed += n
-
     def inc_exception(self, err: str, n: int = 1) -> None:
         now = timex.now_ms()  # before the lock — see inc_in
         with self._lock:
@@ -152,11 +205,11 @@ class StatManager:
 
     def process_begin(self) -> None:
         self._started_at = timex.now_ms()
-        self._started_perf = _time.perf_counter()
+        self.started_perf_ns = _time.perf_counter_ns()
 
     def process_end(self) -> None:
         if self._started_at is not None:
-            busy_us = int((_time.perf_counter() - self._started_perf) * 1e6)
+            busy_us = (_time.perf_counter_ns() - self.started_perf_ns) // 1000
             now = timex.now_ms()  # before the lock — see inc_in
             with self._lock:
                 # latency follows the engine clock (mock-deterministic in
@@ -164,6 +217,7 @@ class StatManager:
                 # counter — sub-ms work must still accrue
                 self.process_latency_us = (now - self._started_at) * 1000
                 self.process_time_us_total += busy_us
+                self.messages_processed += 1
             self.proc_hist.record(busy_us)
             self._started_at = None
 
@@ -175,17 +229,39 @@ class StatManager:
         with self._lock:
             self.buffer_length = n
 
-    def observe_stage(self, stage: str, us: int, rows: int = 0) -> None:
-        """Accrue `us` microseconds (and optionally rows) to a named
-        pipeline stage. Cheap enough for per-batch calls."""
+    def observe_stage(self, stage: str, us: int, rows: int = 0,
+                      cpu_us: int = 0) -> None:
+        """Accrue `us` wall microseconds (and optionally rows and thread-CPU
+        microseconds) to a named pipeline stage. Cheap enough for per-batch
+        calls; `stage()` is the way to call it around work done here."""
         with self._lock:
             st = self.stages.get(stage)
             if st is None:
                 st = self.stages[stage] = {
-                    "calls": 0, "total_us": 0, "rows": 0}
+                    "calls": 0, "total_us": 0, "rows": 0, "cpu_us": 0}
             st["calls"] += 1
             st["total_us"] += int(us)
             st["rows"] += int(rows)
+            st["cpu_us"] += int(cpu_us)
+
+    def stage(self, name: str, rows: int = 0, **attrs) -> _Stage:
+        """Context manager around one run of pipeline stage `name`: counters
+        (wall, CPU, calls, rows), a child span under a traced dispatch, and
+        a `kuiper:<name>` annotation in a profiler capture. Stages of one
+        node must not nest: the health plane sums a node's stage rows as
+        its covered busy time — time a piece inside a stage with span()."""
+        return _Stage(self, name, rows, attrs or None, True)
+
+    def span(self, name: str, rows: int = 0, **attrs) -> _Stage:
+        """A sub-stage: the span and the profiler annotation of stage()
+        without a counter row of its own."""
+        return _Stage(self, name, rows, attrs or None, False)
+
+    def add_backpressure(self, us: int) -> None:
+        """A sender waited `us` microseconds for room in this node's queue
+        (called on the sender's thread)."""
+        with self._lock:
+            self.backpressure_us_total += int(us)
 
     def health_sample(self) -> Dict[str, Any]:
         """Cheap cumulative counters for the health evaluator's per-tick
@@ -226,6 +302,8 @@ class StatManager:
                 "messages_processed_total": self.messages_processed,
                 "process_latency_us": self.process_latency_us,
                 "process_time_us_total": self.process_time_us_total,
+                "idle_us_total": self.idle_us_total,
+                "backpressure_us_total": self.backpressure_us_total,
                 "buffer_length": self.buffer_length,
                 "last_invocation": self.last_invocation,
                 "exceptions_total": self.exceptions,
@@ -239,10 +317,6 @@ class StatManager:
         out["process_latency_us_hist"] = self.proc_hist.snapshot()
         out["queue_wait_us_hist"] = self.queue_hist.snapshot()
         return out
-
-    def metrics_list(self) -> List[Any]:
-        snap = self.snapshot()
-        return [snap[name] for name in self.METRIC_NAMES]
 
 
 def flatten_status(stats: Dict[str, StatManager]) -> Dict[str, Any]:

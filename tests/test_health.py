@@ -33,6 +33,7 @@ class FakeNode:
 class FakeTopo:
     def __init__(self, nodes):
         self.e2e_hist = LatencyHistogram()
+        self.boundary_hists = {}
         self._nodes = nodes
 
     def all_nodes(self):

@@ -524,6 +524,7 @@ class TestPrepUploadStage:
         class FakeTopo:
             from ekuiper_tpu.observability.histogram import LatencyHistogram
             e2e_hist = LatencyHistogram()
+            boundary_hists = {}
 
             def live_shared(self):
                 return []
@@ -570,4 +571,5 @@ class TestStagePrometheus:
         sm.observe_stage("decode", 1500, rows=100)
         sm.observe_stage("decode", 500, rows=50)
         snap = sm.snapshot()["stage_timings"]["decode"]
-        assert snap == {"calls": 2, "total_us": 2000, "rows": 150}
+        assert snap == {"calls": 2, "total_us": 2000, "rows": 150,
+                        "cpu_us": 0}

@@ -106,15 +106,23 @@ class TestTracing:
         assert chain is not None, {
             t: [s["op"] for s in spans] for t, spans in by_trace.items()}
         assert len({s["traceId"] for s in chain}) == 1
-        assert all(s["rule"] == "tr1" for s in chain)
-        assert all(s["rows"] == 1 for s in chain)
+        # the shared source's spans (rule="__shared__") open the trace the
+        # rule's own nodes continue: one root, every other span names a
+        # parent that is in the trace
+        assert {s["rule"] for s in chain} == {"tr1", "__shared__"}
+        assert all(s["rows"] == 1 for s in chain if s["rule"] == "tr1")
+        ids = {s["spanId"] for s in chain}
+        assert [s["op"] for s in chain if not s["parentSpanId"]] == ["demo"]
+        assert all(s["parentSpanId"] in ids
+                   for s in chain if s["parentSpanId"])
         assert req("POST", "/rules/tr1/trace/stop") == \
             "Tracing disabled for rule tr1."
         assert not fresh_tracer.is_enabled("tr1")
 
     def test_disabled_rules_record_nothing(self, fresh_tracer):
         fresh_tracer.enable("other")
-        fresh_tracer.record("other", "op1", 1, 10, "Tuple", 1)
+        fresh_tracer.begin("other", "op1", "Tuple", 1).end()
+        fresh_tracer.begin("not_enabled", "op1", "Tuple", 1).end()
         assert fresh_tracer.rule_spans("other")
         assert fresh_tracer.rule_spans("not_enabled") == []
 
@@ -124,24 +132,26 @@ class TestTracing:
         the bounded fallback map."""
         t = fresh_tracer
         t.enable("r")
-        tid = t.new_trace()
+        span = t.begin("r", "project", "list", 3)
         item = {"deviceId": "a", "temperature": 1.0}
         t.tag(item)
         rows = [1, 2, 3]
         t.tag(rows)
-        t.set_current(None)  # the receiving node's worker: fresh context
-        assert t.lookup(item) == tid
-        assert t.lookup(rows) == tid
+        span.end()  # the receiving node's worker has a fresh context
+        assert t.current() is None
+        assert t.lookup(item) == (span.trace_id, span.span_id)
+        assert t.lookup(rows) == (span.trace_id, span.span_id)
 
     def test_fallback_map_bounded_eviction(self, fresh_tracer):
         t = fresh_tracer
         t.enable("r")
-        t.new_trace()
+        span = t.begin("r", "project", "list", 0)
         first = {"k": 0}
         t.tag(first)
         keep_alive = [{"k": i} for i in range(t.FALLBACK_CAP)]
         for d in keep_alive:
             t.tag(d)
+        span.end()
         assert len(t._fallback_traces) <= t.FALLBACK_CAP
         assert t.lookup(first) is None  # oldest evicted, newest retained
         assert t.lookup(keep_alive[-1]) is not None
@@ -151,18 +161,18 @@ class TestTracing:
 
         t = fresh_tracer
         t.enable("r")
-        t.record("r", "sink", 5, 100, "list", 2, attrs={"e2e_ms": 17})
+        t.begin("r", "sink", "list", 2).end({"e2e_ms": 17})
         span = [s for s in t.rule_spans("r") if s["op"] == "sink"][0]
         assert span["attributes"] == {"e2e_ms": 17}
         plain = t.rule_spans("r")
         # attribute-less spans omit the key (legacy dict/bytes unchanged)
-        t.record("r", "op", 5, 100, "Tuple", 1)
+        t.begin("r", "op", "Tuple", 1).end()
         plain = [s for s in t.rule_spans("r") if s["op"] == "op"][0]
         assert "attributes" not in plain
 
         class S:  # minimal span shape for the encoder
             trace_id, span_id, parent_id = "t1", "s1", ""
-            rule_id, op, start_ms, duration_us = "r", "sink", 5, 100
+            rule_id, op, start_ns, duration_us = "r", "sink", 5, 100
             kind, rows = "list", 2
             attrs = None
 

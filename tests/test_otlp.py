@@ -71,10 +71,10 @@ def decoder():
 
 def _spans():
     return [
-        Span("t0000002a", "s00000001", "", "r1", "source", 1000, 250,
+        Span("t0000002a", "s00000001", "", "r1", "source", 1_000_000_123, 250,
              "ColumnBatch", 16),
         Span("t0000002a", "s00000002", "s00000001", "r1", "window_agg",
-             1001, 1250, "list", 3),
+             1_001_000_000, 1250, "stage", 3, stage="fold"),
     ]
 
 
@@ -95,14 +95,15 @@ class TestEncoding:
         assert s0.trace_id == s1.trace_id  # same engine trace
         assert s0.span_id != s1.span_id
         assert s1.parent_span_id == s0.span_id  # deterministic id mapping
-        assert s0.name == "r1/source" and s1.name == "r1/window_agg"
+        assert s0.name == "r1/source" and s1.name == "r1/window_agg:fold"
         assert s0.kind == 1  # INTERNAL
-        assert s0.start_time_unix_nano == 1000 * 1_000_000
+        # the nanoseconds the span has, not a millisecond times 1e6
+        assert s0.start_time_unix_nano == 1_000_000_123
         assert s0.end_time_unix_nano == s0.start_time_unix_nano + 250_000
         attrs = {kv.key: kv.value for kv in s1.attributes}
         assert attrs["op"].string_value == "window_agg"
         assert attrs["item.rows"].int_value == 3
-        assert attrs["item.kind"].string_value == "list"
+        assert attrs["item.kind"].string_value == "stage"
 
 
 class _Collector:
@@ -156,7 +157,7 @@ class TestExporter:
         req = decoder.FromString(body)
         got = [s.name for rs in req.resource_spans
                for ss in rs.scope_spans for s in ss.spans]
-        assert got == ["r1/source", "r1/window_agg"]
+        assert got == ["r1/source", "r1/window_agg:fold"]
         assert exp.stats()["exported"] == 2
 
     def test_collector_down_bounds_memory(self):
@@ -177,8 +178,8 @@ class TestExporter:
                            batch_interval_ms=50)
         tracer.exporter = exp
         tracer.enable("r9")
-        tracer.record("r9", "decode", 5, 10, "dict", 1)
-        tracer.record("other_rule_not_traced", "decode", 5, 10, "dict", 1)
+        tracer.begin("r9", "decode", "dict", 1).end()
+        tracer.begin("other_rule_not_traced", "decode", "dict", 1).end()
         deadline = time.time() + 5
         while time.time() < deadline and not collector.bodies:
             time.sleep(0.02)

@@ -1,0 +1,406 @@
+"""One stage span where the work happens (PR 27): `StatManager.stage()` feeds
+the stage counters, the rule's trace and the profiler's host plane; the
+node fabric counts starved and blocked time between the stages; a window
+boundary is split into phases; the kernels carry stable scope names."""
+import glob
+import json
+import time
+
+import pytest
+
+import ekuiper_tpu.io.memory as mem
+from ekuiper_tpu.observability.tracer import Tracer
+from ekuiper_tpu.runtime.node import Node
+from ekuiper_tpu.server.processors import StreamProcessor
+from ekuiper_tpu.server.rest import RestApi
+from ekuiper_tpu.store import kv
+
+
+@pytest.fixture
+def fresh_tracer():
+    old = Tracer._instance
+    Tracer._instance = Tracer()
+    yield Tracer._instance
+    Tracer._instance = old
+
+
+class _Topo:
+    """The two things a bare node asks of its topo."""
+
+    rule_id = "r"
+
+    def drain_error(self, err, origin=""):
+        raise err
+
+
+def _node(cls=Node, name="n1", **kw):
+    node = cls(name, **kw)
+    node._topo = _Topo()
+    node.stats.rule_id = "r"
+    return node
+
+
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+class _Worker(Node):
+    """2 ms on the core, then 20 ms off it, inside one stage."""
+
+    def process(self, item):
+        with self.stats.stage("work", rows=7, shard=3):
+            _spin(0.002)
+            time.sleep(0.02)
+
+
+# ------------------------------------------------------------------ (a)
+class TestStage:
+    def test_accrues_wall_cpu_calls_rows(self, fresh_tracer):
+        node = _node(_Worker)
+        node._dispatch("x")
+        node._dispatch("y")
+        st = node.stats.snapshot()["stage_timings"]["work"]
+        assert st["calls"] == 2 and st["rows"] == 14
+        assert st["total_us"] >= 2 * 21_000
+        # the sleep is off the core: CPU time is the spin, not the wall
+        assert 2 * 1_000 <= st["cpu_us"] <= st["total_us"] - 2 * 15_000
+        assert fresh_tracer.rule_spans("r") == []  # untraced: no span
+
+    def test_child_span_of_the_dispatch_starts_inside_it(self, fresh_tracer):
+        """The regression test for "a span's start is its end": the start
+        is taken before the work, on the wall clock, and the stage span
+        names the dispatch span as its parent."""
+        fresh_tracer.enable("r")
+        node = _node(_Worker)
+        before = time.time_ns()
+        node._dispatch("x")
+        after = time.time_ns()
+        spans = fresh_tracer.rule_spans("r")
+        dispatch = next(s for s in spans if "stage" not in s)
+        child = next(s for s in spans if s.get("stage") == "work")
+        assert dispatch["parentSpanId"] == ""
+        assert child["parentSpanId"] == dispatch["spanId"]
+        assert child["traceId"] == dispatch["traceId"]
+        assert child["op"] == "n1" and child["kind"] == "stage"
+        assert child["rows"] == 7 and child["attributes"] == {"shard": 3}
+        assert child["durationUs"] >= 21_000
+        d0, c0 = dispatch["startTimeUnixNano"], child["startTimeUnixNano"]
+        assert before <= d0 <= c0
+        # the child starts near the dispatch's START, a whole stage
+        # duration before the dispatch's end — not at it
+        assert c0 - d0 < 5_000_000
+        d_end = d0 + dispatch["durationUs"] * 1000
+        assert c0 + child["durationUs"] * 1000 <= d_end + 1_000_000
+        assert d_end <= after + 1_000_000
+        assert child["startTimeMs"] == c0 // 1_000_000
+        assert Tracer.current() is None  # the thread is handed back
+
+    def test_sub_stage_is_a_span_without_a_counter_row(self, fresh_tracer):
+        class N(Node):
+            def process(self, item):
+                with self.stats.stage("emit") as st:
+                    with self.stats.span("fetch"):
+                        pass
+                    st.rows = 5
+
+        fresh_tracer.enable("r")
+        node = _node(N)
+        node._dispatch("x")
+        assert set(node.stats.snapshot()["stage_timings"]) == {"emit"}
+        assert node.stats.snapshot()["stage_timings"]["emit"]["rows"] == 5
+        by_stage = {s.get("stage"): s for s in fresh_tracer.rule_spans("r")}
+        assert by_stage["fetch"]["parentSpanId"] == by_stage["emit"]["spanId"]
+        assert by_stage["emit"]["rows"] == 5
+
+    def test_context_crosses_the_queue_hop(self, fresh_tracer):
+        """A dispatch span's parent is the span during which the item was
+        emitted; the receiving worker's own context starts empty."""
+        fresh_tracer.enable("r")
+        up, down = _node(name="up"), _node(name="down")
+        up.connect(down)
+
+        class Item:
+            pass
+
+        up._dispatch(Item())
+        down._dispatch(down.inq.get_nowait())
+        spans = {s["op"]: s for s in fresh_tracer.rule_spans("r")}
+        assert spans["down"]["parentSpanId"] == spans["up"]["spanId"]
+        assert spans["down"]["traceId"] == spans["up"]["traceId"]
+
+
+# ------------------------------------------------------------------ (b)
+class TestBetweenTheStages:
+    def test_slow_consumer_blocks_senders_starved_one_idles(self):
+        class Slow(Node):
+            def process(self, item):
+                time.sleep(0.03)
+
+        slow = _node(Slow, name="slow", buffer_length=1,
+                     disable_buffer_full_discard=True)
+        starved = _node(name="starved")
+        slow.open()
+        starved.open()
+        try:
+            for i in range(5):  # queue of 1: the sender waits for room
+                slow.put(i)
+            slow.put_control("tick")
+            deadline = time.time() + 5
+            while slow.inq.unfinished_tasks and time.time() < deadline:
+                time.sleep(0.01)
+            s = slow.stats.snapshot()
+            time.sleep(0.25)  # one empty poll of the starved worker
+            v = starved.stats.snapshot()
+        finally:
+            slow.close()
+            starved.close()
+            slow.join()
+            starved.join()
+        assert s["backpressure_us_total"] >= 3 * 25_000
+        assert v["backpressure_us_total"] == 0
+        assert v["idle_us_total"] >= 200_000
+        # the slow node was busy nearly all the time it had input
+        assert s["idle_us_total"] < s["process_time_us_total"]
+        # neither is busy time: no stage row appears for them
+        assert s["stage_timings"] == {} and v["stage_timings"] == {}
+        assert s["dropped_total"] == {}
+
+
+# ------------------------------------------------------------------ (c)
+STAGES = ("kuiper:ingest", "kuiper:decode", "kuiper:upload", "kuiper:fold",
+          "kuiper:jit:fold", "kuiper:emit", "kuiper:sink")
+
+
+def _host_events(trace_dir: str):
+    """(name, unix start ns, duration ns, stats) of every `kuiper:` event
+    on a host plane, and the device planes' names."""
+    from jax.profiler import ProfileData
+
+    path = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")[0]
+    data = ProfileData.from_file(path)
+    t0 = next(dict(p.stats)["profile_start_time"] for p in data.planes
+              if p.name == "Task Environment")
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("kuiper:"):
+                    out.append((ev.name, t0 + int(ev.start_ns),
+                                int(ev.duration_ns), dict(ev.stats)))
+    return out
+
+
+@pytest.fixture
+def tumbling_rule(mock_clock):
+    store = kv.get_store()
+    StreamProcessor(store).exec_stmt(
+        'CREATE STREAM spans_in (deviceId STRING, temperature FLOAT) '
+        'WITH (DATASOURCE="spans/in", TYPE="memory", FORMAT="JSON")')
+    api = RestApi(store)
+    got = []
+    mem.subscribe("spans/out", lambda _t, payload: got.append(payload))
+    code, _ = api.dispatch("POST", "/rules", {
+        "id": "spans1",
+        "sql": "SELECT deviceId, count(*) AS c, avg(temperature) AS a "
+               "FROM spans_in GROUP BY deviceId, TUMBLINGWINDOW(ss, 1)",
+        "options": {"prefinalizeLeadMs": 0, "decodePoolSize": 2},
+        "actions": [{"memory": {"topic": "spans/out"}}]}, {})
+    assert code in (200, 201)
+    deadline = time.time() + 20
+    while time.time() < deadline:
+        rs = api.rules.state("spans1")
+        if rs is not None and rs.topo is not None and rs.topo._open:
+            break
+        time.sleep(0.05)
+    yield api, got
+    api.rules.stop_all()
+
+
+def _drive_one_window(mock_clock, got, n_before=0):
+    rows = [json.dumps({"deviceId": f"d{i % 4}", "temperature": float(i)}
+                       ).encode() for i in range(64)]
+    mem.publish("spans/in", rows)
+    mock_clock.advance(20)  # the linger flush
+    time.sleep(0.4)  # decode pool -> fused worker, in real threads
+    mock_clock.advance(1000)  # the boundary
+    deadline = time.time() + 10
+    while time.time() < deadline and len(got) <= n_before:
+        time.sleep(0.02)
+    assert len(got) > n_before, "the window never reached the sink"
+
+
+class TestProfilerAndTrace:
+    def test_spans_on_the_profilers_host_plane_and_in_the_trace(
+            self, tumbling_rule, mock_clock, fresh_tracer, tmp_path):
+        import jax
+
+        api, got = tumbling_rule
+        _drive_one_window(mock_clock, got)  # compiles, off the record
+        fresh_tracer.enable("spans1")
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            _drive_one_window(mock_clock, got, n_before=len(got))
+        finally:
+            jax.profiler.stop_trace()
+        events = _host_events(str(tmp_path))
+        names = {e[0] for e in events}
+        assert set(STAGES) <= names, sorted(names)
+        assert {"kuiper:convert", "kuiper:deliver", "kuiper:merge"} <= names
+        fold = next(e for e in events if e[0] == "kuiper:fold")
+        assert fold[3]["rule"] == "spans1" and fold[3]["rows"] == 64
+        # the host span that enqueued the device program lies inside the
+        # stage that dispatched it
+        jit = next(e for e in events if e[0] == "kuiper:jit:fold")
+        assert fold[1] <= jit[1] and jit[1] + jit[2] <= fold[1] + fold[2]
+
+        # ---- the same work in the rule's trace, on the same clock
+        spans = [s for tid in fresh_tracer.rule_traces("spans1")
+                 for s in fresh_tracer.trace(tid)]
+        for stage in ("ingest", "decode", "upload", "fold", "emit", "sink",
+                      "convert", "deliver"):
+            mine = [s for s in spans if s.get("stage") == stage]
+            assert mine, stage
+            theirs = [e[1] for e in events if e[0] == "kuiper:" + stage]
+            for s in mine:
+                gap = min(abs(s["startTimeUnixNano"] - t) for t in theirs)
+                assert gap < 1_000_000, (stage, gap)
+
+        # ---- parents: one ingest batch, then one boundary
+        by_id = {s["spanId"]: s for s in spans}
+
+        def chain(span):
+            out = [(span["op"], span.get("stage", ""))]
+            while span["parentSpanId"]:
+                span = by_id[span["parentSpanId"]]
+                out.append((span["op"], span.get("stage", "")))
+            return out[::-1]
+
+        fold_span = next(s for s in spans if s.get("stage") == "fold")
+        assert chain(fold_span) == [
+            ("spans_in", ""), ("spans_in", "ingest"),
+            ("spans_in_shared", ""), ("window_agg", ""),
+            ("window_agg", "fold")]
+        decode = next(s for s in spans if s.get("stage") == "decode")
+        assert chain(decode)[:2] == [("spans_in", ""),
+                                     ("spans_in", "ingest")]
+        deliver = next(s for s in spans if s.get("stage") == "deliver")
+        path = chain(deliver)
+        assert path[0] == ("window_agg", "") and \
+            by_id[deliver["parentSpanId"]]["stage"] == "sink"
+        assert ("window_agg", "emit") in path
+        sink_dispatch = next(s for s in spans if s["op"] == path[-1][0]
+                             and "stage" not in s)
+        assert by_id[sink_dispatch["parentSpanId"]]["stage"] == "emit"
+        trigger = next(s for s in spans if s["kind"] == "Trigger")
+        assert trigger["parentSpanId"] == ""
+        # every span but a root names a parent in its own trace, and
+        # starts no earlier than it
+        roots = [s for s in spans if not s["parentSpanId"]]
+        assert {(r["op"], r["kind"]) for r in roots} <= {
+            ("spans_in", "list"), ("spans_in", "LingerTimer"),
+            ("window_agg", "Trigger"), ("window_agg", "PreTrigger")}
+        for s in spans:
+            if s["parentSpanId"]:
+                parent = by_id[s["parentSpanId"]]
+                assert parent["traceId"] == s["traceId"]
+                assert parent["startTimeUnixNano"] <= s["startTimeUnixNano"]
+
+    def test_boundary_phases_count_one_sample_per_window(
+            self, tumbling_rule, mock_clock):
+        """(e) `kuiper_boundary_ms`: one sample per phase per emitted
+        window, in /metrics and in the rule's status."""
+        from tools import check_metrics
+
+        api, got = tumbling_rule
+        for _ in range(3):
+            _drive_one_window(mock_clock, got, n_before=len(got))
+        topo = api.rules.state("spans1").topo
+        deadline = time.time() + 5
+        while time.time() < deadline and \
+                topo.boundary_hists["sink"].count < len(got):
+            time.sleep(0.02)
+        n = len(got)
+        code, text = api.dispatch("GET", "/metrics", None, {})
+        for phase in ("trigger_delay", "emit", "sink"):
+            line = (f'kuiper_boundary_ms_count{{rule="spans1",'
+                    f'phase="{phase}"}} ')
+            count = next(float(ln[len(line):]) for ln in text.splitlines()
+                         if ln.startswith(line))
+            # a boundary with no rows emits nothing and records no emit /
+            # sink sample, but its Trigger still came late or on time
+            assert count == n if phase != "trigger_delay" else count >= n
+        status = topo.status()
+        assert set(status["boundary_ms"]) == {"trigger_delay", "emit", "sink"}
+        assert status["boundary_ms"]["emit"]["count"] == n
+        assert 0 < status["boundary_ms"]["sink"]["p50"] <= \
+            status["boundary_ms"]["sink"]["p95"]
+        for fam in ("kuiper_op_idle_us_total",
+                    "kuiper_op_backpressure_us_total",
+                    "kuiper_op_stage_cpu_us_total"):
+            assert f"# TYPE {fam} counter" in text
+        assert 'stage="emit"' in text and 'stage="sink"' in text \
+            and 'stage="ingest"' in text
+        # every family of this live scrape is prefixed, typed, helped and
+        # in the catalog — the lint's forward direction over a REAL rule
+        docs = check_metrics.documented_families()
+        assert check_metrics.rendered_families(text) <= docs
+
+
+# ------------------------------------------------------------------ (d)
+class TestKernelNames:
+    def test_program_names_stay_and_ops_carry_kuiper_scopes(self):
+        """`trace_kernel_roofline` matches the PROGRAM names by substring;
+        the scopes name the ops inside them."""
+        import jax
+        import jax.numpy as jnp
+
+        from ekuiper_tpu.ops.aggspec import extract_kernel_plan
+        from ekuiper_tpu.ops.groupby import DeviceGroupBy
+        from ekuiper_tpu.sql.parser import parse_select
+
+        stmt = parse_select(
+            "SELECT deviceId, avg(temperature) AS a, count(*) AS c, "
+            "min(temperature) AS mn FROM s GROUP BY deviceId, "
+            "TUMBLINGWINDOW(ss, 1)")
+        plan = extract_kernel_plan(stmt)
+        gb = DeviceGroupBy(plan, capacity=64, n_panes=1, micro_batch=32)
+        state = gb.init_state()
+        cols = {"temperature": jnp.zeros(32, jnp.float32)}
+        slots = jnp.zeros(32, jnp.int32)
+        pane = jnp.asarray(0, jnp.int32)
+        lowered = {
+            "fold": jax.jit(gb._fold_impl).lower(
+                state, cols, slots, jnp.asarray(32, jnp.int32), pane),
+            "finalize": jax.jit(gb._finalize_impl, static_argnums=(1,)
+                                ).lower(state, (True,)),
+            "reset_pane": jax.jit(gb._reset_pane_impl).lower(state, pane),
+            "components": jax.jit(gb._components_impl, static_argnums=(1,)
+                                  ).lower(state, (True,)),
+        }
+        scopes = {
+            "fold": ("kuiper/fold/pad", "kuiper/fold/values",
+                     "kuiper/fold/scatter_act", "kuiper/fold/scatter_s1",
+                     "kuiper/fold/scatter_mn"),
+            "finalize": ("kuiper/finalize/pane_merge",
+                         "kuiper/finalize/values"),
+            "reset_pane": ("kuiper/reset_pane/fill",),
+            "components": ("kuiper/components/pane_merge",
+                           "kuiper/components/stack"),
+        }
+        for site, low in lowered.items():
+            text = low.as_text(debug_info=True)
+            head = next(ln for ln in text.splitlines()
+                        if ln.startswith("module @"))
+            assert f"module @jit__{site}_impl" in head, head
+            for scope in scopes[site]:
+                assert scope in text, (site, scope)
+        # the jit sites' stable trace names
+        assert gb._fold.rec.trace_name == "kuiper:jit:fold"
+        assert gb._reset_pane.rec.trace_name == "kuiper:jit:reset_pane"

@@ -134,6 +134,7 @@ def _synthetic_scrape() -> str:
 
     class Topo:
         e2e_hist = LatencyHistogram()
+        boundary_hists = {"emit": LatencyHistogram()}
 
         def all_nodes(self):
             return [Node("src", "source"), Node("op1"), wm_node,
@@ -144,6 +145,7 @@ def _synthetic_scrape() -> str:
 
     Topo.e2e_hist.record(7)
     Topo.e2e_hist.record(42)
+    Topo.boundary_hists["emit"].record(3400)
 
     class State:
         topo = Topo()
