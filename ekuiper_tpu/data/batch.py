@@ -113,30 +113,41 @@ class ColumnBatch:
             ingest_ms=self.ingest_ms,
         )
 
-    def to_tuples(self) -> List[Tuple]:
-        """Back to row objects (sink/interpreter path)."""
-        out: List[Tuple] = []
+    def to_messages(self) -> List[Dict[str, Any]]:
+        """One plain dict per row, keys in `names()` order — the repo's one
+        ColumnBatch → Python conversion, done per COLUMN: `tolist()` yields
+        the values `.item()` would per element, rows come from `zip(*cols)`.
+        A cell whose `valid` mask is false has its key omitted from the row.
+        Reads no timestamps and builds no Tuple."""
         names = self.names()
-        cols = [self.columns[k] for k in names]
-        valids = [self.valid.get(k) for k in names]
-        ts = self.timestamps
-        for i in range(self.n):
-            msg: Dict[str, Any] = {}
-            for name, col, v in zip(names, cols, valids):
-                if v is not None and not v[i]:
-                    continue
-                val = col[i]
-                if isinstance(val, np.generic):
-                    val = val.item()
-                msg[name] = val
-            out.append(
-                Tuple(
-                    emitter=self.emitter,
-                    message=msg,
-                    timestamp=int(ts[i]) if ts is not None else 0,
-                )
-            )
+        if not names:
+            return [{} for _ in range(self.n)]
+        cols = []
+        for name in names:
+            col = self.columns[name]
+            vals = col.tolist()
+            if col.dtype == np.object_ and any(
+                    issubclass(t, np.generic) for t in set(map(type, vals))):
+                # tolist() hands an object column's elements back as they are
+                vals = [v.item() if isinstance(v, np.generic) else v
+                        for v in vals]
+            cols.append(vals)
+        out = [dict(zip(names, vals)) for vals in zip(*cols)]
+        for name, v in self.valid.items():
+            if name in self.columns and not v.all():
+                for i in np.nonzero(~v)[0].tolist():
+                    del out[i][name]
         return out
+
+    def to_tuples(self) -> List[Tuple]:
+        """Back to row objects (interpreter path)."""
+        emitter = self.emitter
+        if self.timestamps is None:
+            tss = [0] * self.n
+        else:
+            tss = np.asarray(self.timestamps, dtype=np.int64).tolist()
+        return [Tuple(emitter=emitter, message=m, timestamp=t)
+                for m, t in zip(self.to_messages(), tss)]
 
     @staticmethod
     def concat(batches: Sequence["ColumnBatch"]) -> "ColumnBatch":

@@ -425,7 +425,7 @@ class EdgexSink(Sink):
             rows = [r for r in item if isinstance(r, dict)]
         else:
             try:  # columnar emissions (ColumnBatch) flatten to rows
-                rows = [t.message for t in item.to_tuples()]
+                rows = item.to_messages()
             except AttributeError:
                 raise EngineError(f"edgex sink: invalid data {item!r}")
         if self.data_field:

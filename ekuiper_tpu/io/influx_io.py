@@ -123,7 +123,7 @@ class _BaseInfluxSink(Sink):
             rows = [r for r in item if isinstance(r, dict)]
         else:
             try:  # columnar emissions flatten to rows
-                rows = [t.message for t in item.to_tuples()]
+                rows = item.to_messages()
             except AttributeError:
                 raise EngineError(f"influx sink: invalid data {item!r}")
         body = to_lines(rows, self.measurement, self.tags, self.ts_field,

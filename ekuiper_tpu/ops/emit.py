@@ -168,8 +168,8 @@ class DirectEmitPlan:
         """Columnar variant of run(): the window result stays a ColumnBatch
         (NaN→valid-mask for NULLs) instead of exploding into per-group dicts.
         Downstream nodes/sinks consume ColumnBatch natively; sinks that need
-        per-message dicts convert at the edge (to_messages). At 10k+ groups
-        this removes ~20ms of dict building from the emit path."""
+        per-message dicts convert at the edge (`ColumnBatch.to_messages`,
+        one `tolist()` per column), off the emit path."""
         from ..data.batch import ColumnBatch
 
         env, n = self._prepare(dim_cols, agg_cols)
@@ -199,7 +199,7 @@ class DirectEmitPlan:
 
 def _null_preserving(col: np.ndarray) -> np.ndarray:
     """NaN aggregates are NULLs and must stay as explicit None in the sink
-    payload (a valid-mask would make to_tuples OMIT the key — a different
+    payload (a valid-mask would make to_messages OMIT the key — a different
     message shape than the row path emits). NaN-free columns (the common
     case) stay numeric; NULL-bearing ones go object with None holes."""
     if np.issubdtype(col.dtype, np.floating):

@@ -109,13 +109,13 @@ class TransformNode(Node):
         self.omit_if_empty = omit_if_empty
 
     def process(self, item: Any) -> None:
-        from .nodes_sink import apply_transform, to_messages
+        from .nodes_sink import to_messages, transform_messages
 
         msgs = to_messages(item)
         if not msgs and self.omit_if_empty:
             return
-        msgs = [apply_transform(m, self.fields, self.exclude_fields,
-                                self.data_template) for m in msgs]
+        msgs = transform_messages(msgs, self.fields, self.exclude_fields,
+                                  self.data_template)
         if self.send_single:
             for m in msgs:
                 self.emit(m)

@@ -32,6 +32,16 @@ def apply_transform(msg: Dict[str, Any], fields=None, exclude_fields=None,
     return msg
 
 
+def transform_messages(msgs: List[Dict[str, Any]], fields=None,
+                       exclude_fields=None, data_template: str = "") -> List[Any]:
+    """`apply_transform` over one item's messages — decided once per item:
+    with nothing configured the list goes through as it is."""
+    if not (fields or exclude_fields or data_template):
+        return msgs
+    return [apply_transform(m, fields, exclude_fields, data_template)
+            for m in msgs]
+
+
 def to_messages(item: Any) -> List[Dict[str, Any]]:
     """Normalize any runtime data item to a list of plain message dicts
     (shared by SinkNode and the sink-chain EncodeNode)."""
@@ -47,7 +57,7 @@ def to_messages(item: Any) -> List[Dict[str, Any]]:
     if isinstance(item, (WindowTuples,)):
         return [r.all_values() for r in item.rows()]
     if isinstance(item, ColumnBatch):
-        return [t.message for t in item.to_tuples()]
+        return item.to_messages()
     if isinstance(item, dict):
         return [item]
     if isinstance(item, Row):
@@ -130,7 +140,9 @@ class SinkNode(Node):
             n = 1
         else:
             with self.stats.span("convert") as sp:
-                msgs = [self._transform(m) for m in self._to_messages(item)]
+                msgs = transform_messages(
+                    to_messages(item), self.fields, self.exclude_fields,
+                    self.data_template)
                 sp.rows = n = len(msgs)
             if not msgs and self.omit_if_empty:
                 return 0
@@ -167,13 +179,6 @@ class SinkNode(Node):
             observe(lat_ms)
         if self._tracing_now:
             self._span_attrs = {"e2e_ms": lat_ms}
-
-    def _to_messages(self, item: Any) -> List[Dict[str, Any]]:
-        return to_messages(item)
-
-    def _transform(self, msg: Dict[str, Any]) -> Any:
-        return apply_transform(msg, self.fields, self.exclude_fields,
-                               self.data_template)
 
     def _collect(self, payload: Any, ack: bool = True) -> bool:
         attempts = 0
