@@ -653,30 +653,35 @@ class DeviceGroupBy:
         produce their final-value row; hh specs produce 2*k2 rows of
         device-recovered candidate (codes, estimates). One small
         (R, capacity) transfer regardless of sketch width."""
+        import jax
         import jax.numpy as jnp
 
         from .sketches import hh_candidates
 
-        merged = {
-            comp: self._merged(state, comp, pane_mask)
-            for comp in self.comp_specs
-        }
-        act = self._merged(state, "act", pane_mask)
+        with jax.named_scope("kuiper/hh_finalize/pane_merge"):
+            merged = {
+                comp: self._merged(state, comp, pane_mask)
+                for comp in self.comp_specs
+            }
+            act = self._merged(state, "act", pane_mask)
         rows = []
         for i, spec in enumerate(self.plan.specs):
             if spec.kind == "heavy_hitters":
-                hhm = merged["hh"][:, self.comp_specs["hh"].index(i)]
-                codes, est = hh_candidates(hhm, 2 * spec.topk)
-                rows.append(codes.T)  # (k2, cap)
-                rows.append(est.T)
+                with jax.named_scope("kuiper/hh_finalize/candidates"):
+                    hhm = merged["hh"][:, self.comp_specs["hh"].index(i)]
+                    codes, est = hh_candidates(hhm, 2 * spec.topk)
+                    rows.append(codes.T)  # (k2, cap)
+                    rows.append(est.T)
             else:
-                col = {
-                    comp: merged[comp][:, self.comp_specs[comp].index(i)]
-                    for comp in spec.components
-                }
-                rows.append(self._final_value(spec, col)[None, :])
+                with jax.named_scope("kuiper/hh_finalize/values"):
+                    col = {
+                        comp: merged[comp][:, self.comp_specs[comp].index(i)]
+                        for comp in spec.components
+                    }
+                    rows.append(self._final_value(spec, col)[None, :])
         rows.append(act[None, :])
-        return jnp.concatenate(rows, axis=0)
+        with jax.named_scope("kuiper/hh_finalize/stack"):
+            return jnp.concatenate(rows, axis=0)
 
     def hh_assemble(
         self, stacked: np.ndarray, n_keys: int,

@@ -975,7 +975,9 @@ class FusedWindowAggNode(Node):
                 if col is None:
                     cols[name] = np.full(sub.n, np.nan, dtype=np.float32)
                 else:
-                    cols[name] = vd.encode(col)
+                    with self.stats.stage("hh_encode", sub.n,
+                                          within="upload"):
+                        cols[name] = vd.encode(col)
                     if vd.overflowed and raw not in self._hh_overflow_warned:
                         self._hh_overflow_warned.add(raw)
                         self.stats.inc_exception(
@@ -1408,21 +1410,24 @@ class FusedWindowAggNode(Node):
         program sees an immutable snapshot, so the caller is free to reset
         panes immediately after."""
         with self.stats.stage("emit"):
+            t_issue = time.perf_counter()  # issue→landed starts here
             with self.stats.span("finalize"):
                 stacked_dev = dispatch()
                 stacked_dev.copy_to_host_async()
             self._emit_submit(kind, stacked_dev, self.kt.n_keys, wr,
-                              self._keys_snapshot())
+                              self._keys_snapshot(), t_issue)
 
     def _emit_submit(self, kind: str, payload, n_keys: int, wr,
-                     keys_snap=None) -> None:
+                     keys_snap=None, t_issue: Optional[float] = None) -> None:
         """Enqueue one deferred delivery for the emit worker with what it
         needs from THIS (the dispatch) thread, captured at issue: the
         ingest provenance (the worker must not read the live
         _cur_ingest_ms, which keeps advancing with post-boundary folds),
-        the boundary's start and the trace context."""
+        the boundary's start and the trace context. `t_issue` is the perf
+        clock before the dispatch, where the caller made one; now, else."""
         self._ensure_emit_worker()
-        self._emit_q.put((kind, payload, n_keys, wr, time.perf_counter(),
+        self._emit_q.put((kind, payload, n_keys, wr,
+                          t_issue or time.perf_counter(),
                           self._cur_ingest_ms, keys_snap,
                           getattr(_emit_ctx, "boundary_t0", None),
                           Tracer.current()))
@@ -1507,7 +1512,12 @@ class FusedWindowAggNode(Node):
                 "ages_ms": [],
             }
             return self._emit_active(outs, act, wr)
-        with self.stats.span("fetch"):
+        # heavy hitters: dispatch → landed and the host tail below are
+        # stages of their own inside `emit` (counters a reader can take:
+        # one call a boundary), not sub-stage spans
+        with (self.stats.stage("hh_finalize", n_keys, within="emit",
+                               since_ns=int(t_issue * 1e9))
+              if kind == "hh" else self.stats.span("fetch")):
             # kuiperlint: ignore[host-sync]: emit worker thread — THE intended sync point; the fold thread already dispatched and moved on
             arr = np.asarray(payload)
         self.last_emit_info = {
@@ -1519,15 +1529,17 @@ class FusedWindowAggNode(Node):
             self._deliver_mr(arr, n_keys, wr)
             self._count_emit_source()
             return n_keys
-        with self.stats.span("merge"):
-            if kind == "hh":
+        if kind == "hh":
+            with self.stats.stage("hh_assemble", n_keys, within="emit"):
                 outs, act = self.gb.hh_assemble(arr, n_keys)
-            else:
-                outs = [arr[i][:n_keys]
-                        for i in range(len(self.plan.specs))]
-                outs = apply_int_semantics(self.plan.specs, outs)
-                # kuiperlint: ignore[host-sync]: `arr` already landed on host above
-                act = np.asarray(arr[-1][:n_keys])
+                outs = self._decode_hh(outs)
+            return self._emit_active(outs, act, wr, hh_decoded=True)
+        with self.stats.span("merge"):
+            outs = [arr[i][:n_keys]
+                    for i in range(len(self.plan.specs))]
+            outs = apply_int_semantics(self.plan.specs, outs)
+            # kuiperlint: ignore[host-sync]: `arr` already landed on host above
+            act = np.asarray(arr[-1][:n_keys])
         return self._emit_active(outs, act, wr)
 
     def _fetch_and_merge(self, pending, shadow, n_keys: int):
@@ -1540,17 +1552,20 @@ class FusedWindowAggNode(Node):
             return self.gb.prefinalize_merge(pending, shadow, n_keys)
 
     def _emit_active(self, outs, act, wr: WindowRange,
-                     counted: bool = True) -> int:
+                     counted: bool = True, hh_decoded: bool = False) -> int:
         """Build the output of the window's active groups, if any, along
         the path the plan chose (the tail of the `merge` sub-stage) and
         hand it downstream; returns the number of active groups, 0 for an
-        empty window. `counted` bumps the per-source window count first."""
+        empty window. `counted` bumps the per-source window count first;
+        `hh_decoded` says the heavy-hitters codes are values already."""
         active = np.nonzero(act > 0)[0]
         if len(active) == 0:
             return 0
         if counted:
             self._count_emit_source()
         with self.stats.span("merge", len(active)):
+            if not hh_decoded:
+                outs = self._decode_hh(outs)
             built = (self._build_direct(outs, active, wr)
                      if self.direct_emit is not None
                      else self._build_grouped(outs, active, wr))
@@ -2736,7 +2751,6 @@ class FusedWindowAggNode(Node):
     def _build_grouped(self, outs, active: np.ndarray, wr: WindowRange):
         """Row-path emit tail: build the GroupedTuplesSet for downstream
         HAVING/ORDER/PROJECT nodes; returns (item, count)."""
-        outs = self._decode_hh(outs)
         # bulk-convert once (C speed) instead of per-slot numpy scalar access —
         # emit latency is dominated by this host loop at 10k+ groups
         active_list = active.tolist()
@@ -2776,7 +2790,6 @@ class FusedWindowAggNode(Node):
         """Vectorized tail: HAVING/ORDER/LIMIT/projection computed over the
         finalize arrays into the final output messages; returns (item,
         count), or None when HAVING left nothing."""
-        outs = self._decode_hh(outs)
         dim_names = [d.name for d in self.dims]
         dim_cols: Dict[str, np.ndarray] = {}
         if dim_names:

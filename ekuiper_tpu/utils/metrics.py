@@ -28,18 +28,22 @@ class _Stage:
     and thread-CPU microseconds, a call and `rows` to the node's stage
     table, and under a traced dispatch it is a child span of whatever
     span is open on the thread. `rows` may be set inside the body when
-    the count is known only afterwards."""
+    the count is known only afterwards. `since_ns` (perf clock) starts the
+    wall interval earlier than the body — at a dispatch made on another
+    thread, whose completion the body waits for."""
 
-    __slots__ = ("sm", "name", "rows", "attrs", "counted", "_ann", "_span",
-                 "_t0", "_c0")
+    __slots__ = ("sm", "name", "rows", "attrs", "counted", "since_ns",
+                 "_ann", "_span", "_t0", "_c0")
 
     def __init__(self, sm: "StatManager", name: str, rows: int,
-                 attrs: Optional[dict], counted: bool) -> None:
+                 attrs: Optional[dict], counted: bool,
+                 since_ns: Optional[int] = None) -> None:
         self.sm = sm
         self.name = name
         self.rows = rows
         self.attrs = attrs
         self.counted = counted
+        self.since_ns = since_ns
 
     def __enter__(self) -> "_Stage":
         global _TraceAnnotation
@@ -60,7 +64,7 @@ class _Stage:
             # wall read outside the CPU read on both ends: the CPU interval
             # lies inside the wall interval, so cpu <= wall per call. (The
             # CPU clock is a system call; a sub-stage does not pay for it.)
-            self._t0 = _time.perf_counter_ns()
+            self._t0 = self.since_ns or _time.perf_counter_ns()
             self._c0 = _time.thread_time_ns()
         return self
 
@@ -118,6 +122,10 @@ class StatManager:
         # operators see where ingest wall time goes per node — the balance
         # of the sharded ingest pipeline is tuned from these
         self.stages: Dict[str, Dict[str, int]] = {}
+        # stages opened inside another stage of this node (`within=`): they
+        # have counter rows like any stage, but their time is already in
+        # the enclosing stage's, so sums over a node's stages leave them out
+        self.nested_stages: set = set()
         # latency DISTRIBUTIONS (observability/histogram.py): the last-value
         # process_latency_us gauge cannot express a tail — these make the
         # paper's p99 claims measurable per op. proc_hist records each
@@ -244,13 +252,20 @@ class StatManager:
             st["rows"] += int(rows)
             st["cpu_us"] += int(cpu_us)
 
-    def stage(self, name: str, rows: int = 0, **attrs) -> _Stage:
+    def stage(self, name: str, rows: int = 0, within: Optional[str] = None,
+              since_ns: Optional[int] = None, **attrs) -> _Stage:
         """Context manager around one run of pipeline stage `name`: counters
         (wall, CPU, calls, rows), a child span under a traced dispatch, and
-        a `kuiper:<name>` annotation in a profiler capture. Stages of one
-        node must not nest: the health plane sums a node's stage rows as
-        its covered busy time — time a piece inside a stage with span()."""
-        return _Stage(self, name, rows, attrs or None, True)
+        a `kuiper:<name>` annotation in a profiler capture. The health plane
+        sums a node's stage rows as its covered busy time, so a piece inside
+        a stage is timed with span() — or, where a reader needs its counters
+        (it runs once a micro-batch or once a boundary, never per row), as a
+        stage that names the stage it runs `within`: counted, and left out
+        of the node's sums (`nested_stages`)."""
+        if within is not None:
+            self.nested_stages.add(name)
+            attrs["within"] = within
+        return _Stage(self, name, rows, attrs or None, True, since_ns)
 
     def span(self, name: str, rows: int = 0, **attrs) -> _Stage:
         """A sub-stage: the span and the profiler annotation of stage()
@@ -281,7 +296,8 @@ class StatManager:
                 return {
                     "busy_us": self.process_time_us_total,
                     "stages": {k: v["total_us"]
-                               for k, v in self.stages.items()},
+                               for k, v in self.stages.items()
+                               if k not in self.nested_stages},
                     "dropped": sum(self.dropped.values()),
                     "in": self.records_in,
                 }
