@@ -18,6 +18,7 @@ import numpy as np
 from ..sql import ast, expr_ir
 from ..sql.compiler import CompiledExpr
 from ..sql.expr_ir import NotVectorizable
+from .sketches import HH_MAX_CODES
 
 # aggregate name -> components needed by finalize
 DEVICE_AGGS: Dict[str, Set[str]] = {
@@ -130,6 +131,68 @@ def _hll_encode_numeric(raw: "np.ndarray") -> "np.ndarray":
     return out
 
 
+# A table of known integer values is indexed by `value - lowest` while that
+# takes fewer than this many slots a known value, or fewer than the floor
+# (16 KB of float32); wider than that, the sorted values are searched.
+_DENSE_SLOTS_PER_VALUE = 8
+_DENSE_FLOOR = 4096
+_INT64_MIN = -(1 << 63)
+
+
+def _table_class(dtype) -> Optional[type]:
+    """The dtype a column's values are looked up in — every integer but
+    uint64 is exact in int64, float16/32 in float64 — or None for a column
+    no table serves (strings and objects, uint64, longdouble, complex)."""
+    if dtype.kind in "bi" or (dtype.kind == "u" and dtype.itemsize < 8):
+        return np.int64
+    if dtype.kind == "f" and dtype.itemsize <= 8:
+        return np.float64
+    return None
+
+
+class _CodeTable:
+    """The values of one dtype class that `ValueDict` has resolved, sorted,
+    beside their codes (float32, what the fold uploads). `lookup` answers a
+    whole column in a few array calls — each one hands the interpreter lock
+    back once, which is what a micro-batch's encode costs on a busy host."""
+
+    __slots__ = ("keys", "codes", "dense", "base")
+
+    def __init__(self, keys: "np.ndarray", codes: "np.ndarray") -> None:
+        self.keys = keys
+        self.codes = codes
+        self.dense = None
+        if keys.dtype != np.int64:
+            return
+        lo, hi = int(keys[0]), int(keys[-1])
+        limit = max(_DENSE_FLOOR, _DENSE_SLOTS_PER_VALUE * len(keys))
+        # low positive values index the table themselves, which saves the
+        # subtraction; otherwise slot 1 is the lowest value
+        base = 0 if lo > 0 and hi + 2 <= limit else lo - 1
+        if hi - base + 2 <= limit and base >= _INT64_MIN:
+            # slot 0 and the last slot stay -1: take(mode="clip") lands
+            # every value outside [lo, hi] on one of them
+            self.base = base
+            self.dense = np.full(hi - base + 2, -1, dtype=np.float32)
+            self.dense[keys - base] = codes
+
+    def merged(self, keys: "np.ndarray", codes: "np.ndarray") -> "_CodeTable":
+        """This table and the sorted `keys`, none of which it holds."""
+        pos = np.searchsorted(self.keys, keys)
+        return _CodeTable(np.insert(self.keys, pos, keys),
+                          np.insert(self.codes, pos, codes))
+
+    def lookup(self, arr: "np.ndarray") -> "np.ndarray":
+        """float32 codes of `arr`; -1 where the table lacks the value."""
+        if self.dense is not None:
+            return np.take(self.dense, arr - self.base if self.base else arr,
+                           mode="clip")
+        pos = np.searchsorted(self.keys, arr)
+        found = np.take(self.keys, pos, mode="clip") == arr
+        return np.where(found, np.take(self.codes, pos, mode="clip"),
+                        np.float32(-1))
+
+
 class ValueDict:
     """Reversible dictionary encoding for a heavy_hitters column: values map
     to dense integer codes (< sketches.HH_MAX_CODES) that fit the sketch's
@@ -138,16 +201,19 @@ class ValueDict:
     the window, across panes, and across checkpoint restore (the fused node
     persists the value list). Values past the code budget encode as NaN
     (masked — invisible to the sketch); heavy hitters by definition appear
-    early and often, so they claim low codes long before overflow."""
+    early and often, so they claim low codes long before overflow.
+
+    `_ids`/`_values` are the dictionary. A numeric column is answered from
+    `_tables` — per dtype class, the values resolved so far — and Python
+    runs only for the rows no table knows (`lookup`, then `learn`)."""
 
     def __init__(self) -> None:
         self._ids: Dict[Any, int] = {}
         self._values: List[Any] = []
+        self._tables: Dict[type, _CodeTable] = {}
         self.overflowed = False
 
     def _code(self, v) -> float:
-        from .sketches import HH_MAX_CODES
-
         ids = self._ids
         c = ids.get(v)
         if c is None:
@@ -160,33 +226,62 @@ class ValueDict:
         return float(c)
 
     def encode(self, col: "np.ndarray") -> "np.ndarray":
-        """Column -> float32 codes (NaN for None/overflow)."""
+        """Column -> float32 codes (NaN for None/NaN/overflow)."""
+        codes, missed = self.lookup(col)
+        if missed is not None:
+            self.learn(col, codes, missed)
+        return codes
+
+    def lookup(self, col: "np.ndarray"
+               ) -> Tuple["np.ndarray", Optional["np.ndarray"]]:
+        """The codes of `col` as far as a table knows them, and the indices
+        of the rows left for `learn` (NaN so far) — None when there are
+        none, which is every micro-batch of a stream whose values have all
+        been seen."""
         n = len(col)
-        out = np.empty(n, dtype=np.float32)
+        cls = _table_class(col.dtype)
+        table = self._tables.get(cls)
+        if table is None:
+            codes = np.full(n, np.nan, dtype=np.float32)
+            missed = np.arange(n)
+        else:
+            codes = table.lookup(col.astype(cls, copy=False))
+            if n == 0 or codes.min() >= 0:
+                return codes, None
+            missed = np.flatnonzero(codes < 0)
+            codes[missed] = np.nan
+        if col.dtype.kind in "fc":  # NaN is no value: it stays NaN
+            vals = col[missed]
+            missed = missed[vals == vals]
+        return codes, (missed if len(missed) else None)
+
+    def learn(self, col: "np.ndarray", codes: "np.ndarray",
+              missed: "np.ndarray") -> None:
+        """Resolve rows `missed` of `col` through the dictionary into
+        `codes`: first-seen values take the next codes in sorted order, and
+        what was resolved joins the column's table."""
         if col.dtype == np.object_:
             for i, v in enumerate(col.tolist()):
                 if v is None:
-                    out[i] = np.nan
                     continue
                 try:
-                    out[i] = self._code(v)
+                    codes[i] = self._code(v)
                 except TypeError:  # unhashable (list/dict): stringify
-                    out[i] = self._code(repr(v))
-            return out
-        arr = np.asarray(col)
-        if np.issubdtype(arr.dtype, np.floating):
-            nan = np.isnan(arr)
-        else:
-            nan = np.zeros(n, dtype=bool)
-        out = np.full(n, np.nan, dtype=np.float32)
-        clean = arr[~nan] if nan.any() else arr
-        if len(clean):
-            uniq, inverse = np.unique(clean, return_inverse=True)
-            ucodes = np.array(
-                [self._code(u.item()) for u in uniq], dtype=np.float32
-            )
-            out[~nan] = ucodes[inverse]
-        return out
+                    codes[i] = self._code(repr(v))
+            return
+        uniq, inverse = np.unique(col[missed], return_inverse=True)
+        ucodes = np.array(
+            [self._code(u.item()) for u in uniq], dtype=np.float32
+        )
+        codes[missed] = ucodes[inverse]
+        cls = _table_class(col.dtype)
+        coded = ucodes == ucodes  # past the budget: NaN, and in no table
+        if cls is None or not coded.any():
+            return
+        keys, ucodes = uniq[coded].astype(cls, copy=False), ucodes[coded]
+        table = self._tables.get(cls)
+        self._tables[cls] = (_CodeTable(keys, ucodes) if table is None
+                             else table.merged(keys, ucodes))
 
     def decode(self, code: int):
         return self._values[code] if 0 <= code < len(self._values) else None
@@ -197,6 +292,7 @@ class ValueDict:
     def restore(self, values: List[Any]) -> None:
         self._values = list(values)
         self._ids = {}
+        self._tables = {}  # refilled by the batches that follow
         for i, v in enumerate(self._values):
             try:
                 self._ids[v] = i

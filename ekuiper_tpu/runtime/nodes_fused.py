@@ -977,7 +977,14 @@ class FusedWindowAggNode(Node):
                 else:
                     with self.stats.stage("hh_encode", sub.n,
                                           within="upload"):
-                        cols[name] = vd.encode(col)
+                        cols[name], missed = vd.lookup(col)
+                        if missed is not None:
+                            # rows whose value no table knows yet: none,
+                            # once a stream's values have all been seen
+                            with self.stats.stage("hh_encode_new",
+                                                  len(missed),
+                                                  within="hh_encode"):
+                                vd.learn(col, cols[name], missed)
                     if vd.overflowed and raw not in self._hh_overflow_warned:
                         self._hh_overflow_warned.add(raw)
                         self.stats.inc_exception(

@@ -12,11 +12,13 @@ import numpy as np
 import pytest
 
 from ekuiper_tpu.data.batch import ColumnBatch
+from ekuiper_tpu.ops import aggspec
 from ekuiper_tpu.ops.aggspec import ValueDict, extract_kernel_plan
 from ekuiper_tpu.ops.emit import build_direct_emit
 from ekuiper_tpu.planner.planner import device_path_eligible
 from ekuiper_tpu.runtime.events import Trigger
 from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
+from ekuiper_tpu.ops.sketches import HH_MAX_CODES
 from ekuiper_tpu.sql.parser import parse_select
 from ekuiper_tpu.utils.config import RuleOptionConfig
 
@@ -233,6 +235,149 @@ class TestPlannerGates:
         assert extract_kernel_plan(stmt) is None
 
 
+# ---- seeded streams for TestValueDict.test_encode_as_the_routine_stood:
+# name -> () -> (steps, code budget or None); a step is a column or "restore"
+def _mixture(rng, n, dtype=np.int64):
+    """The heavy-hitters cell's codes: 7 / 13 / 99 heavy, the rest uniform
+    over 100..2099."""
+    codes = rng.integers(100, 2100, n)
+    p = rng.random(n)
+    for value, lo, hi in ((7, 0.0, 0.35), (13, 0.35, 0.55), (99, 0.55, 0.7)):
+        codes[(p >= lo) & (p < hi)] = value
+    return codes.astype(dtype)
+
+
+def _stream_int64_mixture():
+    rng = np.random.default_rng(2 ** 31 + 30)
+    return [_mixture(rng, 32_768) for _ in range(4)], None
+
+
+def _stream_float64_with_nan():
+    rng = np.random.default_rng(31)
+    cols = []
+    for _ in range(4):
+        col = rng.integers(-50, 50, 4_096) / 4.0
+        col[rng.random(4_096) < 0.1] = np.nan
+        cols.append(col)
+    cols.append(np.full(16, np.nan))  # nothing but NaN
+    cols.append(np.array([np.inf, -np.inf, 0.25, np.nan, 1e300]))
+    return cols, None
+
+
+def _stream_negative_and_beyond_2_31():
+    rng = np.random.default_rng(32)
+    pool = np.concatenate([
+        rng.integers(-(1 << 62), 1 << 62, 500),
+        rng.integers(-(1 << 33), -(1 << 31), 200),
+        rng.integers(1 << 31, 1 << 33, 200),
+        [-(1 << 63), (1 << 63) - 1, 0, -1, (1 << 53) + 1]])
+    return [rng.choice(pool, 4_096) for _ in range(3)], None
+
+
+def _stream_known_values_only():
+    rng = np.random.default_rng(33)
+    first = np.arange(100, 2100)
+    return [first] + [rng.choice(first, 8_192) for _ in range(3)], None
+
+
+def _stream_first_seen_mid_stream():
+    rng = np.random.default_rng(34)
+    steps = []
+    for hi in (200, 200, 900, 900, 5_000, 200, 70_000, 70_000):
+        steps.append(rng.integers(100, hi, 2_048))
+    # below the table's lowest and above its highest, both ways round
+    steps += [np.array([-5, 99, 100, 69_999, 70_000, 1 << 40]),
+              np.array([1 << 40, -5, -6])]
+    return steps, None
+
+
+def _stream_int64_then_float64():
+    rng = np.random.default_rng(35)
+    steps = []
+    for i in range(6):
+        col = _mixture(rng, 4_096, np.float64 if i % 2 else np.int64)
+        steps.append(col)
+    steps.append(np.array([7.5, 7.0, 8.0, 7.25]))  # not all integral
+    steps.append(np.array([7, 8, 9], dtype=np.int64))
+    # beyond 2**53 an int and the float beside it are different values
+    steps.append(np.array([(1 << 53) + 1, 1 << 53], dtype=np.int64))
+    steps.append(np.array([float(1 << 53), float((1 << 53) + 2)]))
+    return steps, None
+
+
+def _stream_empty_columns():
+    return [np.array([], dtype=np.int64), np.array([3, 1, 2]),
+            np.array([], dtype=np.int64), np.array([], dtype=np.float64),
+            np.array([], dtype=np.object_), np.array([2.0, 4.0]),
+            np.array([], dtype=np.float64)], None
+
+
+def _stream_narrow_range():
+    rng = np.random.default_rng(36)
+    return [rng.integers(-300, 300, 4_096) for _ in range(3)], None
+
+
+def _stream_wide_range():
+    rng = np.random.default_rng(37)
+    pool = rng.integers(0, 1 << 40, 300)
+    return [rng.choice(pool, 4_096) for _ in range(3)] + \
+        [rng.integers(0, 1 << 40, 64)], None
+
+
+def _stream_narrow_turns_wide_turns_narrow():
+    rng = np.random.default_rng(38)
+    return [rng.integers(0, 50, 512), np.array([1 << 30, 3, 1 << 20]),
+            rng.integers(0, 50, 512),
+            rng.integers(0, 1 << 21, 300_000),  # fills the span in
+            rng.integers(0, 1 << 21, 4_096)], None
+
+
+def _stream_overflow_small_budget():
+    rng = np.random.default_rng(39)
+    return [rng.integers(0, 40, 256), rng.integers(0, 120, 256),
+            rng.integers(0, 120, 256), rng.integers(0, 40, 256),
+            rng.integers(0, 120, 256) / 2.0,
+            np.array(["a", "b", None], dtype=np.object_)], 64
+
+
+def _stream_restore_then_more():
+    rng = np.random.default_rng(40)
+    return [_mixture(rng, 8_192), _mixture(rng, 8_192), "restore",
+            _mixture(rng, 8_192), rng.integers(0, 4_000, 8_192), "restore",
+            _mixture(rng, 8_192, np.float64), _mixture(rng, 8_192)], None
+
+
+def _stream_other_dtypes():
+    rng = np.random.default_rng(41)
+    return [rng.integers(-100, 100, 512).astype(np.int32),
+            rng.integers(-100, 100, 512).astype(np.int8),
+            rng.integers(0, 200, 512).astype(np.uint16),
+            rng.integers(0, 200, 512).astype(np.uint64),
+            np.array([(1 << 64) - 1, 5, 1 << 63], dtype=np.uint64),
+            (rng.integers(-100, 100, 512) / 8).astype(np.float32),
+            np.array([0.1, 0.2, np.nan], dtype=np.float32),
+            np.array([0.1, 0.2, 0.1 + 2 ** -30]),
+            rng.random(64) < 0.5,
+            rng.integers(-100, 100, 512)], None
+
+
+def _stream_object_branch():
+    return [np.array(["a", "b", "a", None, "c"], dtype=np.object_),
+            np.array([7, "7", 7.0, None, [1, 2], {"k": 1}, [1, 2]],
+                     dtype=np.object_),
+            np.array([7, 8, 9]), np.array([7.0, 8.5]),
+            np.array(["c", 8, 8.5, "d"], dtype=np.object_)], None
+
+
+_STREAMS = {f.__name__[len("_stream_"):]: f for f in (
+    _stream_int64_mixture, _stream_float64_with_nan,
+    _stream_negative_and_beyond_2_31, _stream_known_values_only,
+    _stream_first_seen_mid_stream, _stream_int64_then_float64,
+    _stream_empty_columns, _stream_narrow_range, _stream_wide_range,
+    _stream_narrow_turns_wide_turns_narrow, _stream_overflow_small_budget,
+    _stream_restore_then_more, _stream_other_dtypes, _stream_object_branch)}
+
+
 class TestValueDict:
     def test_roundtrip_mixed(self):
         vd = ValueDict()
@@ -260,3 +405,162 @@ class TestValueDict:
         assert vd2.decode(0) == "x"
         c = vd2.encode(np.array(["y", "z"], dtype=np.object_))
         assert c[0] == 1.0 and c[1] == 2.0
+
+    # ---- the table lookup (PR 30) against the routine as it stood: on the
+    # same batches, the same codes, the same dictionary
+    @pytest.mark.parametrize("name", sorted(_STREAMS))
+    def test_encode_as_the_routine_stood(self, name, monkeypatch):
+        steps, budget = _STREAMS[name]()
+        if budget is not None:
+            monkeypatch.setattr(aggspec, "HH_MAX_CODES", budget)
+        vd, ref = ValueDict(), _ValueDictAsItStood(
+            budget if budget is not None else HH_MAX_CODES)
+        for step in steps:
+            if isinstance(step, str):  # "restore": a checkpoint round trip
+                snap = vd.snapshot()
+                vd, ref2 = ValueDict(), _ValueDictAsItStood(ref.budget)
+                vd.restore(snap)
+                ref2.restore(ref.snapshot())
+                ref2.overflowed, ref = False, ref2
+                continue
+            got, want = vd.encode(step), ref.encode(step)
+            assert got.dtype == np.float32 and got.shape == want.shape
+            np.testing.assert_array_equal(got, want)  # NaN == NaN here
+            assert vd.snapshot() == ref.snapshot()
+            assert [type(v) for v in vd.snapshot()] == \
+                [type(v) for v in ref.snapshot()]
+            assert vd.overflowed == ref.overflowed
+        n = len(ref.snapshot())
+        assert [vd.decode(c) for c in range(-1, n + 1)] == \
+            [None] + ref.snapshot() + [None]
+
+    @pytest.mark.parametrize("dtype,values,base", [
+        # base: what the dense table subtracts; None: the searched table
+        (np.int64, [7, 13, 99, 2099, 100], 0),  # low and positive: no base
+        (np.int64, [-300, 299, 5], -301),  # under the floor of 4,096 slots
+        (np.int64, [0, 4093], -1),
+        (np.int64, [0, 4094], None),  # one slot too many
+        (np.int64, [5000, 5001], 4999),  # positive, but not low
+        (np.int64, [0, 1 << 40], None),
+        (np.int64, [-(1 << 63), -(1 << 63) + 5], None),  # no slot below
+        (np.int64, [(1 << 63) - 9, (1 << 63) - 1], (1 << 63) - 10),
+        (np.int64, list(range(0, 80_000, 9)), None),  # 9 slots a value
+        (np.int64, list(range(0, 80_000, 7)), -1),  # 7 slots a value
+        (np.int32, [5, 6, 7], 0),
+        (np.float64, [1.0, 2.0, 3.0], None),  # floats are searched
+    ])
+    def test_table_form_follows_the_known_values(self, dtype, values, base):
+        vd = ValueDict()
+        col = np.array(values, dtype=dtype)
+        first = vd.encode(col)
+        (table,) = vd._tables.values()
+        if base is None:
+            assert table.dense is None
+        else:
+            assert table.dense is not None and table.base == base
+            assert len(table.dense) == max(values) - base + 2
+        # the second time round no row is left for the dictionary
+        codes, missed = vd.lookup(col[::-1])
+        assert missed is None
+        np.testing.assert_array_equal(codes, first[::-1])
+
+    @pytest.mark.parametrize("dtype", [np.int64, np.float64])
+    def test_known_values_never_reach_python(self, dtype, monkeypatch):
+        vd = ValueDict()
+        rng = np.random.default_rng(5)
+        vd.encode(np.arange(7, 2100).astype(dtype))
+        monkeypatch.setattr(vd, "_code", None)  # a call would raise
+        monkeypatch.setattr(aggspec.np, "unique", None)
+        col = rng.integers(7, 2100, 32_768).astype(dtype)
+        codes = vd.encode(col)
+        monkeypatch.undo()
+        np.testing.assert_array_equal(codes, col - 7)
+
+    def test_learn_is_given_the_unknown_rows_only(self):
+        vd = ValueDict()
+        vd.encode(np.array([10, 11, 12]))
+        col = np.array([11, 500, 10, -3, 500, 12])
+        codes, missed = vd.lookup(col)
+        assert missed.tolist() == [1, 3, 4]
+        assert np.isnan(codes[missed]).all()
+        vd.learn(col, codes, missed)
+        assert codes.tolist() == [1.0, 4.0, 0.0, 3.0, 4.0, 2.0]
+        # a float column: NaN rows are nobody's to learn
+        codes, missed = vd.lookup(np.array([np.nan, 11.0, np.nan]))
+        assert missed.tolist() == [1]
+        vd.encode(np.array([11.0]))
+        codes, missed = vd.lookup(np.array([np.nan, 11.0, np.nan]))
+        assert missed is None and np.isnan(codes[[0, 2]]).all()
+
+    def test_overflow_at_the_real_budget(self):
+        """HH_MAX_CODES distinct values take every code; the next ones
+        encode NaN, stay out of the table and set `overflowed`."""
+        vd = ValueDict()
+        codes = vd.encode(np.arange(HH_MAX_CODES + 3, dtype=np.int64))
+        assert vd.overflowed and len(vd.snapshot()) == HH_MAX_CODES
+        assert codes[HH_MAX_CODES - 1] == HH_MAX_CODES - 1
+        assert np.isnan(codes[HH_MAX_CODES:]).all()
+        again, missed = vd.lookup(
+            np.array([0, HH_MAX_CODES - 1, HH_MAX_CODES + 1]))
+        assert missed.tolist() == [2] and again[1] == HH_MAX_CODES - 1
+
+
+class _ValueDictAsItStood:
+    """`ValueDict` before PR 30, kept as the reference: a sort of the whole
+    batch and a Python call per distinct value, known or not."""
+
+    def __init__(self, budget):
+        self.budget = budget
+        self._ids = {}
+        self._values = []
+        self.overflowed = False
+
+    def _code(self, v):
+        c = self._ids.get(v)
+        if c is None:
+            if len(self._values) >= self.budget:
+                self.overflowed = True
+                return np.nan
+            c = len(self._values)
+            self._ids[v] = c
+            self._values.append(v)
+        return float(c)
+
+    def encode(self, col):
+        n = len(col)
+        out = np.empty(n, dtype=np.float32)
+        if col.dtype == np.object_:
+            for i, v in enumerate(col.tolist()):
+                if v is None:
+                    out[i] = np.nan
+                    continue
+                try:
+                    out[i] = self._code(v)
+                except TypeError:
+                    out[i] = self._code(repr(v))
+            return out
+        arr = np.asarray(col)
+        if np.issubdtype(arr.dtype, np.floating):
+            nan = np.isnan(arr)
+        else:
+            nan = np.zeros(n, dtype=bool)
+        out = np.full(n, np.nan, dtype=np.float32)
+        clean = arr[~nan] if nan.any() else arr
+        if len(clean):
+            uniq, inverse = np.unique(clean, return_inverse=True)
+            ucodes = np.array(
+                [self._code(u.item()) for u in uniq], dtype=np.float32)
+            out[~nan] = ucodes[inverse]
+        return out
+
+    def snapshot(self):
+        return list(self._values)
+
+    def restore(self, values):
+        self._values = list(values)
+        self._ids = {}
+        for i, v in enumerate(self._values):
+            try:
+                self._ids[v] = i
+            except TypeError:
+                pass

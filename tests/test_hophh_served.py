@@ -233,7 +233,7 @@ def test_hh_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         # time twice: `health_sample` is what the health plane's covered
         # time and `kuiper_bottleneck_stage` are computed from
         assert fused.stats.nested_stages == {
-            "hh_encode", "hh_finalize", "hh_assemble"}
+            "hh_encode", "hh_encode_new", "hh_finalize", "hh_assemble"}
         sample = fused.stats.health_sample()["stages"]
         assert set(sample) == {"upload", "fold", "emit"}
         code, text = api.dispatch("GET", "/metrics", None, {})
@@ -268,6 +268,82 @@ def test_hh_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         assert {"kuiper:hh_encode", "kuiper:hh_finalize",
                 "kuiper:hh_assemble", "kuiper:jit:hh_finalize",
                 "kuiper:emit", "kuiper:upload"} <= names, sorted(names)
+    finally:
+        api.rules.stop_all()
+
+
+def _stage_total(text: str, fam: str, stage: str) -> float:
+    """Sum of `kuiper_op_stage_<fam>_total{...stage="<stage>"...}`."""
+    return sum(float(ln.rsplit(" ", 1)[1]) for ln in text.splitlines()
+               if ln.startswith(f"kuiper_op_stage_{fam}_total{{")
+               and f'stage="{stage}"' in ln)
+
+
+def test_hh_encode_new_counts_the_rows_the_table_lacked(mock_clock,
+                                                        fresh_tracer):
+    """`hh_encode_new` (PR 30) is opened for a micro-batch that has values
+    no table knows, with those rows: all of a cold dictionary's first
+    batch, none of a batch of known values, the first-seen rows of a later
+    one. Over `hh_encode`'s rows it is the miss share."""
+    api, got = _start_rule("hophh_new", 2)
+    try:
+        fresh_tracer.enable("hophh_new")
+        rng = np.random.default_rng(30)
+        known = np.array([7, 13, 99] + list(range(100, 150)))
+
+        def hop(first_seen=()):
+            codes = rng.choice(known, HOP_ROWS)
+            codes[:len(first_seen)] = first_seen
+            return rng.integers(0, N_KEYS, HOP_ROWS), codes
+
+        topo = api.rules.state("hophh_new").topo
+        fused = next(n for n in topo.ops
+                     if type(n).__name__ == "FusedWindowAggNode")
+
+        def marks():
+            st = fused.stats.snapshot()["stage_timings"]
+            new = st.get("hh_encode_new", {"calls": 0, "rows": 0})
+            return (st["hh_encode"]["calls"], st["hh_encode"]["rows"],
+                    new["calls"], new["rows"])
+
+        _drive_hop(mock_clock, "hophh_new/in", hop(), got)
+        assert marks() == (1, HOP_ROWS, 1, HOP_ROWS)  # a cold dictionary
+        for _ in range(3):  # known values only: the stage is not opened
+            _drive_hop(mock_clock, "hophh_new/in", hop(), got)
+        assert marks() == (4, 4 * HOP_ROWS, 1, HOP_ROWS)
+        _drive_hop(mock_clock, "hophh_new/in",
+                   hop(first_seen=[5000, 6000, 5000]), got)
+        assert marks() == (5, 5 * HOP_ROWS, 2, HOP_ROWS + 3)
+        _drive_hop(mock_clock, "hophh_new/in", hop(), got)
+        assert marks() == (6, 6 * HOP_ROWS, 2, HOP_ROWS + 3)
+        fused._drain_async_emits()
+        # the windows say the same: the three rows are counted as 5000 / 6000
+        late = {p["value"] for msgs in got[4:6] for m in msgs
+                for p in m["top"]}
+        assert late <= set(known.tolist()) | {5000, 6000}
+
+        # ---- /metrics: the four families; rows over rows = the miss share
+        code, text = api.dispatch("GET", "/metrics", None, {})
+        assert _stage_total(text, "calls", "hh_encode_new") == 2
+        assert _stage_total(text, "rows", "hh_encode_new") == HOP_ROWS + 3
+        assert _stage_total(text, "rows", "hh_encode") == 6 * HOP_ROWS
+        assert 0 < _stage_total(text, "cpu_us", "hh_encode_new") <= \
+            _stage_total(text, "us", "hh_encode_new") <= \
+            _stage_total(text, "us", "hh_encode")
+        # ---- a nested stage: counted, and left out of the health plane
+        assert "hh_encode_new" in fused.stats.nested_stages
+        assert set(fused.stats.health_sample()["stages"]) == {
+            "upload", "fold", "emit"}
+        # ---- the rule's trace: a span under hh_encode, two in six batches
+        spans = [s for tid in fresh_tracer.rule_traces("hophh_new")
+                 for s in fresh_tracer.trace(tid)]
+        by_id = {s["spanId"]: s for s in spans}
+        mine = [s for s in spans if s.get("stage") == "hh_encode_new"]
+        assert sorted(s["rows"] for s in mine) == [3, HOP_ROWS]
+        for s in mine:
+            assert by_id[s["parentSpanId"]].get("stage") == "hh_encode"
+            assert s["attributes"]["within"] == "hh_encode"
+        assert len([s for s in spans if s.get("stage") == "hh_encode"]) == 6
     finally:
         api.rules.stop_all()
 
