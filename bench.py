@@ -5,18 +5,12 @@ over an MQTT demo stream) at TPU scale: 10,000 devices, avg/count/min/max
 aggregates, measured through the real engine node (key encode + device fold
 + window emit), not just the raw kernel.
 
-Two phases, mirroring standard throughput-vs-latency methodology:
-
-- Phase T (throughput): saturate the host→device link. Every row folds
-  on device; every window emits from a pre-issued DEVICE fetch the boundary
-  waits for (no host backstop) — the reported rows/s therefore includes
-  the full cost of device-served emission.
-- Phase L (latency): pace ingest at the north-star load (>=1M rows/s,
-  BASELINE.md) where the link has headroom, and measure emit latency over
-  >=50 window boundaries. The pre-issued fetch lands before the boundary,
-  so emits are device-served with p99 in single-digit ms; the per-window
-  source tag (device/backstop/sync) is reported so a host-served emit can
-  never masquerade as a device number (r02 post-mortem).
+Phase T (throughput) saturates the host→device link. Every row folds on
+device; every window emits from a pre-issued DEVICE fetch the boundary
+waits for — the reported rows/s therefore includes the full cost of
+device-served emission. The per-window source tag (device/sync) is reported
+so a sync finalize can never masquerade as a pre-issued device number (r02
+post-mortem). Paced emit latency is the benchmark's (`tumbling10k.paced`).
 
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
 Baseline = the reference's best published single-node throughput for its
@@ -85,7 +79,6 @@ PHASE_FLOORS = (
     ("full-pipe-contended", 90.0),
     ("hetero 256-rule", 90.0),
     ("phase_throughput", 60.0),
-    ("phase_latency", 40.0),
     ("sliding", 50.0),
     ("heavy_hitters", 30.0),
     ("hll_1m", 60.0),
@@ -178,13 +171,6 @@ T_WINDOW_BATCHES = 64
 T_PRE_ISSUE_AT = (48,)
 T_WINDOWS = 20
 T_BLOCK_EVERY = 16  # bound the dispatch queue (client buffers uploads)
-
-# Phase L: paced at north-star load
-L_TARGET_ROWS_S = 1_500_000
-L_WINDOW_BATCHES = 35  # ~1.5s windows at the paced rate
-L_PRE_ISSUE_AT = (25, 30)  # ~440ms / ~220ms leads
-L_MIN_SAMPLES = 50
-L_MAX_SECONDS = 150.0
 
 SQL = (
     "SELECT deviceId, avg(temperature) AS avg_t, count(*) AS cnt, "
@@ -499,7 +485,7 @@ def bench_hopping_heavy_hitters(batches, kt_slots) -> None:
     emit_ms = []
     # paced at the north-star load: boundary fetches queue FIFO behind
     # in-flight folds, so emit latency is only meaningful when the link
-    # has headroom (same methodology as phase L)
+    # has headroom
     interval = BATCH_ROWS / 1_100_000
     t0 = time.time()
     while time.time() - t0 < 10.0:
@@ -2956,7 +2942,7 @@ def bench_event_time(batches, kt_slots) -> None:
     record("event_time", rows_per_sec=rows / elapsed, windows=n_windows)
 
 
-def make_node(backstop: bool):
+def make_node():
     from ekuiper_tpu.ops.aggspec import extract_kernel_plan
     from ekuiper_tpu.ops.emit import build_direct_emit
     from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
@@ -2970,7 +2956,7 @@ def make_node(backstop: bool):
     node = FusedWindowAggNode(
         "bench", stmt.window, plan, dims=[d.expr for d in stmt.dimensions],
         capacity=KEY_SLOTS, micro_batch=BATCH_ROWS, direct_emit=direct,
-        emit_columnar=True, prefinalize_backstop=backstop,
+        emit_columnar=True,
     )
     node.state = node.gb.init_state()
     node.broadcast = lambda item: None
@@ -3014,7 +3000,6 @@ def warmup(node, batches) -> None:
     node.process(batches[3])
     node._emit(WindowRange(0, 10_000))  # merged path (compiles components)
     node.state = node.gb.reset_pane(node.state, 0)
-    node.begin_window_backstop()
     jax.block_until_ready(node.state)
 
 
@@ -3025,8 +3010,7 @@ class WindowStats:
         self.latencies: list = []
         self.device_latencies: list = []
         self.fetch_ms: list = []
-        self.sources = {"device": 0, "backstop": 0, "sync": 0}
-        self.storms = 0
+        self.sources = {"device": 0, "sync": 0}
 
     def boundary(self, node, emit_fn) -> None:
         from ekuiper_tpu.data.rows import WindowRange
@@ -3036,8 +3020,6 @@ class WindowStats:
         lat = (time.time() - t) * 1000
         self.latencies.append(lat)
         node.state = node.gb.reset_pane(node.state, 0)
-        node.begin_window_backstop()
-        self.storms += 1 if node._storm else 0
         info = node.last_emit_info
         if info is None:  # empty window: no emit, no source to attribute
             return
@@ -3054,23 +3036,22 @@ class WindowStats:
         return (
             f"emit p50={pct(self.latencies, 50):.1f}ms "
             f"p99={pct(self.latencies, 99):.1f}ms over "
-            f"{len(self.latencies)} samples; sources device/backstop/sync="
-            f"{s['device']}/{s['backstop']}/{s['sync']}; "
+            f"{len(self.latencies)} samples; sources device/sync="
+            f"{s['device']}/{s['sync']}; "
             f"device-served p50={pct(self.device_latencies, 50):.1f}ms "
             f"p99={pct(self.device_latencies, 99):.1f}ms "
-            f"(fetch issue→landed p50={pct(self.fetch_ms, 50):.0f}ms); "
-            f"storm windows={self.storms}"
+            f"(fetch issue→landed p50={pct(self.fetch_ms, 50):.0f}ms)"
         )
 
 
 def phase_throughput(batches) -> float:
     """Saturate the ingest path; boundaries WAIT on the pre-issued device
-    fetch (no backstop), so throughput includes device-served emission."""
+    fetch, so throughput includes device-served emission."""
     import jax
 
     from ekuiper_tpu.runtime.events import PreTrigger
 
-    node = make_node(backstop=False)
+    node = make_node()
     warmup(node, batches)
     stats = WindowStats()
     rows = 0
@@ -3107,53 +3088,8 @@ def phase_throughput(batches) -> float:
     record("tumbling_saturated", rows_per_sec=rows_per_sec,
            emit_p50_ms=float(np.percentile(stats.latencies, 50)),
            emit_p99_ms=float(np.percentile(stats.latencies, 99)),
-           windows=len(stats.latencies), storms=stats.storms)
+           windows=len(stats.latencies))
     return rows_per_sec
-
-
-def phase_latency(batches) -> None:
-    """Pace ingest at the north-star load and measure boundary latency."""
-    import jax
-
-    from ekuiper_tpu.runtime.events import PreTrigger
-
-    node = make_node(backstop=True)
-    warmup(node, batches)
-    stats = WindowStats()
-    interval = BATCH_ROWS / L_TARGET_ROWS_S
-    rows = 0
-    n = 0
-    t0 = time.time()
-    while (len(stats.latencies) < L_MIN_SAMPLES
-           and time.time() - t0 < L_MAX_SECONDS):
-        target = t0 + n * interval
-        delay = target - time.time()
-        if delay > 0:
-            time.sleep(delay)
-        node.process(batches[n % len(batches)])
-        rows += BATCH_ROWS
-        n += 1
-        m = n % L_WINDOW_BATCHES
-        if m in L_PRE_ISSUE_AT:
-            node.on_pre_trigger(PreTrigger(ts=0))
-        elif m == 0:
-            stats.boundary(node, node._emit)
-    jax.block_until_ready(node.state)
-    elapsed = time.time() - t0
-    print(
-        f"# phase L (paced {L_TARGET_ROWS_S / 1e6:.1f}M rows/s): "
-        f"{rows:,} rows in {elapsed:.2f}s "
-        f"({rows / elapsed:,.0f} rows/s achieved); {stats.line()}",
-        file=sys.stderr,
-    )
-    record("tumbling_paced", rows_per_sec=rows / elapsed,
-           emit_p50_ms=float(np.percentile(stats.latencies, 50))
-           if stats.latencies else None,
-           emit_p99_ms=float(np.percentile(stats.latencies, 99))
-           if stats.latencies else None,
-           device_served=stats.sources["device"],
-           backstop_served=stats.sources["backstop"],
-           storms=stats.storms)
 
 
 def _final_json(rows_per_sec: float = 0.0, error: str = "") -> None:
@@ -3239,7 +3175,6 @@ def main() -> None:
     dog = PhaseWatchdog()
     for name, budget_s, fn in (
         ("phase_throughput", 900.0, lambda: phase_throughput(batches)),
-        ("phase_latency", 600.0, lambda: phase_latency(batches)),
         ("sliding", 600.0,
          lambda: bench_sliding_percentile(batches, KEY_SLOTS)),
         ("heavy_hitters", 600.0,

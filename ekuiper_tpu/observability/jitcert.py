@@ -350,8 +350,7 @@ def _derive_boundary(ks: KernelShape, op: str, rule: Optional[str],
                   every caller passes panes=None on the static route;
                   subsets go through the traced-mask twin),
     pane_mask   — traced bool[n_panes] (finalize_dyn / hh_finalize),
-    pane_scalar — scalar pane index (reset_pane),
-    shadow      — host-shadow components + scalar pane (absorb)."""
+    pane_scalar — scalar pane index (reset_pane)."""
     sigs: List[str] = []
     deriv = [f"capacity ladder: {ks.base_capacity} x2^0..{grows}"]
     if ks.touch:
@@ -366,16 +365,6 @@ def _derive_boundary(ks: KernelShape, op: str, rule: Optional[str],
             sigs.append(_sig(state + [_arr("bool", ks.n_panes)]))
         elif tail == "pane_scalar":
             sigs.append(_sig(state + [_arr("int32")]))
-        elif tail == "shadow":
-            shadow: List[str] = []
-            for comp in sorted(list(ks.comps) + ["act"]):
-                if comp == "act":
-                    dims: Tuple[int, ...] = (cap,)
-                else:
-                    k, wide = ks.comps[comp]
-                    dims = (cap, k) + ((wide,) if wide else ())
-                shadow.append(_arr("float32", *dims))
-            sigs.append(_sig(state + shadow + [_arr("int32")]))
         else:  # pragma: no cover - derivation bug
             raise ValueError(f"unknown boundary tail {tail!r}")
     if tail == "static_all":
@@ -385,9 +374,6 @@ def _derive_boundary(ks: KernelShape, op: str, rule: Optional[str],
     elif tail == "pane_mask":
         deriv.append(f"pane mask: traced bool[{ks.n_panes}] — one "
                      "executable per capacity, any pane subset")
-    elif tail == "shadow":
-        deriv.append("host-shadow components at state capacity + scalar "
-                     "pane (checkpoint absorb)")
     return SiteCert(op, rule, "_derive_boundary",
                     {"base_capacity": ks.base_capacity, "grows": grows,
                      "n_panes": ks.n_panes, "tail": tail,
@@ -494,7 +480,7 @@ def _derive_tier(ks: KernelShape, op: str, rule: Optional[str],
     demote batch. `tail` is one of:
     demote  — int32[D] slot vector (gather + identity reset),
     promote — float32[D, W] packed rows + int32[D] slot vector
-              (scatter-merge, absorb's combine algebra)."""
+              (scatter-merge: add, or min / max, per component)."""
     packed_w = _tier_packed_w(ks.comps, ks.n_panes)
     sigs: List[str] = []
     deriv = [
@@ -659,7 +645,6 @@ def _groupby_certs(kernel, prefix: str, rule: Optional[str]
         _derive_boundary(ks, f"{prefix}.components_dyn", rule,
                          "pane_mask"),
         _derive_boundary(ks, f"{prefix}.reset_pane", rule, "pane_scalar"),
-        _derive_boundary(ks, f"{prefix}.absorb", rule, "shadow"),
     ]
     if ks.host_finalize_only:
         certs.append(_derive_boundary(ks, f"{prefix}.hh_finalize", rule,
@@ -687,7 +672,6 @@ def _sharded_certs(kernel, rule: Optional[str]) -> List[SiteCert]:
         _derive_boundary(ks, "sharded.finalize_dyn", rule, "pane_mask"),
         _derive_boundary(ks, "sharded.components", rule, "static_all"),
         _derive_boundary(ks, "sharded.reset_pane", rule, "pane_scalar"),
-        _derive_boundary(ks, "sharded.absorb", rule, "shadow"),
     ]
 
 
@@ -771,7 +755,6 @@ SITE_DERIVATIONS: Dict[str, str] = {
     "groupby.components": "_derive_boundary(static_all)",
     "groupby.components_dyn": "_derive_boundary(pane_mask)",
     "groupby.reset_pane": "_derive_boundary(pane_scalar)",
-    "groupby.absorb": "_derive_boundary(shadow)",
     "groupby.hh_finalize": "_derive_boundary(pane_mask)",
     "multirule.fold": "_derive_fold(lead_rules)",
     "multirule.finalize": "_derive_boundary(static_all)",
@@ -782,7 +765,6 @@ SITE_DERIVATIONS: Dict[str, str] = {
     "sharded.finalize_dyn": "_derive_boundary(pane_mask)",
     "sharded.components": "_derive_boundary(static_all)",
     "sharded.reset_pane": "_derive_boundary(pane_scalar)",
-    "sharded.absorb": "_derive_boundary(shadow)",
     "sketch.update": "_derive_sketch",
     "sketch.query": "_derive_sketch(query_only)",
     "slidingring.advance": "_derive_ring(advance)",

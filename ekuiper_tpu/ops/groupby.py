@@ -638,14 +638,20 @@ class DeviceGroupBy:
     ) -> Tuple[List[np.ndarray], np.ndarray]:
         """Complete a pre-issued finalize: fetch device components (usually
         already on host), merge the tail shadow, compute final values in
-        numpy. Same (outs, act) contract as finalize()."""
+        numpy. Same (outs, act) contract as finalize(). `pending` None:
+        nothing on the device belongs to the window, the shadow is all of
+        it."""
         from .prefinalize import merge_components
 
-        # capacity may have grown during a frozen tail (new keys live only in
-        # the shadow) — merge at the widest extent so no slot is truncated
+        # the key table may have grown since the snapshot was taken (new
+        # keys live only in the shadow) — merge at the widest extent so no
+        # slot is truncated
         cap = max(self.capacity,
                   shadow.capacity if shadow is not None else 0)
-        comb = merge_components(pending.get(), shadow, cap)
+        if pending is None:
+            comb = merge_components(shadow.data, None, cap)
+        else:
+            comb = merge_components(pending.get(), shadow, cap)
         return self._final_from_components(comb, n_keys)
 
     def _hh_finalize_impl(self, state, pane_mask):
@@ -752,40 +758,6 @@ class DeviceGroupBy:
         act = stacked[-1]
         host = apply_int_semantics(self.plan.specs, host)
         return host, np.asarray(act[:n_keys])
-
-    # ----------------------------------------------------------------- absorb
-    def _absorb_impl(self, state, sh, pane_idx):
-        for comp in list(state.keys()):
-            if comp not in sh:
-                continue  # touch column: shadows carry no policy state
-            arr = state[comp]
-            u = sh[comp]
-            if comp == "mn":
-                state[comp] = arr.at[pane_idx].min(u)
-            elif comp in ("mx", "hll"):
-                state[comp] = arr.at[pane_idx].max(u)
-            else:
-                state[comp] = arr.at[pane_idx].add(u)
-        return state
-
-    def absorb(self, state: Dict[str, Any], shadow_data: Dict[str, np.ndarray],
-               pane_idx: int) -> Dict[str, Any]:
-        """Merge host-shadow components into one pane of the device state.
-        Used when a checkpoint barrier lands during a host-only window tail
-        (runtime/nodes_fused.py): the shadowed rows are flushed to the device
-        so the snapshot stays complete."""
-        import jax
-        import jax.numpy as jnp
-
-        if not hasattr(self, "_absorb"):
-            from ..runtime.aotcache import aot_jit
-
-            self._absorb = aot_jit(self._absorb_impl,
-                                       op=self._watch_op("absorb"),
-                                       kind="boundary",
-                                       donate_argnums=(0,))
-        sh = {k: jnp.asarray(v) for k, v in shadow_data.items()}
-        return self._absorb(state, sh, jnp.asarray(pane_idx, dtype=jnp.int32))
 
     # ------------------------------------------------------------------ reset
     def _reset_pane_impl(self, state, pane_idx):

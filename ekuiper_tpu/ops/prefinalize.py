@@ -388,28 +388,6 @@ def merge_components(
     return out
 
 
-class IdentityFinalize:
-    """Always-ready stand-in for a device components fetch whose state
-    snapshot is EMPTY (identity values). Used by storm mode
-    (runtime/nodes_fused.py): when the device link is stalling, a window
-    runs fully host-shadowed and merges against this identity — emit
-    latency stays bounded while real fetches probe for recovery."""
-
-    def __init__(self, comp_specs: Dict[str, List[int]], capacity: int) -> None:
-        self.capacity = capacity
-        self._comps: Dict[str, np.ndarray] = {}
-        for comp, spec_idxs in comp_specs.items():
-            shape = (capacity,) + _comp_shape(comp, spec_idxs)
-            self._comps[comp] = np.full(shape, _INIT[comp], dtype=np.float32)
-        self._comps["act"] = np.zeros(capacity, dtype=np.float32)
-
-    def ready(self) -> bool:
-        return True
-
-    def get(self) -> Dict[str, np.ndarray]:
-        return self._comps
-
-
 def begin_pending(stacked, capacity: int, layout) -> "PendingFinalize":
     """Start the async device→host copy of a dispatched components array
     and wrap it — the ONE async-fetch protocol shared by the prefinalize,

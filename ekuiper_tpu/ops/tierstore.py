@@ -24,7 +24,7 @@ When a demoted key reappears in an ingest batch, the slot-encode path is
 the admission point: the batch's new-key log tells us exactly which keys
 are returning before the fold runs, and one certified scatter
 (`tierstore.promote`) merges their spilled per-pane partials back into a
-fresh device slot — add/min/max per component, exactly `absorb`'s
+fresh device slot — add/min/max per component, the fold's own combine
 algebra, so the emission is bit-equal to never having demoted. The
 ingest prep's upload stage can start the H2D copy of the packed rows a
 batch early (`TierManager.prefetch`, runtime/ingest.py).
@@ -238,8 +238,8 @@ class TierStore:
     def promote(self, state, packed: Any, slots: np.ndarray):
         """Scatter-merge packed rows back into device slots: add for the
         additive components (n/s1/s2/hist/hh/act), min/max for mn and
-        mx/hll — `absorb`'s algebra, so a promoted key's state is
-        bit-equal to never having left. Padding rows must be
+        mx/hll — the fold's combine algebra, so a promoted key's state
+        is bit-equal to never having left. Padding rows must be
         `init_row()` (the combine identity) so duplicate pad slots are
         no-ops. `packed` may be a pre-uploaded device block (prefetch)."""
         import jax

@@ -169,7 +169,6 @@ def merged_options(rule: RuleDef) -> RuleOptionConfig:
         "concurrency": "concurrency",
         "debug": "debug",
         "planOptimizeStrategy": "plan_optimize_strategy",
-        "tailMode": "tail_mode",
         "prefinalizeLeadMs": "prefinalize_lead_ms",
         "decodePoolSize": "decode_pool_size",
         "decodeShards": "decode_shards",
@@ -1003,12 +1002,6 @@ def _build_device_chain(
         rule_id=rule_id, buffer_length=opts.buffer_length,
         direct_emit=direct, mesh=mesh,
         prefinalize_lead_ms=opts.prefinalize_lead_ms,
-        # a served boundary waits for its device fetch (on the emit worker
-        # when it has not landed): with the chip attached the host shadow
-        # answered 1 of 6 two-second windows in the PR 22 acceptance run,
-        # and a host-served window is a different result, not a faster one
-        prefinalize_backstop=False,
-        tail_mode=opts.tail_mode,
         emit_columnar=opts.emit_columnar,
         is_event_time=opts.is_event_time,
         late_tolerance_ms=opts.late_tolerance_ms,

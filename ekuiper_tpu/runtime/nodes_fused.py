@@ -80,8 +80,6 @@ class FusedWindowAggNode(Node):
         mesh=None,  # jax.sharding.Mesh — run the kernel sharded (parallel/)
         prefinalize_lead_ms: int = 250,  # latency-hiding emit (prefinalize.py)
         emit_columnar: bool = False,  # window result stays a ColumnBatch
-        prefinalize_backstop: bool = True,  # host backstop: boundaries never block
-        tail_mode: str = "device",  # window-tail rows: "device" | "host"
         is_event_time: bool = False,  # watermark-driven panes (see below)
         late_tolerance_ms: int = 0,
         dev_ring_budget_mb: int = 256,  # sliding device-state HBM cap
@@ -351,11 +349,10 @@ class FusedWindowAggNode(Node):
         # latency-hiding emit: pre-issued device finalize + host tail shadow.
         # Only for timer-driven windows (boundary known in advance), plans
         # whose expressions have numpy twins, and non-collective kernels.
-        # _pipeline holds up to 3 (PendingFinalize, HostShadow) pairs: a
+        # _pipeline holds up to 2 (PendingFinalize, HostShadow) pairs: a
         # fresher pre-issue is stacked when an earlier fetch is still in
-        # flight at the next pre-trigger (link jitter), and the boundary
-        # uses the newest READY one — emit latency decouples from device
-        # round-trip variance.
+        # flight at the next pre-trigger, and the boundary uses the newest
+        # READY one.
         self._pipeline = []
         self._pre_timers = []
         self.prefinalize_lead_ms = int(prefinalize_lead_ms)
@@ -371,42 +368,12 @@ class FusedWindowAggNode(Node):
                             ast.WindowType.HOPPING_WINDOW)
             and self.prefinalize_lead_ms < self._tick_interval()
         )
-        # Window-tail handling after a pre-issue freezes a snapshot:
-        #
-        # "device" (default): tail rows keep folding into the device state
-        #   AND into the pre-issue's host shadow. The emitted window =
-        #   snapshot ⊕ shadow counts each row exactly once (the snapshot
-        #   excludes tail rows, the shadow holds exactly them); the device
-        #   state stays COMPLETE at all times, so checkpoints need no
-        #   flush-back and hopping panes retain tail rows for later windows.
-        #
-        # "host": tumbling-only. Tail rows die at the boundary reset anyway,
-        #   so once a pre-issue freezes the snapshot they fold into host
-        #   shadows ONLY — zero upload traffic competing with the result
-        #   fetch. Useful when the host→device link is SATURATED: the
-        #   fetch needs a quiet channel to land.
-        #   A checkpoint barrier in the frozen span flushes the frozen
-        #   span's shadow back to the device (absorb).
-        if tail_mode not in ("device", "host"):
-            raise ValueError(
-                f"tail_mode must be 'device' or 'host', got {tail_mode!r}")
-        self.tail_mode = tail_mode
-        self._tail_host_only = (
-            self._prefinalize_ok and tail_mode == "host"
-            and self.wt == ast.WindowType.TUMBLING_WINDOW
-        )
-        self._device_frozen = False  # set at the first real pre-issue
-        # backstop: every window opens with an always-ready identity entry
-        # plus a window-spanning shadow, so a boundary NEVER blocks on the
-        # device link — the device result is preferred whenever its fetch
-        # lands (steady state), the backstop serves link-stall windows.
-        # Tumbling-only: a hopping window spans panes older than the last
-        # boundary, which a boundary-started shadow cannot represent.
-        self._backstop_ok = (
-            self._prefinalize_ok
-            and self.wt == ast.WindowType.TUMBLING_WINDOW
-        )
-        self._backstop = bool(prefinalize_backstop) and self._backstop_ok
+        # After a pre-issue takes its snapshot, tail rows keep folding into
+        # the device state AND into that pre-issue's host shadow. The
+        # emitted window = snapshot ⊕ shadow counts each row exactly once
+        # (the snapshot excludes tail rows, the shadow holds exactly them);
+        # the device state stays COMPLETE at all times, so checkpoints need
+        # no flush-back and hopping panes retain tail rows for later windows.
         # COUNT-window async emission: the boundary dispatches the device
         # finalize on an immutable state snapshot, resets, and keeps folding;
         # a worker thread fetches + emits when the result lands. Emission
@@ -436,10 +403,9 @@ class FusedWindowAggNode(Node):
         # and a sync fetch at the boundary stalls every rider's fold stream
         self._async_mr = False  # set by MultiRuleFusedNode
         # deferred boundary emission: when no pre-issue has landed at a
-        # tumbling/hopping boundary (and no host backstop can serve), the
-        # merge wait moves to the emit worker instead of stalling folds —
-        # crucial for wide sketch finalizes (hll components are KBs/key)
-        # on hopping windows, which have no backstop
+        # tumbling/hopping boundary, the merge wait moves to the emit
+        # worker instead of stalling folds — crucial for wide sketch
+        # finalizes (hll components are KBs/key)
         self._emit_late_async = (
             self.wt in (ast.WindowType.TUMBLING_WINDOW,
                         ast.WindowType.HOPPING_WINDOW)
@@ -452,18 +418,15 @@ class FusedWindowAggNode(Node):
         # worker-installed slot->key decode pin for deferred deliveries
         # (tiered slot recycling; see _keys_snapshot)
         self._kt_keys_override = None
-        # telemetry: the last boundary found no landed device fetch
-        self._storm = False
-        # per-boundary record: {"source": "device"|"backstop"|"sync",
-        #  "fetch_ms": issue→landed ms of the chosen fetch (-1 in flight),
-        #  "ages_ms": [age of each real pre-issue at the boundary]}
+        # per-boundary record: {"source": "device"|"sync"|"device-async"|
+        #  "device-async-late"|"device-ring",
+        #  "fetch_ms": issue→landed ms of the chosen fetch (-1 in flight)}
         self.last_emit_info: Optional[dict] = None
         # cumulative twin of last_emit_info["source"], one count per
-        # emitted window — a boundary the host backstop answered stays
-        # visible after the next boundary overwrites the record above
-        # (surfaced in /rules/{id}/status)
+        # emitted window — how a boundary was answered stays visible after
+        # the next boundary overwrites the record above (surfaced in
+        # /rules/{id}/status)
         self.emit_sources: Dict[str, int] = {}
-        self._identity = None  # cached IdentityFinalize (immutable, per capacity)
 
     def _make_gb(self, plan, capacity: int, micro_batch: int, mesh):
         """Build the group-by kernel; subclasses override (MultiRuleFusedNode
@@ -601,13 +564,6 @@ class FusedWindowAggNode(Node):
             self._warmup_stage = "prefinalize"
             pending = self.gb.prefinalize_begin(dummy)
             self.gb.prefinalize_merge(pending, None, 1)
-        if self._tail_host_only:
-            self._warmup_stage = "absorb"
-            # compile absorb with an identity (empty) shadow
-            from ..ops.prefinalize import HostShadow
-
-            hs = HostShadow(self.plan, self.gb.comp_specs, self.gb.capacity)
-            dummy = self.gb.absorb(dummy, hs.data, 0)
         if self.tier is not None:
             self._warmup_stage = "tier"
             # compile the demote/promote sites so the first boundary
@@ -667,8 +623,8 @@ class FusedWindowAggNode(Node):
             next_end - now, lambda ts: self.put_control(Trigger(ts=ts))
         )
         if self._prefinalize_ok:
-            # two chances per boundary: the 2x-lead pre-issue covers link
-            # jitter, the 1x-lead one refreshes if the first already landed
+            # two chances per boundary: a pre-issue at 2x lead, and one at
+            # 1x lead that on_pre_trigger skips when the first has landed
             self._pre_timers = []
             lead = self.prefinalize_lead_ms
             for k in (2, 1):
@@ -725,8 +681,7 @@ class FusedWindowAggNode(Node):
             return self._fold_sliding(sub)
         return self._fold_rows(sub, self.cur_pane)
 
-    def _shared_encode(self, sub: ColumnBatch,
-                       frozen: bool) -> Optional[np.ndarray]:
+    def _shared_encode(self, sub: ColumnBatch) -> Optional[np.ndarray]:
         """Shared-source fan-out: reuse the subtopo's one-per-batch key
         encode (subtopo.py SharedPrepCtx) instead of re-encoding per rule.
         The neutral table's slot ids are dense insertion-ordered, so
@@ -759,7 +714,7 @@ class FusedWindowAggNode(Node):
             new = np.array(nkt.keys_slice(self.kt.n_keys, n_keys),
                            dtype=np.object_)
             _, grew = self.kt.encode_column(new)
-            if grew and not frozen:
+            if grew:
                 self.state = self.gb.grow(self.state, self.kt.capacity)
         if self.kt.n_keys < n_keys:
             # truly diverged (sync could not reach the snapshot): self-
@@ -917,7 +872,7 @@ class FusedWindowAggNode(Node):
             return None
         return dcols, dvalid, dslots
 
-    def _build_kernel_inputs(self, sub: ColumnBatch, frozen: bool = False):
+    def _build_kernel_inputs(self, sub: ColumnBatch):
         """Encode group keys + materialize the kernel's numeric columns and
         validity masks for `sub`. Returns (cols, valid, slots)."""
         key_cols = []
@@ -927,11 +882,11 @@ class FusedWindowAggNode(Node):
                 col = np.full(sub.n, None, dtype=np.object_)
             key_cols.append(col)
         if key_cols:
-            slots = (self._shared_encode(sub, frozen)
+            slots = (self._shared_encode(sub)
                      if len(self.dims) == 1 else None)
             if slots is None:
                 slots, grew = self.kt.encode_multi(key_cols)
-                if grew and not frozen:
+                if grew:
                     self.state = self.gb.grow(self.state, self.kt.capacity)
         else:
             slots = np.zeros(sub.n, dtype=np.int32)
@@ -1029,51 +984,44 @@ class FusedWindowAggNode(Node):
         the jitted fold dispatch (which carries the implicit H2D copy when
         inputs weren't pre-uploaded) — together with the source's "decode"
         these expose the ingest-pipeline balance per node."""
-        frozen = self._device_frozen and bool(self._pipeline)
         with self.stats.stage("upload", sub.n):
-            cols, valid, slots = self._build_kernel_inputs(sub, frozen)
-            dev = None
-            if not frozen:
-                if self.gb.capacity < self.kt.capacity:
-                    # deferred grow (keys first seen in an earlier frozen
-                    # span)
-                    self.state = self.gb.grow(self.state, self.kt.capacity)
-                if self.tier is not None:
-                    # admission point: returning demoted keys (this batch's
-                    # new-key log) get their spilled partials merged back
-                    # into their fresh slots before the fold lands
-                    self.state = self.tier.admit(self.state)
-                dev = self._shared_device_inputs(sub, cols, valid, slots)
-        if not frozen:
-            with self.stats.stage("fold", sub.n):
-                if dev is not None:
-                    # shared uploads: device columns/slots computed once
-                    # serve every fan-out consumer; host copies still feed
-                    # the shadows
-                    dcols, dvalid, dslots = dev
-                    self.state = self.gb.fold(
-                        self.state, {**cols, **dcols},
-                        dslots if dslots is not None else slots,
-                        {**valid, **dvalid}, pane_arg, n_rows=sub.n)
-                else:
-                    self.state = self.gb.fold(self.state, cols, slots,
-                                              valid, pane_arg)
-            if hasattr(self.gb, "note_rows"):
-                # per-shard accounting (kuiper_shard_*): the kernel counts
-                # host slot vectors itself; the prep path hands it DEVICE
-                # slots, so count off the host copy here — and refresh the
-                # key-occupancy hint either way
-                if dev is not None and dev[2] is not None:
-                    self.gb.note_rows(slots, sub.n, n_keys=self.kt.n_keys)
-                else:
-                    self.gb.n_keys_hint = self.kt.n_keys
-        # every live shadow mirrors the fold (dedup: frozen-span retries and
-        # the backstop may share shadow objects)
-        seen = set()
+            cols, valid, slots = self._build_kernel_inputs(sub)
+            if self.gb.capacity < self.kt.capacity:
+                # the key table is wider than the state: a restored
+                # snapshot taken at a smaller capacity than this node was
+                # built with (restore_state keeps the table's own)
+                self.state = self.gb.grow(self.state, self.kt.capacity)
+            if self.tier is not None:
+                # admission point: returning demoted keys (this batch's
+                # new-key log) get their spilled partials merged back
+                # into their fresh slots before the fold lands
+                self.state = self.tier.admit(self.state)
+            dev = self._shared_device_inputs(sub, cols, valid, slots)
+        with self.stats.stage("fold", sub.n):
+            if dev is not None:
+                # shared uploads: device columns/slots computed once
+                # serve every fan-out consumer; host copies still feed
+                # the shadows
+                dcols, dvalid, dslots = dev
+                self.state = self.gb.fold(
+                    self.state, {**cols, **dcols},
+                    dslots if dslots is not None else slots,
+                    {**valid, **dvalid}, pane_arg, n_rows=sub.n)
+            else:
+                self.state = self.gb.fold(self.state, cols, slots,
+                                          valid, pane_arg)
+        if hasattr(self.gb, "note_rows"):
+            # per-shard accounting (kuiper_shard_*): the kernel counts
+            # host slot vectors itself; the prep path hands it DEVICE
+            # slots, so count off the host copy here — and refresh the
+            # key-occupancy hint either way
+            if dev is not None and dev[2] is not None:
+                self.gb.note_rows(slots, sub.n, n_keys=self.kt.n_keys)
+            else:
+                self.gb.n_keys_hint = self.kt.n_keys
+        # every un-merged pre-issue's shadow mirrors the fold
         for _, shadow in self._pipeline:
-            if id(shadow) not in seen:
-                seen.add(id(shadow))
-                shadow.fold(cols, slots, valid)
+            shadow.fold(cols, slots, valid)
         return sub.n
 
     # ------------------------------------------------------------ event time
@@ -1504,7 +1452,7 @@ class FusedWindowAggNode(Node):
         from ..ops.groupby import apply_int_semantics
 
         if kind == "pf":
-            return self._deliver_pf(*payload, n_keys, wr, t_issue)
+            return self._deliver_pf(*payload, n_keys, wr)
         if kind == "ring":
             # sliding DABA trigger: fetch the O(1) body combine, merge the
             # host edge shadow, final values in numpy — the same component
@@ -1513,10 +1461,8 @@ class FusedWindowAggNode(Node):
             outs, act = self._fetch_and_merge(pending, shadow, n_keys)
             self.last_emit_info = {
                 "source": "device-ring",
-                "fetch_ms": (pending.fetch_ms()
-                             if hasattr(pending, "fetch_ms") else
+                "fetch_ms": (pending.fetch_ms() if pending is not None else
                              (time.perf_counter() - t_issue) * 1000.0),
-                "ages_ms": [],
             }
             return self._emit_active(outs, act, wr)
         # heavy hitters: dispatch → landed and the host tail below are
@@ -1530,7 +1476,6 @@ class FusedWindowAggNode(Node):
         self.last_emit_info = {
             "source": "device-async",
             "fetch_ms": (time.perf_counter() - t_issue) * 1000.0,
-            "ages_ms": [],
         }
         if kind == "mr":
             self._deliver_mr(arr, n_keys, wr)
@@ -1552,9 +1497,12 @@ class FusedWindowAggNode(Node):
     def _fetch_and_merge(self, pending, shadow, n_keys: int):
         """Complete a pre-issued finalize on this thread: wait for its
         fetch to land (`fetch` sub-stage), then merge the tail shadow and
-        compute the final values (`merge`)."""
+        compute the final values (`merge`). `pending` is None where
+        nothing on the device belongs to the window (a sliding trigger
+        whose rows all sit in its host edge shadow)."""
         with self.stats.span("fetch"):
-            pending.get()
+            if pending is not None:
+                pending.get()
         with self.stats.span("merge"):
             return self.gb.prefinalize_merge(pending, shadow, n_keys)
 
@@ -2254,7 +2202,7 @@ class FusedWindowAggNode(Node):
         window-length pane merge. Exactness matches the refold path: the
         panes remain the ground truth and every off-discipline shape
         (delay, recycled panes, restores) takes an exact fallback."""
-        from ..ops.prefinalize import HostShadow, IdentityFinalize
+        from ..ops.prefinalize import HostShadow
 
         n_keys = self.kt.n_keys
         if n_keys == 0:
@@ -2280,8 +2228,6 @@ class FusedWindowAggNode(Node):
             else:
                 self._shadow_ring_rows(shadow, b_hi, hi_incl=hi)
         pending = self._ring_body_query(body, include_head, b_hi, shadow)
-        if pending is None:
-            pending = IdentityFinalize(self.gb.comp_specs, self.kt.capacity)
         self._emit_submit("ring", (pending, shadow), n_keys,
                           WindowRange(lo, hi))
 
@@ -2438,36 +2384,24 @@ class FusedWindowAggNode(Node):
         snapshot (jax immutability = free double buffer) and start shadowing
         tail rows on host. If an earlier pre-issue for this boundary has
         already landed, this refresh is unnecessary and skipped; if it's
-        still in flight (link jitter), stack a fresher one. See
-        ops/prefinalize.py."""
+        still in flight, stack a fresher one. See ops/prefinalize.py."""
         if not self._prefinalize_ok or self.kt.n_keys == 0:
             return
-        from ..ops.prefinalize import HostShadow, IdentityFinalize
+        from ..ops.prefinalize import HostShadow
 
-        real = [e for e in self._pipeline
-                if not isinstance(e[0], IdentityFinalize)]
-        # a landed REAL fetch serves the boundary — no refresh needed; the
-        # backstop identity never suppresses probes
-        if real and real[-1][0].ready():
+        # a landed fetch serves the boundary — no refresh needed
+        if self._pipeline and self._pipeline[-1][0].ready():
             return
         # at most 2 un-landed device fetches: each is a full components
-        # download occupying the (serialized, RTT-bound) device link —
-        # stacking more on a congested link compounds the backlog until
-        # fetches lag the stream by whole windows (r02 bench post-mortem)
-        if len(self._pipeline) >= 4 or len(real) >= 2:
+        # download, and stacking more behind a slow one compounds the
+        # backlog until fetches lag the stream by whole windows
+        if len(self._pipeline) >= 2:
             return
-        # device state unchanged since the first real pre-issue (frozen span
-        # rows are host-only): retry the fetch on the same snapshot, sharing
-        # that span's shadow
-        retry = bool(real) and self._device_frozen
         with self.stats.stage("emit"), self.stats.span("finalize"):
             self._pipeline.append((
                 self.gb.prefinalize_begin(self.state),
-                real[0][1] if retry else HostShadow(
-                    self.plan, self.gb.comp_specs, self.kt.capacity),
+                HostShadow(self.plan, self.gb.comp_specs, self.kt.capacity),
             ))
-        if not retry:
-            self._device_frozen = self._tail_host_only
 
     def on_trigger(self, trig: Trigger) -> None:
         # the boundary begins with this dispatch; how late it is against
@@ -2505,35 +2439,7 @@ class FusedWindowAggNode(Node):
             self.cur_pane = (self.cur_pane + 1) % self.n_panes
             self._reset_pane_tiered(self.cur_pane)
         self._tier_boundary()
-        self.begin_window_backstop()
         self._schedule_next_tick()
-
-    def begin_window_backstop(self) -> None:
-        """Open the next window with an always-ready identity entry plus a
-        window-spanning host shadow, so its boundary can never block on the
-        device link. Active for every window when the backstop is enabled;
-        otherwise only after a boundary whose fetches all missed (storm).
-        Real pre-issues still run and are preferred when they land."""
-        if not (self._backstop_ok and self.kt.n_keys):
-            return
-        if not self._backstop:
-            # prefinalize_backstop=False means strictly synchronous
-            # boundaries: the caller chose to WAIT on the device fetch
-            # (throughput benches, strict device-served accounting) — a
-            # storm must not silently re-arm host-shadow serving
-            return
-        from ..ops.prefinalize import HostShadow, IdentityFinalize
-
-        if self._identity is None or self._identity.capacity != self.kt.capacity:
-            # immutable (merge never writes into it) -> safe to reuse; wide
-            # sketch components make a fresh one per boundary real churn
-            self._identity = IdentityFinalize(self.gb.comp_specs,
-                                              self.kt.capacity)
-        self._pipeline = [(
-            self._identity,
-            HostShadow(self.plan, self.gb.comp_specs, self.kt.capacity),
-        )]
-        self._device_frozen = False
 
     def on_eof(self, eof: EOF) -> None:
         if self.is_event_time and self.wt == ast.WindowType.SESSION_WINDOW:
@@ -2579,13 +2485,12 @@ class FusedWindowAggNode(Node):
     def _boundary_emit(self, wr: WindowRange) -> None:
         """Window-boundary emission that never blocks the fold stream.
 
-        If some pre-issue is ready (a landed device fetch, or the tumbling
-        host backstop), emit synchronously — the fast path, identical to
-        before. Otherwise the merge would WAIT on an un-landed fetch (a
-        wide sketch finalize is tens of MB; on a slow link that stalls
-        ingest for seconds — the reference's window trigger emits inline
-        and has the same stall, window_op.go:235), so hand the wait to the
-        emit worker and keep folding: the pre-issue snapshot is immutable,
+        If some pre-issue is ready (a landed device fetch), emit
+        synchronously — the fast path. Otherwise the merge would WAIT on
+        an un-landed fetch (a wide sketch finalize is tens of MB — the
+        reference's window trigger emits inline and has the same stall,
+        window_op.go:235), so hand the wait to the emit worker and keep
+        folding: the pre-issue snapshot is immutable,
         and the boundary's pane reset cannot disturb it. A worker backlog
         also defers, so windows always deliver in order."""
         if not self._emit_late_async:
@@ -2597,7 +2502,6 @@ class FusedWindowAggNode(Node):
             return self._emit(wr)
         n_keys = self.kt.n_keys
         pipeline, self._pipeline = self._pipeline, []
-        frozen, self._device_frozen = self._device_frozen, False
         if pipeline:
             with self.stats.stage("emit"):
                 # backup finalize dispatched NOW, before on_trigger's
@@ -2608,7 +2512,7 @@ class FusedWindowAggNode(Node):
                 with self.stats.span("finalize"):
                     backup = self.gb._finalize(
                         self.state, (True,) * self.gb.n_panes)
-                self._emit_submit("pf", (pipeline, frozen, backup), n_keys,
+                self._emit_submit("pf", (pipeline, backup), n_keys,
                                   wr, self._keys_snapshot())
         else:
             # no pre-issue in flight: dispatch the finalize on the
@@ -2617,8 +2521,8 @@ class FusedWindowAggNode(Node):
                 "count", lambda: self.gb._finalize(
                     self.state, (True,) * self.gb.n_panes), wr)
 
-    def _deliver_pf(self, pipeline, frozen, backup, n_keys: int,
-                    wr: WindowRange, t_issue: float) -> int:
+    def _deliver_pf(self, pipeline, backup, n_keys: int,
+                    wr: WindowRange) -> int:
         """Emit-worker delivery of a deferred boundary: wait for the best
         pre-issue to land, merge, emit. Runs off the fold thread; touches
         only the immutable pre-issue snapshots and the closed window's
@@ -2626,12 +2530,10 @@ class FusedWindowAggNode(Node):
         on the pre-reset snapshot — the recovery path when the merge
         fails, mirroring the sync path's finalize fallback."""
         from ..ops.groupby import apply_int_semantics
-        from ..ops.prefinalize import IdentityFinalize
 
-        real = [e for e in pipeline if not isinstance(e[0], IdentityFinalize)]
         chosen = next(
-            ((p, s) for p, s in reversed(real) if p.ready()), None,
-        ) or (real[0] if real else pipeline[0])
+            ((p, s) for p, s in reversed(pipeline) if p.ready()),
+            pipeline[0])
         try:
             outs, act = self._fetch_and_merge(chosen[0], chosen[1], n_keys)
         except Exception as exc:
@@ -2655,10 +2557,7 @@ class FusedWindowAggNode(Node):
                 return 0
         self.last_emit_info = {
             "source": "device-async-late",
-            "fetch_ms": (chosen[0].fetch_ms()
-                         if hasattr(chosen[0], "fetch_ms")
-                         else (time.perf_counter() - t_issue) * 1000.0),
-            "ages_ms": [],
+            "fetch_ms": chosen[0].fetch_ms(),
         }
         return self._emit_active(outs, act, wr)
 
@@ -2672,18 +2571,16 @@ class FusedWindowAggNode(Node):
         """Synchronous emission on the calling (fold) thread: the `emit`
         stage with its `finalize`/`fetch`/`merge` sub-stages."""
         pipeline, self._pipeline = self._pipeline, []
-        frozen, self._device_frozen = self._device_frozen, False
         n_keys = self.kt.n_keys
         if n_keys == 0:
             self.last_emit_info = None  # no stale record for empty windows
             return
         with self.stats.stage("emit") as st:
             if pipeline:
-                outs, act = self._merge_pipeline(pipeline, frozen, n_keys)
+                outs, act = self._merge_pipeline(pipeline, n_keys)
             else:
                 outs, act = self._finalize_sync(n_keys)
-                self.last_emit_info = {"source": "sync", "fetch_ms": 0.0,
-                                       "ages_ms": []}
+                self.last_emit_info = {"source": "sync", "fetch_ms": 0.0}
             st.rows = self._emit_active(outs, act, wr)
             if not st.rows:
                 self.last_emit_info = None  # nothing emitted this boundary
@@ -2693,49 +2590,24 @@ class FusedWindowAggNode(Node):
         with self.stats.span("finalize"):
             return self.gb.finalize(self.state, n_keys)
 
-    def _merge_pipeline(self, pipeline, frozen: bool, n_keys: int):
+    def _merge_pipeline(self, pipeline, n_keys: int):
         """Serve a boundary from its pre-issued finalizes."""
-        from ..ops.prefinalize import IdentityFinalize
-
-        # newest READY pre-issue wins (prefer real device fetches over
-        # the backstop identity); if nothing is ready, wait on the
+        # newest READY pre-issue wins; if nothing is ready, wait on the
         # oldest (its fetch was registered first, it completes first)
-        real = [e for e in pipeline
-                if not isinstance(e[0], IdentityFinalize)]
         chosen = next(
-            ((p, s) for p, s in reversed(real) if p.ready()), None,
-        ) or next(
             ((p, s) for p, s in reversed(pipeline) if p.ready()),
-            pipeline[0],
-        )
-        self._storm = self._backstop_ok and bool(real) and not any(
-            p.ready() for p, _ in real
-        )
-        # engine-clock ms, matching PendingFinalize.t_created — ages
-        # are deterministic under the mock clock
-        now = timex.now_ms()
-        self.last_emit_info = {
-            "source": ("backstop"
-                       if isinstance(chosen[0], IdentityFinalize)
-                       else "device"),
-            "fetch_ms": (chosen[0].fetch_ms()
-                         if hasattr(chosen[0], "fetch_ms") else 0.0),
-            "ages_ms": [float(now - p.t_created)
-                        for p, _ in real if hasattr(p, "t_created")],
-        }
+            pipeline[0])
+        source = "device"
         try:
             outs, act = self._fetch_and_merge(chosen[0], chosen[1], n_keys)
-            if hasattr(chosen[0], "fetch_ms"):
-                # the fetch may have been waited for; record the real
-                # issue→landed latency, not the -1 sentinel
-                self.last_emit_info["fetch_ms"] = chosen[0].fetch_ms()
         except Exception as exc:
             logger.warning("prefinalize merge failed, sync fallback: %s",
                            exc)
-            if frozen and real:
-                self._flush_shadow(real[0][1])
             outs, act = self._finalize_sync(n_keys)
-            self.last_emit_info["source"] = "sync"
+            source = "sync"
+        # read after the merge: the fetch may have been waited for
+        self.last_emit_info = {"source": source,
+                               "fetch_ms": chosen[0].fetch_ms()}
         return outs, act
 
     def _decode_hh(self, outs):
@@ -2828,34 +2700,12 @@ class FusedWindowAggNode(Node):
         # internal/xsql/collection.go:70, WindowTuples is one type.
         return (msgs, len(msgs)) if msgs else None
 
-    def _flush_shadow(self, shadow) -> None:
-        """Fold frozen-span (host-only) rows back into the device state
-        (tumbling only — hopping shadows duplicate device content)."""
-        if not self._tail_host_only or shadow is None or not shadow.n_rows:
-            return
-        if self.gb.capacity < shadow.capacity:
-            self.state = self.gb.grow(self.state, shadow.capacity)
-        self.state = self.gb.absorb(self.state, shadow.data, 0)
-
-    def _flush_tail(self) -> None:
-        """Make the device state complete before a checkpoint snapshot or
-        any sync finalize; drops the pre-issue pipeline. Only the frozen
-        span's shadow is device-missing (the backstop's window-spanning
-        shadow duplicates rows the device already folded)."""
-        from ..ops.prefinalize import IdentityFinalize
-
-        pipeline, self._pipeline = self._pipeline, []
-        frozen, self._device_frozen = self._device_frozen, False
-        if not (frozen and pipeline):
-            return
-        real = [e for e in pipeline if not isinstance(e[0], IdentityFinalize)]
-        if real:
-            self._flush_shadow(real[0][1])
-
     # ------------------------------------------------------------------ state
     def snapshot_state(self) -> Optional[dict]:
         self._drain_async_emits(must_complete=True)
-        self._flush_tail()
+        # the pre-issues' shadows are not part of a snapshot: drop them, so
+        # the open window's boundary finalizes the (complete) device state
+        self._pipeline = []
         host = self.gb.state_to_host(self.state)
         snap = {
             "keys": self.kt.decode_all(),

@@ -45,6 +45,9 @@ class RuleState:
         # utils/lockcheck.py caught on day one (clock orders first)
         self._worker_mu = threading.Lock()
         self._actions: "queue.Queue[str]" = queue.Queue()
+        # notified by every state change and at the end of every action
+        # (wait_state); a leaf lock: nothing is taken while it is held
+        self._settled = threading.Condition()
         self._worker: Optional[threading.Thread] = None
         self._supervisor: Optional[threading.Thread] = None
         self._stop_supervision = threading.Event()
@@ -70,6 +73,8 @@ class RuleState:
         callers hold self._lock or run on the serialized action worker."""
         prev = self.state
         self.state = st
+        with self._settled:
+            self._settled.notify_all()
         if prev is not st:
             from .events import recorder
 
@@ -90,6 +95,16 @@ class RuleState:
     def restart(self) -> None:
         self._enqueue("stop")
         self._enqueue("start")
+
+    def wait_state(self, st: RunState, timeout: float) -> bool:
+        """Block until the rule RESTS in `st`: the state is `st` and no
+        action is queued or running. A transition sets its state first and
+        does its work after (topo opened or closed, the schedule's next
+        timer armed), so the state alone does not say the work is done."""
+        with self._settled:
+            return self._settled.wait_for(
+                lambda: self.state is st
+                and not self._actions.unfinished_tasks, timeout)
 
     def _enqueue(self, action: str) -> None:
         self._actions.put(action)
@@ -124,6 +139,10 @@ class RuleState:
                 with self._lock:
                     self._set_state(RunState.STOPPED_BY_ERR, reason=str(exc))
                     self.last_error = str(exc)
+            finally:
+                self._actions.task_done()
+                with self._settled:
+                    self._settled.notify_all()
 
     # ------------------------------------------------------------- transitions
     def _do_start(self) -> None:

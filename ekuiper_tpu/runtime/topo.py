@@ -213,7 +213,7 @@ class Topo:
                 stats.setdefault(name, sm)
         out = flatten_status(stats)
         # which path answered each emitted window, cumulatively (fused
-        # window nodes): device fetch / host backstop / sync finalize
+        # window nodes): device fetch / sync finalize
         for n in self.all_nodes():
             srcs = getattr(n, "emit_sources", None)
             if srcs:

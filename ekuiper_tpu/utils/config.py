@@ -95,10 +95,6 @@ class RuleOptionConfig:
     # pre-issue the window finalize this long before the boundary so the
     # device round trip overlaps the stream (ops/prefinalize.py); 0 disables
     prefinalize_lead_ms: int = 250
-    # window-tail rows after a pre-issue: "device" folds them to both the
-    # device state and the merge shadow (state always complete); "host"
-    # freezes the device and shadows only (for saturated host→device links)
-    tail_mode: str = "device"
     # fused window results stay columnar (ColumnBatch) end-to-end; sinks
     # convert to per-message dicts at the edge
     emit_columnar: bool = True

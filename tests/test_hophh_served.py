@@ -146,7 +146,6 @@ def test_served_hopping_heavy_hitters_against_the_reference(
         fused = next(n for n in api.rules.state(rule_id).topo.ops
                      if type(n).__name__ == "FusedWindowAggNode")
         assert fused.n_panes == span and fused._async_hh
-        assert not fused._backstop  # the served node has no host backstop
         hops = seeded_hops(seed, 4)
         for hop in hops:
             _drive_hop(mock_clock, f"{rule_id}/in", hop, got)

@@ -168,6 +168,24 @@ class TestPrefinalizeParity:
         so, sa = gb.finalize(state, kt.n_keys)
         _assert_parity(mo, ma, so, sa)
 
+    def test_shadow_alone(self):
+        """No device fetch at all (`pending` None — a sliding trigger whose
+        rows all sit in its host edge shadow): the shadow is the window."""
+        plan = _plan("SELECT avg(temp), count(*), min(temp), max(temp) "
+                     "FROM s GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)")
+        rng = np.random.default_rng(12)
+        keys, raw = _batch(rng, 50, 5)
+        kt = KeyTable(32)
+        gb = DeviceGroupBy(plan, capacity=32, micro_batch=32)
+        slots, _ = kt.encode_column(keys)
+        cols = _cols_for(plan, raw, 50)
+        shadow = HostShadow(plan, gb.comp_specs, kt.capacity)
+        shadow.fold(cols, slots, None)
+        mo, ma = gb.prefinalize_merge(None, shadow, kt.n_keys)
+        state = gb.fold(gb.init_state(), cols, slots)
+        so, sa = gb.finalize(state, kt.n_keys)
+        _assert_parity(mo, ma, so, sa)
+
     def test_int_semantics(self):
         plan = _plan("SELECT sum(temp), avg(temp), count(*) FROM s "
                      "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)")
@@ -194,10 +212,11 @@ class TestPrefinalizeParity:
         _assert_parity(mo, ma, so, sa)
 
 
-class TestFrozenTailGrow:
-    def test_no_truncation_when_device_grow_deferred(self):
-        """Keys first seen during a frozen (host-only) tail grow the key
-        table but NOT the device state; merge must still emit them."""
+class TestTailGrow:
+    def test_no_truncation_when_key_table_grew_after_the_snapshot(self):
+        """A pending finalize older than a key-table growth: keys first
+        seen during the tail are wider than its snapshot, and the merge
+        must still emit every one of them."""
         plan = _plan("SELECT count(*) AS c, sum(temp) AS s FROM s "
                      "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)")
         kt = KeyTable(8)
@@ -238,13 +257,17 @@ class TestColumnarNulls:
         assert msgs == dict_msgs
 
 
-def _node_bits():
+WINDOWS = {"tumbling": "TUMBLINGWINDOW(ss, 10)",
+           "hopping": "HOPPINGWINDOW(ss, 10, 5)"}  # length / hop = 2
+
+
+def _node_bits(kind="tumbling"):
     from ekuiper_tpu.data.batch import ColumnBatch
     from ekuiper_tpu.ops.emit import build_direct_emit
     from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
 
     sql = ("SELECT deviceId, avg(temp) AS a, count(*) AS c FROM s "
-           "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)")
+           f"GROUP BY deviceId, {WINDOWS[kind]}")
     stmt = parse_select(sql)
     rng = np.random.default_rng(9)
 
@@ -256,15 +279,15 @@ def _node_bits():
                           "temp": rng.normal(20, 5, n).astype(np.float32)},
             timestamps=np.zeros(n, dtype=np.int64), emitter="s")
 
-    def mknode(prefinalize, tail_mode="device", backstop=True):
+    def mknode(prefinalize, capacity=64):
+        """The node as the planner builds it: its arguments, no other."""
         plan = extract_kernel_plan(stmt)
         node = FusedWindowAggNode(
             "t", stmt.window, plan,
-            dims=[d.expr for d in stmt.dimensions], capacity=64,
+            dims=[d.expr for d in stmt.dimensions], capacity=capacity,
             micro_batch=32,
             direct_emit=build_direct_emit(stmt, plan, ["deviceId"]),
             prefinalize_lead_ms=250 if prefinalize else 0,
-            tail_mode=tail_mode, prefinalize_backstop=backstop,
         )
         node.state = node.gb.init_state()
         got = []
@@ -275,55 +298,79 @@ def _node_bits():
 
 
 def _flat(items):
+    """{deviceId: (avg, count)} of one or more emits; the comparison below
+    holds the counts exact and the averages to float32 accumulation order."""
     out = []
     for item in items:
         out.extend(item if isinstance(item, list) else [item])
-    return {(m.message if hasattr(m, "message") else m)["deviceId"]:
-            (round((m.message if hasattr(m, "message") else m)["a"], 3),
-             (m.message if hasattr(m, "message") else m)["c"])
-            for m in out}
+    msgs = [m.message if hasattr(m, "message") else m for m in out]
+    return {m["deviceId"]: (pytest.approx(m["a"], rel=1e-5), m["c"])
+            for m in msgs}
+
+
+def _landed(node):
+    """Wait (real time) for the newest pre-issued fetch to land."""
+    import time
+
+    pending = node._pipeline[-1][0]
+    deadline = time.time() + 10
+    while not pending.ready() and time.time() < deadline:
+        time.sleep(0.005)
+    assert pending.ready()
 
 
 class TestNodePrefinalize:
-    @pytest.mark.parametrize("tail_mode", ["device", "host"])
-    def test_node_emits_via_pretrigger(self, tail_mode):
-        """Drive FusedWindowAggNode through PreTrigger→data→Trigger and
-        assert the merged emit matches a sync-emit node on the same data,
-        for both tail modes (device: tail rows fold to device AND shadow;
-        host: device frozen at pre-issue, tail rows shadow-only)."""
+    @pytest.mark.parametrize("kind", ["tumbling", "hopping"])
+    def test_node_emits_via_pretrigger(self, kind):
+        """Drive FusedWindowAggNode through PreTrigger→rows→Trigger over
+        three boundaries and assert every merged emit matches a sync-emit
+        node on the same data: tail rows fold to the device AND to the
+        pre-issue's shadow, so the next window (tumbling) and the next
+        hop's window (hopping: a row sits in two) count each row once."""
         from ekuiper_tpu.runtime.events import PreTrigger, Trigger
 
-        _, mkbatch, mknode = _node_bits()
-        batches = [mkbatch(40) for _ in range(4)]
+        _, mkbatch, mknode = _node_bits(kind)
+        batches = [mkbatch(40) for _ in range(6)]
+        step = 10_000 if kind == "tumbling" else 5_000
 
         def run(prefinalize):
-            node, got = mknode(prefinalize, tail_mode)
-            node.process(batches[0])
-            node.process(batches[1])
-            if prefinalize:
-                node.on_pre_trigger(PreTrigger(ts=10_000))
-                assert node._pipeline
-            node.process(batches[2])
-            node.process(batches[3])
-            node.on_trigger(Trigger(ts=10_000))
-            return got
+            node, got = mknode(prefinalize)
+            assert node.n_panes == (1 if kind == "tumbling" else 2)
+            for w in range(3):
+                end = step * (w + 1)
+                node.process(batches[2 * w])
+                if prefinalize:
+                    node.on_pre_trigger(PreTrigger(ts=end))
+                    assert len(node._pipeline) == 1
+                    if w != 1:  # boundary 2 finds its fetch in flight or not
+                        _landed(node)
+                node.process(batches[2 * w + 1])
+                node.on_trigger(Trigger(ts=end))
+                assert node._pipeline == []
+            # a boundary without a landed pre-issue defers to the emit
+            # worker (_emit_late_async) — drain before comparing
+            node._drain_async_emits()
+            return node, got
 
-        sync = run(False)
-        merged = run(True)
-        assert len(sync) == len(merged) > 0
-        assert _flat(sync) == _flat(merged)
+        _, sync = run(False)
+        node, merged = run(True)
+        assert len(sync) == len(merged) == 3
+        for a, b in zip(merged, sync):
+            assert _flat([a]) == _flat([b])
+        assert node.emit_sources.get("device", 0) >= 2
+        assert set(node.emit_sources) <= {"device", "device-async-late"}
 
-    def test_device_tail_mode_across_windows(self):
-        """Device tail mode: rows arriving after the pre-issue fold into
-        both device state and shadow; the boundary reset must leave the
-        NEXT window counting only its own rows (no loss, no double
-        count), across several consecutive windows."""
+    def test_tail_rows_across_windows(self):
+        """Rows arriving after the pre-issue fold into both device state
+        and shadow; the boundary reset must leave the NEXT window counting
+        only its own rows (no loss, no double count), across several
+        consecutive windows."""
         from ekuiper_tpu.runtime.events import PreTrigger, Trigger
 
         _, mkbatch, mknode = _node_bits()
         batches = [mkbatch(40) for _ in range(8)]
-        node, got = mknode(True, "device")
-        sync_node, sync_got = mknode(False, "device")
+        node, got = mknode(True)
+        sync_node, sync_got = mknode(False)
         for w in range(4):
             for i in range(2):
                 node.process(batches[2 * w + i])
@@ -345,51 +392,43 @@ class TestNodePrefinalize:
         """The cumulative twin of last_emit_info: one count per emitted
         window by the path that answered it, kept after the next boundary
         overwrites the per-boundary record, and shown in the rule status."""
-        import time
-
-        from ekuiper_tpu.ops.prefinalize import IdentityFinalize
         from ekuiper_tpu.runtime.events import PreTrigger, Trigger
         from ekuiper_tpu.runtime.topo import Topo
 
         _, mkbatch, mknode = _node_bits()
-        node, got = mknode(True, "device")
+        node, got = mknode(True)
         topo = Topo("r_emit")
         topo.add_op(node)
         assert node.emit_sources == {}
         # pre-issue at boundaries 1 and 3 only; boundary 2 finds nothing
-        # but the identity entry and is answered by the host backstop
+        # pre-issued and dispatches its finalize for the emit worker
         for w, pre_issue in enumerate([True, False, True]):
             end = 10_000 * (w + 1)
             node.process(mkbatch(40))
             if pre_issue:
+                node._drain_async_emits()  # no backlog ahead of a landed fetch
                 node.on_pre_trigger(PreTrigger(ts=end))
-                real = [p for p, _ in node._pipeline
-                        if not isinstance(p, IdentityFinalize)]
-                deadline = time.time() + 10
-                while not real[0].ready() and time.time() < deadline:
-                    time.sleep(0.005)
-                assert real[0].ready()
+                _landed(node)
             node.process(mkbatch(40))
             node.on_trigger(Trigger(ts=end))
         node._drain_async_emits()
         assert len(got) == 3
-        assert node.emit_sources == {"device": 2, "backstop": 1}
-        assert node.last_emit_info["source"] == "device"  # the newest only
+        assert node.emit_sources == {"device": 2, "device-async": 1}
+        assert sum(node.emit_sources.values()) == len(got)
         assert topo.status()["op_t_0_emit_sources"] == {
-            "device": 2, "backstop": 1}
+            "device": 2, "device-async": 1}
 
-    def test_no_backstop_boundary_waits_for_the_device(self):
-        """prefinalize_backstop=False (what the planner builds): a boundary
-        whose fetch has not landed is delivered by the emit worker from the
-        device snapshot, never from the host shadow alone, and the windows
-        equal a sync node's."""
+    def test_boundary_waits_for_the_device(self):
+        """A boundary whose fetch has not landed is delivered by the emit
+        worker from the device snapshot, never from the host shadow alone,
+        and the windows equal a sync node's."""
         from ekuiper_tpu.ops.prefinalize import PendingFinalize
         from ekuiper_tpu.runtime.events import PreTrigger, Trigger
 
         _, mkbatch, mknode = _node_bits()
         batches = [mkbatch(40) for _ in range(6)]
-        node, got = mknode(True, "device", backstop=False)
-        sync_node, sync_got = mknode(False, "device")
+        node, got = mknode(True)
+        sync_node, sync_got = mknode(False)
 
         class LandsLate(PendingFinalize):
             def ready(self):
@@ -409,7 +448,7 @@ class TestNodePrefinalize:
             for n in (node, sync_node):
                 n.process(batches[2 * w + 1])
                 n.on_trigger(Trigger(ts=end))
-            assert len(node._pipeline) == 0  # no identity entry re-armed
+            assert len(node._pipeline) == 0  # the next window opens empty
         node._drain_async_emits()
         sync_node._drain_async_emits()
         assert node.emit_sources == {"device-async-late": 2,
@@ -418,39 +457,14 @@ class TestNodePrefinalize:
         for a, b in zip(got, sync_got):
             assert _flat([a]) == _flat([b])
 
-    def test_planner_builds_the_node_without_backstop(self):
-        """Through the normal entry point a tumbling boundary is answered
-        by the device: the planner passes prefinalize_backstop=False."""
-        from ekuiper_tpu.planner.planner import RuleDef, plan_rule
-        from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
-        from ekuiper_tpu.server.processors import StreamProcessor
-        from ekuiper_tpu.store import kv
-        from ekuiper_tpu.utils.infra import PlanError
-
-        store = kv.get_store()
-        try:
-            StreamProcessor(store).exec_stmt(
-                'CREATE STREAM pf_s (deviceId STRING, temp FLOAT) WITH '
-                '(DATASOURCE="pf/in", TYPE="memory", FORMAT="JSON")')
-        except PlanError:
-            pass
-        rule = RuleDef(
-            id="pf_r", sql="SELECT deviceId, count(*) AS c FROM pf_s "
-            "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)",
-            actions=[{"nop": {}}], options={"sharedFold": False})
-        topo = plan_rule(rule, store)
-        fused = next(n for n in topo.ops
-                     if isinstance(n, FusedWindowAggNode))
-        assert fused._backstop_ok and not fused._backstop
-
     def test_inflight_fetch_cap(self):
         """No more than two un-landed device fetches may stack: each is a
-        full components download on a serialized link (r02 post-mortem)."""
-        from ekuiper_tpu.ops.prefinalize import IdentityFinalize, PendingFinalize
+        full components download (r02 post-mortem)."""
+        from ekuiper_tpu.ops.prefinalize import PendingFinalize
         from ekuiper_tpu.runtime.events import PreTrigger
 
         _, mkbatch, mknode = _node_bits()
-        node, _ = mknode(True, "device")
+        node, _ = mknode(True)
         node.process(mkbatch(40))
 
         class NeverReady(PendingFinalize):
@@ -463,9 +477,226 @@ class TestNodePrefinalize:
             node.gb._components_layout())
         for _ in range(5):
             node.on_pre_trigger(PreTrigger(ts=10_000))
-        real = [e for e in node._pipeline
-                if not isinstance(e[0], IdentityFinalize)]
-        assert len(real) == 2
+        assert len(node._pipeline) == 2
+
+    @pytest.mark.parametrize("kind", ["tumbling", "hopping"])
+    def test_snapshot_between_pretrigger_and_trigger(self, kind):
+        """A snapshot taken after the pre-issue and before the boundary,
+        restored into a fresh node, closes the window the uninterrupted
+        node closes: the device state alone is complete (tail rows fold to
+        it too), and the pre-issues are dropped, not saved."""
+        import json
+
+        from ekuiper_tpu.runtime.events import PreTrigger, Trigger
+
+        _, mkbatch, mknode = _node_bits(kind)
+        batches = [mkbatch(40) for _ in range(5)]
+        step = 10_000 if kind == "tumbling" else 5_000
+        node, got = mknode(True)
+        node.process(batches[0])
+        node.on_trigger(Trigger(ts=step))  # hopping: an older pane is live
+        node.process(batches[1])
+        node.on_pre_trigger(PreTrigger(ts=2 * step))
+        node.process(batches[2])  # the tail: device state and shadow
+        assert node._pipeline and node._pipeline[0][1].n_rows == 40
+        snap = json.loads(json.dumps(node.snapshot_state()))
+        assert node._pipeline == []
+        fresh, fresh_got = mknode(True)
+        fresh.restore_state(snap)
+        for n in (node, fresh):
+            n.process(batches[3])
+            n.on_trigger(Trigger(ts=2 * step))
+            n.process(batches[4])
+            n.on_trigger(Trigger(ts=3 * step))
+            n._drain_async_emits()
+        assert len(got) == 3 and len(fresh_got) == 2
+        for a, b in zip(got[1:], fresh_got):
+            assert _flat([a]) == _flat([b])
+        # and both equal a node that never pre-issued
+        sync_node, sync_got = mknode(False)
+        for i, b in enumerate(batches):
+            sync_node.process(b)
+            if i in (0, 3, 4):
+                sync_node.on_trigger(Trigger(ts=step * (1 + (i + 1) // 2)))
+        sync_node._drain_async_emits()
+        assert [_flat([x]) for x in sync_got] == [_flat([x]) for x in got]
+
+
+class TestRestoredCapacity:
+    def test_snapshot_of_a_smaller_capacity_grows_before_the_fold(self):
+        """restore_state keeps the key table at the node's own capacity and
+        takes the state at the snapshot's: the fold must widen the state
+        before rows of new keys land, or slots past it are lost."""
+        from ekuiper_tpu.runtime.events import Trigger
+
+        _, mkbatch, mknode = _node_bits()
+        small, _ = mknode(False, capacity=4)
+        small.process(mkbatch(8))
+        snap = small.snapshot_state()
+        node, got = mknode(False, capacity=64)
+        node.restore_state(snap)
+        assert node.gb.capacity < node.kt.capacity == 64
+        node.process(mkbatch(200))  # all five keys d0..d4
+        assert node.gb.capacity == 64
+        node.on_trigger(Trigger(ts=10_000))
+        node._drain_async_emits()
+        assert sum(c for _, c in _flat(got).values()) == 208
+
+
+class TestServedRule:
+    """The boundary as a rule created through the planner or over REST has
+    it: default options, the engine's own timers."""
+
+    SQL = ("SELECT deviceId, avg(temp) AS a, count(*) AS c FROM {s} "
+           "GROUP BY deviceId, TUMBLINGWINDOW(ss, 1)")
+
+    @staticmethod
+    def _stream(name):
+        from ekuiper_tpu.server.processors import StreamProcessor
+        from ekuiper_tpu.store import kv
+
+        store = kv.get_store()
+        StreamProcessor(store).exec_stmt(
+            f'CREATE STREAM {name} (deviceId STRING, temp FLOAT) WITH '
+            f'(DATASOURCE="{name}/in", TYPE="memory", FORMAT="JSON")')
+        return store
+
+    def test_half_of_a_one_second_window_reaches_the_shadow(
+            self, mock_clock):
+        """The two pre-triggers of `_schedule_next_tick` (2 x lead and 1 x
+        lead before the boundary; the second is skipped once the first has
+        landed) put the snapshot 500 ms before a 1 s window closes at the
+        default lead of 250 ms: every row after it is folded twice, on the
+        device and into the shadow. Rows spread evenly over the window:
+        one half of them reach the shadow (PERF.md, section 4)."""
+        import queue
+
+        from ekuiper_tpu.planner.planner import RuleDef, plan_rule
+        from ekuiper_tpu.runtime.events import PreTrigger, Trigger
+        from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
+
+        store = self._stream("pf_half")
+        topo = plan_rule(RuleDef(
+            id="pf_half_r", sql=self.SQL.format(s="pf_half"),
+            actions=[{"nop": {}}], options={"sharedFold": False}), store)
+        fused = next(n for n in topo.ops
+                     if isinstance(n, FusedWindowAggNode))
+        assert fused.prefinalize_lead_ms == 250 and fused._prefinalize_ok
+        _, mkbatch, _ = _node_bits()
+        got = []
+        fused.broadcast = got.append
+        fused.state = fused.gb.init_state()
+        seen, shadow_rows = [], []
+
+        def pump():
+            """The worker's loop, on this thread: what the timers queued."""
+            while True:
+                try:
+                    item = fused.inq.get_nowait()
+                except queue.Empty:
+                    return
+                seen.append(type(item).__name__)
+                if isinstance(item, Trigger):
+                    shadow_rows.append(
+                        [sh.n_rows for _, sh in fused._pipeline])
+                fused._dispatch(item)
+                fused.inq.task_done()
+                if isinstance(item, PreTrigger) and fused._pipeline:
+                    _landed(fused)
+
+        start = mock_clock.now_ms()
+        assert start % 1000 == 0
+        fused.on_open()  # arms the boundary and its two pre-triggers
+        try:
+            mock_clock.advance(50)
+            for _ in range(10):  # 100 rows every 100 ms, at 50, 150 .. 950
+                fused.process(mkbatch(100))
+                mock_clock.advance(100)
+                pump()
+            fused._drain_async_emits()
+        finally:
+            fused.on_close()
+        assert seen == ["PreTrigger", "PreTrigger", "Trigger"]
+        assert shadow_rows == [[500]]  # one pre-issue, the one at 2 x lead
+        assert len(got) == 1 and int(got[0].columns["c"].sum()) == 1000
+        assert fused.emit_sources == {"device": 1}
+        assert shadow_rows[0][0] / 1000 == 0.5
+
+    def test_rule_with_the_removed_tail_mode_option(self, mock_clock):
+        """`tailMode` is no option any more: a rule that still carries it
+        is accepted as any unknown option is, plans device-fused and emits
+        what the same rule without it emits."""
+        import json
+        import time
+
+        import ekuiper_tpu.io.memory as mem
+        from ekuiper_tpu.runtime.rule import RunState
+        from ekuiper_tpu.server.rest import RestApi
+
+        rng = np.random.default_rng(31)
+        rules = {"pf_tm": {"tailMode": "host"}, "pf_plain": {}}
+        sinks = {rid: [] for rid in rules}
+        for rid in rules:
+            store = self._stream(f"{rid}_s")
+            mem.subscribe(f"{rid}/out",
+                          lambda _t, payload, r=rid: sinks[r].append(payload))
+        api = RestApi(store)
+        try:
+            for rid, extra in rules.items():
+                code, _ = api.dispatch("POST", "/rules", {
+                    "id": rid, "sql": self.SQL.format(s=f"{rid}_s"),
+                    "options": {"sharedFold": False, **extra},
+                    "actions": [{"memory": {"topic": f"{rid}/out"}}]}, {})
+                assert code in (200, 201)
+            fused = {}
+            for rid in sinks:
+                rs = api.rules.state(rid)
+                assert rs.wait_state(RunState.RUNNING, 20) and rs.topo._open
+                code, explain = api.dispatch(
+                    "GET", f"/rules/{rid}/explain", None, {})
+                assert code == 200 and explain["path"] == "device-fused"
+                fused[rid] = next(
+                    n for n in api.rules.state(rid).topo.ops
+                    if type(n).__name__ == "FusedWindowAggNode")
+                assert fused[rid]._prefinalize_ok
+
+            def publish(n):
+                rows = [json.dumps({"deviceId": f"d{k}", "temp": float(t)})
+                        .encode() for k, t in zip(
+                            rng.integers(0, 7, n).tolist(),
+                            rng.normal(20, 5, n).round(2).tolist())]
+                for rid in sinks:
+                    mem.publish(f"{rid}_s/in", rows)
+
+            for w in range(2):  # two windows, rows before and after the
+                publish(300)    # pre-issue of each
+                mock_clock.advance(60)   # the source's linger flush
+                time.sleep(0.3)
+                mock_clock.advance(540)  # past the pre-trigger at 500
+                time.sleep(0.3)
+                publish(200)
+                mock_clock.advance(60)
+                time.sleep(0.3)
+                mock_clock.advance(340)  # the boundary
+                deadline = time.time() + 20
+                while time.time() < deadline and \
+                        any(len(v) <= w for v in sinks.values()):
+                    time.sleep(0.02)
+                time.sleep(0.3)
+            for f in fused.values():
+                f._drain_async_emits()
+
+            def windows(payloads):
+                return [_flat([p]) for p in payloads]
+
+            assert len(sinks["pf_tm"]) == len(sinks["pf_plain"]) == 2
+            assert windows(sinks["pf_tm"]) == windows(sinks["pf_plain"])
+            assert [sum(c for _, c in w.values())
+                    for w in windows(sinks["pf_tm"])] == [500, 500]
+            for f in fused.values():
+                assert set(f.emit_sources) <= {"device", "device-async-late"}
+        finally:
+            api.rules.stop_all()
 
 
 class TestKeyTableFastPath:
@@ -533,7 +764,7 @@ class TestEngineClockTelemetry:
 
         mock_clock.set(5_000_000)
         _, mkbatch, mknode = _node_bits()
-        node, _ = mknode(True, "device")
+        node, _ = mknode(True)
         node.process(mkbatch(40))
         p = node.gb.prefinalize_begin(node.state)
         assert isinstance(p, PendingFinalize)

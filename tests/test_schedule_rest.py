@@ -70,12 +70,10 @@ class TestScheduledRule:
             options=options), store)
 
     def _wait_state(self, rs, state, timeout=5.0):
-        deadline = time.time() + timeout
-        while time.time() < deadline:
-            if rs.state == state:
-                return True
-            time.sleep(0.02)
-        return False
+        """Until the rule rests in `state`, its transition's work done (the
+        next schedule timer armed): advancing the clock on the state alone
+        raced the timer and lost the fire."""
+        return rs.wait_state(state, timeout)
 
     def test_cron_cycle(self, mock_clock):
         store = kv.get_store()
@@ -117,9 +115,11 @@ class TestScheduledRule:
         })
         rs.start()
         assert self._wait_state(rs, RunState.SCHEDULED)
+        armed = rs._sched_timer
         mock_clock.advance(60_000)  # fires, but now (60s) is out of range
-        time.sleep(0.3)
-        assert rs.state == RunState.SCHEDULED and rs.topo is None
+        assert self._wait_state(rs, RunState.SCHEDULED)
+        assert rs._sched_timer is not armed  # fired, and armed the next
+        assert rs.topo is None
         rs.stop()
 
 

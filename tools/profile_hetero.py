@@ -24,7 +24,7 @@ def run(shared: bool, seconds: float = 8.0):
     if not shared:
         orig_enc = FusedWindowAggNode._shared_encode
         orig_dev = FusedWindowAggNode._shared_device_inputs
-        FusedWindowAggNode._shared_encode = lambda self, sub, frozen: None
+        FusedWindowAggNode._shared_encode = lambda self, sub: None
         FusedWindowAggNode._shared_device_inputs = \
             lambda self, sub, cols, valid, slots: None
     try:
