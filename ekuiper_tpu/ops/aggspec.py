@@ -211,6 +211,8 @@ class ValueDict:
         self._ids: Dict[Any, int] = {}
         self._values: List[Any] = []
         self._tables: Dict[type, _CodeTable] = {}
+        # the values and one None, for `decode_array`; grown when they have
+        self._decode_table = np.array([None], dtype=np.object_)
         self.overflowed = False
 
     def _code(self, v) -> float:
@@ -286,6 +288,22 @@ class ValueDict:
     def decode(self, code: int):
         return self._values[code] if 0 <= code < len(self._values) else None
 
+    def decode_array(self, codes: "np.ndarray") -> "np.ndarray":
+        """`decode` over an integer array at once: the values themselves in
+        an object array, None for a code outside the dictionary."""
+        table = self._decode_table
+        values = self._values
+        # read once: the fused worker appends while the emit worker decodes,
+        # and codes never change their value, so a table is stale only by
+        # being short
+        n = len(values)
+        if len(table) != n + 1:
+            known = len(table) - 1
+            table = np.concatenate([table[:known], np.fromiter(
+                values[known:n], dtype=np.object_, count=n - known), [None]])
+            self._decode_table = table
+        return table[np.where((codes >= 0) & (codes < n), codes, n)]
+
     def snapshot(self) -> List[Any]:
         return list(self._values)
 
@@ -293,6 +311,7 @@ class ValueDict:
         self._values = list(values)
         self._ids = {}
         self._tables = {}  # refilled by the batches that follow
+        self._decode_table = np.array([None], dtype=np.object_)
         for i, v in enumerate(self._values):
             try:
                 self._ids[v] = i

@@ -25,7 +25,7 @@ merges (parallel/) are elementwise add/min/max.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -691,26 +691,31 @@ class DeviceGroupBy:
 
     def hh_assemble(
         self, stacked: np.ndarray, n_keys: int,
+        items: Optional[Callable[[int, np.ndarray, list], list]] = None,
     ) -> Tuple[List[np.ndarray], np.ndarray]:
         """Host tail of the heavy-hitters finalize: dedupe candidates (a
-        code can appear once per depth) and trim to top-k; plain specs read
-        their final-value row. Shared by the sync finalize route and the
-        async emit worker."""
-        from .prefinalize import hh_dedupe_topk
+        code can appear once per depth) and trim to top-k, every key in one
+        pass of array operations (`hh_topk_block`); plain specs read their
+        final-value row. Shared by the sync finalize route and the async
+        emit worker. A heavy-hitters column holds per-key lists of
+        `(code, count)`, or of what `items(spec index, kept codes, kept
+        counts)` makes of all keys' kept candidates at once (the served
+        path: its `{"value", "count"}` dicts)."""
+        from .prefinalize import hh_split, hh_topk_block
 
         outs: List[np.ndarray] = []
         r = 0
-        for spec in self.plan.specs:
+        for i, spec in enumerate(self.plan.specs):
             if spec.kind == "heavy_hitters":
                 k2 = 2 * spec.topk
-                codes = stacked[r:r + k2, :n_keys]
-                est = stacked[r + k2:r + 2 * k2, :n_keys]
+                codes, counts, lens = hh_topk_block(
+                    stacked[r:r + k2, :n_keys],
+                    stacked[r + k2:r + 2 * k2, :n_keys], spec.topk)
                 r += 2 * k2
-                col = np.empty(n_keys, dtype=np.object_)
-                for j in range(n_keys):
-                    col[j] = hh_dedupe_topk(codes[:, j], est[:, j],
-                                            spec.topk)
-                outs.append(col)
+                counts = counts.tolist()
+                outs.append(hh_split(
+                    list(zip(codes.tolist(), counts)) if items is None
+                    else items(i, codes, counts), lens))
             else:
                 outs.append(stacked[r, :n_keys].copy())
                 r += 1

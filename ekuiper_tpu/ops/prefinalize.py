@@ -29,7 +29,7 @@ accumulation order.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -130,9 +130,10 @@ def hh_update_parts_np(codes: np.ndarray, mf: np.ndarray):
 
 def hh_dedupe_topk(codes_row, est_row, k: int):
     """Dedupe estimate-descending candidates (a code can appear once per
-    depth) and trim to top-k (code, count) pairs. Shared by the device
-    finalize route (groupby._host_finalize) and the numpy components route
-    (hh_topk_np) so both produce identical top lists."""
+    depth) and trim to top-k (code, count) pairs: one key's list, in
+    Python. The tail of the numpy components route (hh_topk_np) and the
+    reference the device finalize route's array form (hh_topk_block) is
+    held to, so both produce identical top lists."""
     seen = set()
     row = []
     for c, e in zip(codes_row, est_row):
@@ -146,6 +147,39 @@ def hh_dedupe_topk(codes_row, est_row, k: int):
         if len(row) >= k:
             break
     return row
+
+
+def hh_topk_block(codes: np.ndarray, est: np.ndarray, k: int
+                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """`hh_dedupe_topk` over a whole (k2, n_keys) candidate block — one key
+    a column, candidates down it as the device's `top_k` returned them — in
+    array operations, no Python per key. Returns the kept codes and counts
+    (int64, flat, key-major, a key's in candidate order) and the number
+    kept per key: what `hh_split` cuts into the per-key lists."""
+    with np.errstate(invalid="ignore"):  # a NaN is never among the kept
+        code = codes.astype(np.int64)
+        count = np.rint(est).astype(np.int64)  # half to even, as round()
+    # the scalar loop stops at the first estimate <= 0
+    keep = np.logical_and.accumulate(~(est <= 0), axis=0)
+    # ... skips a code an earlier candidate had (every earlier one is
+    # still alive where this one is), one comparison a distance between
+    # the two rather than one a pair. Right for the k2 = 2 * topk of a rule;
+    # hh_topk_np's D * W wide candidate list keeps the scalar tail for that
+    # reason
+    for d in range(1, len(code)):
+        keep[d:] &= code[d:] != code[:-d]
+    # ... and stops once it holds k
+    keep &= np.cumsum(keep, axis=0) <= k
+    kept = keep.T  # key-major: boolean indexing reads in C order
+    return code.T[kept], count.T[kept], keep.sum(axis=0)
+
+
+def hh_split(items: list, lens: np.ndarray) -> np.ndarray:
+    """Cut a flat key-major list into an object column of per-key lists,
+    `lens[j]` items for key j."""
+    ends = np.cumsum(lens).tolist()
+    return np.fromiter([items[a:b] for a, b in zip([0] + ends, ends)],
+                       dtype=np.object_, count=len(ends))
 
 
 def hh_topk_np(hh: np.ndarray, k: int) -> np.ndarray:

@@ -1483,8 +1483,7 @@ class FusedWindowAggNode(Node):
             return n_keys
         if kind == "hh":
             with self.stats.stage("hh_assemble", n_keys, within="emit"):
-                outs, act = self.gb.hh_assemble(arr, n_keys)
-                outs = self._decode_hh(outs)
+                outs, act = self.gb.hh_assemble(arr, n_keys, self._hh_items)
             return self._emit_active(outs, act, wr, hh_decoded=True)
         with self.stats.span("merge"):
             outs = [arr[i][:n_keys]
@@ -2610,21 +2609,31 @@ class FusedWindowAggNode(Node):
                                "fetch_ms": chosen[0].fetch_ms()}
         return outs, act
 
+    def _hh_items(self, i: int, codes: np.ndarray, counts: list) -> list:
+        """The emitted form of spec `i`'s kept heavy-hitters candidates, all
+        keys' at once: codes back to the original values in one array
+        lookup, one dict a candidate."""
+        vd = self._hh_dicts.get(self._hh_cols[i])
+        if vd is None:
+            return [{"value": None, "count": n} for n in counts]
+        return [{"value": v, "count": n}
+                for v, n in zip(vd.decode_array(codes).tolist(), counts)]
+
     def _decode_hh(self, outs):
         """Map heavy_hitters (code, count) pairs back to original values."""
+        from ..ops.prefinalize import hh_split
+
         if not self._hh_cols:
             return outs
         outs = list(outs)
-        for i, raw in self._hh_cols.items():
-            vd = self._hh_dicts.get(raw)
+        for i in self._hh_cols:
             col = outs[i]
-            dec = np.empty(len(col), dtype=np.object_)
-            dec[:] = [
-                [{"value": vd.decode(c) if vd else None, "count": n}
-                 for c, n in row]
-                for row in col
-            ]
-            outs[i] = dec
+            pairs = [p for row in col for p in row]
+            outs[i] = hh_split(
+                self._hh_items(
+                    i, np.array([c for c, _ in pairs], dtype=np.int64),
+                    [n for _, n in pairs]),
+                np.fromiter(map(len, col), dtype=np.int64, count=len(col)))
         return outs
 
     def _build_grouped(self, outs, active: np.ndarray, wr: WindowRange):

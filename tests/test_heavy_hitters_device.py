@@ -237,6 +237,202 @@ class TestPlannerGates:
 
 # ---- seeded streams for TestValueDict.test_encode_as_the_routine_stood:
 # name -> () -> (steps, code budget or None); a step is a column or "restore"
+# ---- the array assemble (PR 33) against the routine as it stood: the scalar
+# `hh_dedupe_topk` per key, then one `ValueDict.decode` per kept candidate
+N_VALUES = 40  # codes 0..39 decode; a block also holds codes that do not
+
+
+def _block_dup_pairs(k2, rng):
+    """One key per pair of positions (i < j) that hold the same code."""
+    cols = []
+    for j in range(1, k2):
+        for i in range(j):
+            codes = rng.permutation(N_VALUES)[:k2].astype(np.float32)
+            codes[j] = codes[i]
+            cols.append((codes, np.sort(rng.integers(1, 500, k2))[::-1]))
+    return cols
+
+
+def _block_stop_in_the_middle(k2, rng, at):
+    """An estimate `at` (0 or below) at every position, live ones after
+    it: nothing from there on may appear."""
+    cols = []
+    for pos in range(k2):
+        est = np.sort(rng.integers(1, 500, k2))[::-1].astype(np.float32)
+        est[pos] = at
+        cols.append((rng.permutation(N_VALUES)[:k2], est))
+    return cols
+
+
+def _block_few_uniques(k2, rng):
+    """Fewer than topk distinct codes, by duplicates and by a short list."""
+    cols = [(np.full(k2, 5.0), np.arange(k2, 0, -1))]  # one code k2 times
+    for n_live in range(1, max(k2 // 2, 2)):
+        est = np.zeros(k2)
+        est[:n_live] = np.arange(n_live, 0, -1) * 3
+        cols.append((rng.permutation(N_VALUES)[:k2], est))
+        cols.append((np.resize([3.0, 9.0], k2), est + 1))  # two codes
+    return cols
+
+
+def _block_all_zero(k2, rng):
+    return [(np.zeros(k2), np.zeros(k2)),
+            (rng.permutation(N_VALUES)[:k2], np.zeros(k2))]
+
+
+def _block_half_estimates(k2, rng):
+    """Estimates ending in .5: `round` and `rint` go to the even one."""
+    est = np.sort(rng.integers(0, 40, (4, k2)), axis=1)[:, ::-1] + 0.5
+    return [(rng.permutation(N_VALUES)[:k2], e) for e in est]
+
+
+def _block_below_one(k2, rng):
+    """Estimates between 0 and 1 are alive (only `<= 0` stops the list),
+    whatever count they round to."""
+    est = np.sort(rng.choice([0.125, 0.25, 0.5, 0.75, 1.0], (6, k2)),
+                  axis=1)[:, ::-1]
+    return [(rng.permutation(N_VALUES)[:k2], e) for e in est]
+
+
+def _block_outside_dictionary(k2, rng):
+    """Codes the dictionary lacks (-> None), a negative one among them."""
+    cols = []
+    for _ in range(6):
+        codes = rng.permutation(N_VALUES + 30)[:k2].astype(np.float32)
+        codes[rng.integers(0, k2)] = -1.0
+        codes[rng.integers(0, k2)] = float(HH_MAX_CODES - 1)
+        cols.append((codes, np.sort(rng.integers(1, 500, k2))[::-1]))
+    return cols
+
+
+def _block_random(k2, rng):
+    """Seeded candidates from a few codes, so duplicates, ties, zeros and
+    a stray negative all occur, in no particular place."""
+    n = 300
+    codes = rng.integers(0, 2 * k2, (n, k2))
+    est = np.sort(rng.integers(-1, 30, (n, k2)), axis=1)[:, ::-1] \
+        * rng.choice([1.0, 0.5, 0.25], (n, 1))
+    return list(zip(codes, est))
+
+
+_BLOCKS = {
+    "dup_pairs": _block_dup_pairs,
+    "zero_in_the_middle": lambda k2, rng: _block_stop_in_the_middle(
+        k2, rng, 0.0),
+    "negative_in_the_middle": lambda k2, rng: _block_stop_in_the_middle(
+        k2, rng, -2.0),
+    "few_uniques": _block_few_uniques,
+    "all_zero": _block_all_zero,
+    "half_estimates": _block_half_estimates,
+    "below_one": _block_below_one,
+    "outside_dictionary": _block_outside_dictionary,
+    "random": _block_random,
+    "no_keys": lambda k2, rng: [],
+}
+
+
+def _assemble_as_it_stood(stacked, n_keys, topk, vd):
+    """The sync form and the served form of the column, key by key."""
+    from ekuiper_tpu.ops.prefinalize import hh_dedupe_topk
+
+    k2 = 2 * topk
+    codes, est = stacked[:k2, :n_keys], stacked[k2:2 * k2, :n_keys]
+    sync = [hh_dedupe_topk(codes[:, j], est[:, j], topk)
+            for j in range(n_keys)]
+    served = [[{"value": vd.decode(c) if vd else None, "count": n}
+               for c, n in row] for row in sync]
+    return sync, served
+
+
+def _same(got, want):
+    """Equal, value for value and type for type; a decoded value is the
+    dictionary's own object."""
+    assert type(got) is type(want), (got, want)
+    if isinstance(want, (list, tuple)):
+        assert len(got) == len(want), (got, want)
+        for g, w in zip(got, want):
+            _same(g, w)
+    elif isinstance(want, dict):
+        assert list(got) == list(want) == ["value", "count"]
+        assert got["value"] is want["value"], (got, want)
+        _same(got["count"], want["count"])
+    else:
+        assert got == want, (got, want)
+
+
+@pytest.fixture(scope="module", params=[1, 3, 5])
+def topk_node(request):
+    topk = request.param
+    node, _ = make_node(
+        f"SELECT deviceId, heavy_hitters(code, {topk}) AS top, "
+        "count(*) AS c FROM s GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)")
+    return topk, node
+
+
+@pytest.mark.parametrize("with_dict", [True, False],
+                         ids=["dict", "no_dict"])
+@pytest.mark.parametrize("block", sorted(_BLOCKS))
+def test_hh_assemble_as_the_routine_stood(topk_node, block, with_dict):
+    topk, node = topk_node
+    k2 = 2 * topk
+    rng = np.random.default_rng([33, topk, sorted(_BLOCKS).index(block)])
+    cols = _BLOCKS[block](k2, rng)
+    n_keys, cap = len(cols), len(cols) + 7  # fewer keys than slots
+    # the device's layout: k2 rows of codes, k2 of estimates, c, act; the
+    # slots past n_keys hold candidates too, which must not be read
+    stacked = rng.integers(1, 9, (2 * k2 + 2, cap)).astype(np.float32)
+    for j, (codes, est) in enumerate(cols):
+        stacked[:k2, j] = codes
+        stacked[k2:2 * k2, j] = est
+    vd = None
+    node._hh_dicts.clear()
+    if with_dict:
+        vd = node._hh_dicts["code"] = ValueDict()
+        vd.encode(np.array([f"ev{i}" for i in range(N_VALUES)],
+                           dtype=np.object_))
+    sync, served = _assemble_as_it_stood(stacked, n_keys, topk, vd)
+    if cols and block != "all_zero":
+        assert any(sync), "the block exercises nothing"
+
+    outs, act = node.gb.hh_assemble(stacked, n_keys)
+    assert outs[0].dtype == np.object_ and outs[0].shape == (n_keys,)
+    _same(outs[0].tolist(), sync)
+    assert outs[1].dtype == np.int64  # apply_int_semantics still runs
+    assert (outs[1] == stacked[2 * k2, :n_keys]).all()
+    assert (act == stacked[-1, :n_keys]).all()
+    # the served form, built on the emit worker from the flat arrays ...
+    hot, _ = node.gb.hh_assemble(stacked, n_keys, node._hh_items)
+    assert hot[0].dtype == np.object_ and hot[0].shape == (n_keys,)
+    _same(hot[0].tolist(), served)
+    # ... and from the sync form, where a window was finalized in line
+    _same(node._decode_hh(outs)[0].tolist(), served)
+
+
+def test_decode_array_follows_the_dictionary():
+    """`decode_array` is `decode` over an array; its table is built again
+    when the dictionary has grown or was restored, not on every call."""
+    vd = ValueDict()
+    codes = np.array([-1, 0, 1, 2, 3, 10 ** 12], dtype=np.int64)
+
+    def held():
+        got = vd.decode_array(codes)
+        assert got.dtype == np.object_
+        for g, c in zip(got.tolist(), codes.tolist()):
+            assert g is vd.decode(c)
+        return got.tolist()
+
+    assert held() == [None] * 6  # an empty dictionary
+    vd.encode(np.array(["a", (1, 2), "c"], dtype=np.object_))
+    assert held() == [None, "a", (1, 2), "c", None, None]
+    table = vd._decode_table
+    assert held() and vd._decode_table is table  # nothing new: kept
+    vd.encode(np.array([2.5, 2.5]))
+    assert held() == [None, "a", (1, 2), "c", 2.5, None]
+    assert vd._decode_table is not table
+    vd.restore(["x", "y", "z", "w"])  # as long as before, other values
+    assert held() == [None, "x", "y", "z", "w", None]
+
+
 def _mixture(rng, n, dtype=np.int64):
     """The heavy-hitters cell's codes: 7 / 13 / 99 heavy, the rest uniform
     over 100..2099."""

@@ -166,6 +166,93 @@ def test_served_hopping_heavy_hitters_against_the_reference(
         api.rules.stop_all()
 
 
+# ------------------------------------ the boundary's host tail (PR 33)
+def test_served_window_makes_no_call_per_key_or_per_candidate(
+        mock_clock, monkeypatch):
+    """The hot path assembles a window's lists with array operations: the
+    scalar `hh_dedupe_topk` and `ValueDict.decode` are never called, and
+    the windows still hold what the reference says."""
+    from ekuiper_tpu.ops import prefinalize
+    from ekuiper_tpu.ops.aggspec import ValueDict
+
+    def scalar_call(*_a, **_k):
+        raise AssertionError("a per-key or per-candidate call on the "
+                             "served boundary")
+
+    monkeypatch.setattr(prefinalize, "hh_dedupe_topk", scalar_call)
+    monkeypatch.setattr(ValueDict, "decode", scalar_call)
+    api, got = _start_rule("hophh_flat", 2)
+    try:
+        hops = seeded_hops(33, 3)
+        for hop in hops:
+            _drive_hop(mock_clock, "hophh_flat/in", hop, got)
+        _drive_hop(mock_clock, "hophh_flat/in", None, got)
+        topo = api.rules.state("hophh_flat").topo
+        fused = next(n for n in topo.ops
+                     if type(n).__name__ == "FusedWindowAggNode")
+        fused._drain_async_emits()
+        held_to_reference(got, hops, 2)
+        status = topo.status()
+        assert not any(v for k, v in status.items()
+                       if k.endswith("_exceptions_total"))
+        sources = next(v for k, v in status.items()
+                       if k.endswith("_emit_sources"))
+        assert sources == {"device-async": len(got)}
+        for msgs in got:  # the emitted types: Python's own
+            for m in msgs:
+                assert type(m["top"]) is list
+                for pair in m["top"]:
+                    assert list(pair) == ["value", "count"]
+                    assert type(pair["value"]) is int
+                    assert type(pair["count"]) is int
+    finally:
+        api.rules.stop_all()
+
+
+def test_values_learnt_after_the_decode_table_was_built(mock_clock):
+    """The emit worker decodes from an array of the dictionary's values,
+    built again only when the dictionary has grown: two hops of known
+    values decode from the same array, and values the fused worker learns
+    afterwards are decoded at the next hop."""
+    api, got = _start_rule("hophh_grow", 2)
+    try:
+        rng = np.random.default_rng(33)
+        known = np.array([7, 13, 99])
+
+        def hop(values):
+            return (rng.integers(0, N_KEYS, HOP_ROWS),
+                    rng.choice(values, HOP_ROWS))
+
+        def tops(msgs):
+            return {p["value"] for m in msgs for p in m["top"]}
+
+        topo = api.rules.state("hophh_grow").topo
+        fused = next(n for n in topo.ops
+                     if type(n).__name__ == "FusedWindowAggNode")
+        _drive_hop(mock_clock, "hophh_grow/in", hop(known), got)
+        fused._drain_async_emits()
+        vd = fused._hh_dicts["code"]
+        table = vd._decode_table
+        assert len(table) == 4 and tops(got[0]) == {7, 13, 99}
+        _drive_hop(mock_clock, "hophh_grow/in", hop(known), got)
+        _drive_hop(mock_clock, "hophh_grow/in", hop(known), got)
+        fused._drain_async_emits()
+        assert vd._decode_table is table  # known values: not rebuilt
+        assert tops(got[1]) == tops(got[2]) == {7, 13, 99}
+        late = np.array([5000, 6000, 7000])
+        for _ in range(2):  # both panes of a window hold the late values
+            _drive_hop(mock_clock, "hophh_grow/in", hop(late), got)
+        fused._drain_async_emits()
+        assert vd._decode_table is not table
+        assert len(vd._decode_table) == 7
+        assert tops(got[3]) <= {7, 13, 99, 5000, 6000, 7000}
+        assert tops(got[4]) == {5000, 6000, 7000}
+        assert not any(v for k, v in topo.status().items()
+                       if k.endswith("_exceptions_total"))
+    finally:
+        api.rules.stop_all()
+
+
 # --------------------------------------------- what the boundary is seen by
 @pytest.fixture
 def fresh_tracer():
