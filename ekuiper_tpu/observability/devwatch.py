@@ -27,6 +27,7 @@ takes the record lock).
 """
 from __future__ import annotations
 
+import collections
 import threading
 import time as _time
 from typing import Any, Callable, Dict, List, Optional, Tuple
@@ -244,6 +245,9 @@ class _Registry:
         self._lock = threading.Lock()
         self._watches: List = []  # weakref.ref[OpWatch]
         self._retired: Dict[Tuple[str, str], Dict[str, int]] = {}
+        # counts of watches collected since the rollup was last read:
+        # (key, calls, compiles, storms), queued by retire_dead
+        self._dying: collections.deque = collections.deque()
 
     def register(self, op: str, rule: Optional[str],
                  kind: str = "hot") -> OpWatch:
@@ -256,25 +260,34 @@ class _Registry:
         return w
 
     def retire_dead(self, w: OpWatch) -> None:
-        """Fold a dying watch's counts into the retired rollup (called
+        """Queue a dying watch's counts for the retired rollup (called
         from OpWatch.__del__; w is mid-collection — touch plain counters
-        only, never its histogram/lock machinery)."""
+        only, never its histogram/lock machinery). The collector may run
+        a __del__ inside ANY allocation, also one this thread makes while
+        it holds `_lock` (the prune in register, a rollup's copy): so no
+        lock is taken here — deque.append is atomic — and `_fold_dying`
+        adds the counts under the lock before the rollup is read."""
         if w.calls == 0 and w.traces == 0:
             return  # never used: leave no zero-valued metric rows behind
-        key = (w.op, w.rule or "")
         kern = getattr(w, "kern", None)
         if kern is not None:
             from . import kernwatch
 
             kernwatch.retire(w.op, w.rule or "", kern)
-        with self._lock:
+        self._dying.append(((w.op, w.rule or ""), w.calls, w.traces,
+                            w.storms))
+
+    def _fold_dying(self) -> None:
+        """Queued counts into the rollup; the caller holds `_lock`."""
+        while self._dying:
+            key, calls, compiles, storms = self._dying.popleft()
             acc = self._retired.setdefault(
                 key, {"calls": 0, "compiles": 0, "storms": 0})
-            acc["calls"] += w.calls
-            acc["compiles"] += w.traces
-            acc["storms"] += w.storms
-            while len(self._retired) > RETIRED_CAP:
-                del self._retired[next(iter(self._retired))]
+            acc["calls"] += calls
+            acc["compiles"] += compiles
+            acc["storms"] += storms
+        while len(self._retired) > RETIRED_CAP:
+            del self._retired[next(iter(self._retired))]
 
     # -------------------------------------------------------------- queries
     def watches(self) -> List[OpWatch]:
@@ -287,6 +300,7 @@ class _Registry:
         include retired instances; the compile histogram merges live ones."""
         watches = self.watches()
         with self._lock:
+            self._fold_dying()
             out: Dict[Tuple[str, str], Dict[str, Any]] = {
                 k: {**v, "hist": None, "signatures": 0}
                 for k, v in self._retired.items()}
@@ -328,6 +342,7 @@ class _Registry:
         calls = compiles = storms = 0
         watches = self.watches()
         with self._lock:
+            self._fold_dying()
             for v in self._retired.values():
                 calls += v["calls"]
                 compiles += v["compiles"]
@@ -343,6 +358,7 @@ class _Registry:
         """Test hook."""
         with self._lock:
             self._watches.clear()
+            self._dying.clear()
             self._retired.clear()
 
 

@@ -42,6 +42,7 @@ the bench artifact's per-kernel summaries (`docs/OBSERVABILITY.md`
 """
 from __future__ import annotations
 
+import collections
 import os
 import threading
 from typing import Any, Dict, List, Optional, Tuple
@@ -318,24 +319,35 @@ _lock = threading.Lock()
 #: owner is collected — exported counters stay monotonic across restarts
 _retired: Dict[Tuple[str, str], Dict[str, float]] = {}
 RETIRED_CAP = 4096
+#: counters of records collected since the rollup was last read, queued
+#: by retire: ((op, rule), samples, device_us, dispatch_us, transfer_us)
+_dying: collections.deque = collections.deque()
 
 
 def retire(op: str, rule: str, kern: KernelRecord) -> None:
-    """Fold a dying record's counters into the rollup (called from
-    devwatch._Registry.retire_dead; kern is mid-collection — plain
-    counter reads only)."""
+    """Queue a dying record's counters for the rollup (called from
+    devwatch._Registry.retire_dead, under a __del__ that the collector
+    may run inside an allocation made while `_lock` is held: no lock
+    here; kern is mid-collection — plain counter reads only)."""
     if kern.samples == 0:
         return
-    with _lock:
-        acc = _retired.setdefault((op, rule), {
+    _dying.append(((op, rule), kern.samples, kern.device_us,
+                   kern.dispatch_us, kern.transfer_us))
+
+
+def _fold_dying() -> None:
+    """Queued counters into the rollup; the caller holds `_lock`."""
+    while _dying:
+        key, samples, device_us, dispatch_us, transfer_us = _dying.popleft()
+        acc = _retired.setdefault(key, {
             "samples": 0, "device_us": 0.0, "dispatch_us": 0.0,
             "transfer_us": 0.0})
-        acc["samples"] += kern.samples
-        acc["device_us"] += kern.device_us
-        acc["dispatch_us"] += kern.dispatch_us
-        acc["transfer_us"] += kern.transfer_us
-        while len(_retired) > RETIRED_CAP:
-            del _retired[next(iter(_retired))]
+        acc["samples"] += samples
+        acc["device_us"] += device_us
+        acc["dispatch_us"] += dispatch_us
+        acc["transfer_us"] += transfer_us
+    while len(_retired) > RETIRED_CAP:
+        del _retired[next(iter(_retired))]
 
 
 def _live() -> List[Tuple[str, str, KernelRecord]]:
@@ -368,6 +380,7 @@ def aggregate() -> Dict[Tuple[str, str], Dict[str, Any]]:
     include retired instances; gauges (cost, utilization) ride the live
     records."""
     with _lock:
+        _fold_dying()
         out: Dict[Tuple[str, str], Dict[str, Any]] = {
             k: dict(v) for k, v in _retired.items()}
     for op, rule, kern in _live():
@@ -394,6 +407,7 @@ def rule_ops_all() -> Dict[str, Dict[str, Dict[str, Any]]]:
     axis (a per-rule scan would make the tick O(rules x watches))."""
     out: Dict[str, Dict[str, Dict[str, Any]]] = {}
     with _lock:
+        _fold_dying()
         for (op, rule), v in _retired.items():
             out.setdefault(rule, {})[op] = {
                 "samples": v["samples"], "device_us": v["device_us"],
@@ -518,6 +532,7 @@ def reset() -> None:
     """Test hook: drop retired rollups, restore default cadences, and
     un-cache the device spec (tests monkeypatch it)."""
     with _lock:
+        _dying.clear()
         _retired.clear()
     # in place: set_sampling and callers hold the dict itself
     DEFAULT_SAMPLING.update(_default_sampling())

@@ -215,6 +215,20 @@ def render(rule_registry) -> str:
         _family(out, mname, mtype, help_txt)
         for st in fold_stores:
             out.append(f'{mname}{{op="{_esc(st.name)}"}} {value(st)}')
+    # sliding windows on the DABA ring (runtime/nodes_fused.py): triggers
+    # by the path that served the window body — a rule that falls to the
+    # dyn path every trigger pays a window-length pane merge each time
+    _family(out, "kuiper_sliding_triggers_total", "counter",
+            "sliding-window triggers answered, by the path that served the "
+            "window body: fast (one combine of the ring's running "
+            "partials), flip (partials rebuilt from the panes first), dyn "
+            "(traced-mask pane merge, the exact fallback), edge (every row "
+            "in the host edge shadow)")
+    for rule_id, node in rows:
+        for path, n in sorted(getattr(node, "sliding_triggers", {}).items()):
+            out.append(
+                f"kuiper_sliding_triggers_total{{{op_labels(rule_id, node)},"
+                f'path="{_esc(path)}"}} {n}')
     # the SLO headline: per-rule ingest→emit latency as a real Prometheus
     # histogram (_bucket/_sum/_count with le labels) — histogram_quantile()
     # over it answers "is p99 emit under 50ms" directly

@@ -643,15 +643,10 @@ class DeviceGroupBy:
         it."""
         from .prefinalize import merge_components
 
-        # the key table may have grown since the snapshot was taken (new
-        # keys live only in the shadow) — merge at the widest extent so no
-        # slot is truncated
-        cap = max(self.capacity,
-                  shadow.capacity if shadow is not None else 0)
         if pending is None:
-            comb = merge_components(shadow.data, None, cap)
+            comb = merge_components(shadow.data, None, n_keys)
         else:
-            comb = merge_components(pending.get(), shadow, cap)
+            comb = merge_components(pending.get(), shadow, n_keys)
         return self._final_from_components(comb, n_keys)
 
     def _hh_finalize_impl(self, state, pane_mask):

@@ -223,12 +223,38 @@ def hh_topk_np(hh: np.ndarray, k: int) -> np.ndarray:
     return out
 
 
+#: bins a block of the two-level quantile search (HIST_BINS = 32 blocks)
+_HIST_BLOCK = 32
+
+
+def _hist_first_reaching(hist: np.ndarray, frac: float):
+    """Per key the row total and the first bin whose cumulative count
+    reaches frac x total — `argmax(cumsum(hist) >= frac * total)` without
+    the cumulative sum over every bin of every key (17M sequential adds at
+    16,384 keys x 1,024 bins): block sums first (the totals come from
+    them), the cumulative sum over the blocks, then over the bins of the
+    one block that reaches the target. Bin counts are whole numbers below
+    2^24 carried in float32, so every partial sum is exact in either order
+    and both forms name the same bin; they are non-negative, so the first
+    block whose running sum reaches the target holds that bin."""
+    bins = hist.shape[-1]
+    per_block = np.add.reduceat(
+        hist, np.arange(0, bins, _HIST_BLOCK), axis=-1)
+    upto = np.cumsum(per_block, axis=-1)
+    total = upto[..., -1]
+    target = np.maximum(frac * total[..., None], 1e-9)
+    at = np.argmax(upto >= target, axis=-1)[..., None]
+    before = (np.take_along_axis(upto, at, axis=-1)
+              - np.take_along_axis(per_block, at, axis=-1))
+    blocks = hist.reshape(hist.shape[:-1] + (bins // _HIST_BLOCK,
+                                             _HIST_BLOCK))
+    inner = np.take_along_axis(blocks, at[..., None], axis=-2)[..., 0, :]
+    within = np.argmax(np.cumsum(inner, axis=-1) + before >= target, axis=-1)
+    return total, at[..., 0] * _HIST_BLOCK + within
+
+
 def hist_quantile_np(hist: np.ndarray, frac: float) -> np.ndarray:
-    total = np.sum(hist, axis=-1)
-    cum = np.cumsum(hist, axis=-1)
-    target = frac * total[..., None]
-    ge = cum >= np.maximum(target, 1e-9)
-    idx = np.argmax(ge, axis=-1)
+    total, idx = _hist_first_reaching(hist, frac)
     mag_idx = np.where(
         idx > _HIST_HALF, idx - _HIST_HALF - 1, _HIST_HALF - 1 - idx
     ).astype(np.float32)
@@ -371,9 +397,14 @@ class HostShadow:
                         np.maximum.at(arr, (slots[m], kk, reg[m]), rho[m])
                 elif comp == "hist":
                     if m.any():
-                        b = hist_bin_np(v)
-                        kk = np.full(int(m.sum()), k)
-                        np.add.at(arr, (slots[m], kk, b[m]), 1.0)
+                        # one add a distinct (key, bin), not one a row:
+                        # sort the rows' flat indices, add the run lengths
+                        # (np.add.at costs several times that at the
+                        # 200,000 rows of a sliding trigger's edge)
+                        flat = ((slots[m].astype(np.int64) * arr.shape[1]
+                                 + k) * arr.shape[2] + hist_bin_np(v)[m])
+                        at, rows_at = np.unique(flat, return_counts=True)
+                        arr.reshape(-1)[at] += rows_at
                 elif comp == "hh":
                     if m.any():
                         idx, wts = hh_update_parts_np(v[m], mf[m])
@@ -397,21 +428,25 @@ _MERGE_MAX = {"mn": False, "mx": True, "hll": True}
 
 
 def merge_components(
-    dev: Dict[str, np.ndarray], shadow: Optional[HostShadow], capacity: int,
+    dev: Dict[str, np.ndarray], shadow: Optional[HostShadow], n_keys: int,
 ) -> Dict[str, np.ndarray]:
-    """Device components ⊕ shadow components. Pads the device result when
-    the key table grew during the tail (new keys exist only in the shadow)."""
+    """Device components ⊕ shadow components over the window's `n_keys`
+    key slots — the slots in use when the window was issued; the rows
+    behind them (spare capacity, keys that came later) are no part of it,
+    so nothing is computed for them. Pads the device result when the key
+    table grew during the tail (new keys exist only in the shadow)."""
     out: Dict[str, np.ndarray] = {}
     if shadow is not None and shadow.n_rows:
-        shadow._ensure(capacity - 1)
+        shadow._ensure(n_keys - 1)
     for comp, d in dev.items():
-        if d.shape[0] < capacity:
-            pad_shape = (capacity - d.shape[0],) + d.shape[1:]
+        d = d[:n_keys]
+        if d.shape[0] < n_keys:
+            pad_shape = (n_keys - d.shape[0],) + d.shape[1:]
             d = np.concatenate(
                 [d, np.full(pad_shape, _INIT[comp], dtype=d.dtype)], axis=0
             )
         if shadow is not None and shadow.n_rows:
-            s = shadow.data[comp][: d.shape[0]]
+            s = shadow.data[comp][:n_keys]
             if comp == "mn":
                 d = np.minimum(d, s)
             elif comp in ("mx", "hll"):

@@ -312,10 +312,16 @@ class SlidingRing:
         import jax.numpy as jnp
 
         out = dict(ring)
+        # a masked sum does not depend on the panes' order: carry the mask
+        # to the slots and read the panes where they lie (a gather into
+        # age order would be a second copy of every pane: 3.5 GB of
+        # 1,024-bin histograms at 16,384 keys)
+        slot_on = jnp.zeros(self.gb.n_panes, dtype=jnp.bool_) \
+            .at[order].set(valid)
         for c in self.add_comps:
-            g = pane_state[c][order]
-            vm = valid.reshape((-1,) + (1,) * (g.ndim - 1))
-            out[f"tot_{c}"] = jnp.sum(jnp.where(vm, g, 0.0), axis=0)
+            p = pane_state[c]
+            vm = slot_on.reshape((-1,) + (1,) * (p.ndim - 1))
+            out[f"tot_{c}"] = jnp.sum(jnp.where(vm, p, 0.0), axis=0)
         for c in self.mm_comps:
             ident = jnp.float32(_INIT[c])
             g = pane_state[c][order]
@@ -336,28 +342,33 @@ class SlidingRing:
         into the SAME (capacity, W) components array _components_body
         produces — the host merge/final-value tail is shared with the
         prefinalize emit path."""
+        import jax
         import jax.numpy as jnp
 
         cap = self.capacity
         parts = []
         for c in sorted(self.gb.comp_specs) + ["act"]:
-            if c in ADD_COMBINE:
-                v = jnp.where(body_on, ring[f"tot_{c}"], 0.0)
-                for i in range(QUERY_ADJ):
-                    v = v + adj_w[i] * pane_state[c][adj_slots[i]]
-            else:
-                ident = jnp.float32(_INIT[c])
-                v = jnp.where(jnp.logical_and(body_on, f_on),
-                              ring[f"front_{c}"][f_idx], ident)
-                v = self._combine(
-                    c, v, jnp.where(body_on, ring[f"back_{c}"], ident))
-                for i in range(QUERY_ADJ):
+            # scoped so a device trace's op names say which component's
+            # combine they belong to (kuiper/slide_query/<comp>)
+            with jax.named_scope(f"kuiper/slide_query/{c}"):
+                if c in ADD_COMBINE:
+                    v = jnp.where(body_on, ring[f"tot_{c}"], 0.0)
+                    for i in range(QUERY_ADJ):
+                        v = v + adj_w[i] * pane_state[c][adj_slots[i]]
+                else:
+                    ident = jnp.float32(_INIT[c])
+                    v = jnp.where(jnp.logical_and(body_on, f_on),
+                                  ring[f"front_{c}"][f_idx], ident)
                     v = self._combine(
-                        c, v, jnp.where(adj_mm[i],
-                                        pane_state[c][adj_slots[i]],
-                                        ident))
-            parts.append(v.reshape(cap, -1))
-        return jnp.concatenate(parts, axis=1)
+                        c, v, jnp.where(body_on, ring[f"back_{c}"], ident))
+                    for i in range(QUERY_ADJ):
+                        v = self._combine(
+                            c, v, jnp.where(adj_mm[i],
+                                            pane_state[c][adj_slots[i]],
+                                            ident))
+                parts.append(v.reshape(cap, -1))
+        with jax.named_scope("kuiper/slide_query/stack"):
+            return jnp.concatenate(parts, axis=1)
 
     # ---------------------------------------------------------- wrappers
     def advance(self, ring, pane_state, closed_slot: int, closed_on: bool,

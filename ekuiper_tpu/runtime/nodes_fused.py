@@ -427,6 +427,12 @@ class FusedWindowAggNode(Node):
         # the next boundary overwrites the record above (surfaced in
         # /rules/{id}/status)
         self.emit_sources: Dict[str, int] = {}
+        # sliding (DABA) triggers by the path that served the window body:
+        # fast (one combine of the ring's running partials), flip (the
+        # partials rebuilt from the panes first), dyn (traced-mask pane
+        # merge, the exact fallback), edge (nothing on the device: every
+        # row in the host edge shadow) — kuiper_sliding_triggers_total
+        self.sliding_triggers: Dict[str, int] = {}
 
     def _make_gb(self, plan, capacity: int, micro_batch: int, mesh):
         """Build the group-by kernel; subclasses override (MultiRuleFusedNode
@@ -1456,9 +1462,21 @@ class FusedWindowAggNode(Node):
         if kind == "ring":
             # sliding DABA trigger: fetch the O(1) body combine, merge the
             # host edge shadow, final values in numpy — the same component
-            # tail as the prefinalize emit
-            pending, shadow = payload
-            outs, act = self._fetch_and_merge(pending, shadow, n_keys)
+            # tail as the prefinalize emit. Dispatch -> landed of the ring's
+            # query program and the host tail are stages of their own
+            # inside `emit` (one call a trigger each: counters a reader
+            # can take); the dyn fallback's fetch is another program's
+            # and stays a `fetch` span
+            pending, shadow, path = payload
+            if pending is not None:
+                with (self.stats.span("fetch") if path == "dyn"
+                      else self.stats.stage("slide_query", n_keys,
+                                            within="emit",
+                                            since_ns=int(t_issue * 1e9))):
+                    pending.get()
+            with self.stats.stage("slide_merge", n_keys, within="emit"):
+                outs, act = self.gb.prefinalize_merge(pending, shadow,
+                                                      n_keys)
             self.last_emit_info = {
                 "source": "device-ring",
                 "fetch_ms": (pending.fetch_ms() if pending is not None else
@@ -1496,12 +1514,9 @@ class FusedWindowAggNode(Node):
     def _fetch_and_merge(self, pending, shadow, n_keys: int):
         """Complete a pre-issued finalize on this thread: wait for its
         fetch to land (`fetch` sub-stage), then merge the tail shadow and
-        compute the final values (`merge`). `pending` is None where
-        nothing on the device belongs to the window (a sliding trigger
-        whose rows all sit in its host edge shadow)."""
+        compute the final values (`merge`)."""
         with self.stats.span("fetch"):
-            if pending is not None:
-                pending.get()
+            pending.get()
         with self.stats.span("merge"):
             return self.gb.prefinalize_merge(pending, shadow, n_keys)
 
@@ -1832,9 +1847,10 @@ class FusedWindowAggNode(Node):
             else:
                 ev_slot, ev_on = oslot, bool(oon)
         if not self._rg_dirty:
-            self._ring_dev = self.ring.advance(
-                self._ring_state_now(), self.state, slot, bool(on),
-                ev_slot, ev_on)
+            with self.stats.stage("slide_advance", flip=False):
+                self._ring_dev = self.ring.advance(
+                    self._ring_state_now(), self.state, slot, bool(on),
+                    ev_slot, ev_on)
         self._rg_closes += 1
         self._rg_closed = b
 
@@ -2209,26 +2225,37 @@ class FusedWindowAggNode(Node):
         lo = t - self.length_ms  # exclusive
         hi = t + self.delay_ms  # inclusive
         b_lo, b_hi = lo // self.bucket_ms, hi // self.bucket_ms
-        shadow = HostShadow(self.plan, self.gb.comp_specs, self.kt.capacity)
         include_head = False
-        if b_lo == b_hi:
-            # window inside one bucket: the host edge fold IS the window
-            self._shadow_ring_rows(shadow, b_lo, lo_excl=lo, hi_incl=hi)
-            body = None
-        else:
-            self._shadow_ring_rows(shadow, b_lo, lo_excl=lo)
-            body = (b_lo + 1, b_hi - 1)
-            # high edge served straight from the live PANE when exact: it
-            # holds precisely bucket b_hi's rows folded so far, which
-            # equals (b_hi*B, hi] when no received row exceeds hi
-            if (self._pane_bucket.get(b_hi % self.n_ring_panes) == b_hi
-                    and self._bucket_max_ts.get(b_hi, hi + 1) <= hi):
-                include_head = True
+        # the trigger's host part on this (the fused) thread: its shadow
+        # and the numpy fold of the partial edge buckets' rows into it —
+        # a stage of its own, inside neither `fold` nor `emit`
+        with self.stats.stage("slide_edge") as st:
+            # as many rows as key slots are in use: the window has no
+            # other, and for a wide sketch the allocation is most of the
+            # stage (4 KB a row at 1,024 bins)
+            shadow = HostShadow(self.plan, self.gb.comp_specs, n_keys)
+            if b_lo == b_hi:
+                # window inside one bucket: the host edge fold IS the window
+                self._shadow_ring_rows(shadow, b_lo, lo_excl=lo, hi_incl=hi)
+                body = None
             else:
-                self._shadow_ring_rows(shadow, b_hi, hi_incl=hi)
-        pending = self._ring_body_query(body, include_head, b_hi, shadow)
-        self._emit_submit("ring", (pending, shadow), n_keys,
-                          WindowRange(lo, hi))
+                self._shadow_ring_rows(shadow, b_lo, lo_excl=lo)
+                body = (b_lo + 1, b_hi - 1)
+                # high edge served straight from the live PANE when exact:
+                # it holds precisely bucket b_hi's rows folded so far, which
+                # equals (b_hi*B, hi] when no received row exceeds hi
+                if (self._pane_bucket.get(b_hi % self.n_ring_panes) == b_hi
+                        and self._bucket_max_ts.get(b_hi, hi + 1) <= hi):
+                    include_head = True
+                else:
+                    self._shadow_ring_rows(shadow, b_hi, hi_incl=hi)
+            st.rows = shadow.n_rows
+        t_issue = time.perf_counter()  # slide_query: dispatch -> landed
+        pending, path = self._ring_body_query(body, include_head, b_hi,
+                                              shadow)
+        self.sliding_triggers[path] = self.sliding_triggers.get(path, 0) + 1
+        self._emit_submit("ring", (pending, shadow, path), n_keys,
+                          WindowRange(lo, hi), t_issue=t_issue)
 
     def _shadow_ring_rows(self, shadow, b: int, lo_excl: Optional[int] = None,
                           hi_incl: Optional[int] = None) -> None:
@@ -2257,17 +2284,18 @@ class FusedWindowAggNode(Node):
         ring query when the running partials cover the body, a one-off
         flip (rebuild from panes) when they don't, and the traced-mask
         components fallback for shapes outside the in-order discipline
-        (delayed emissions, recycled panes). Returns a PendingFinalize or
-        None (empty body, nothing on device)."""
+        (delayed emissions, recycled panes). Returns the PendingFinalize
+        (None: empty body, nothing on the device) and the path that
+        served it — fast, flip, dyn or edge."""
         from ..ops.slidingring import QUERY_ADJ
 
         head_slot = b_hi % self.n_ring_panes
         if body is None:
-            return None
+            return None, "edge"
         j, e = body
         if j > e:
             if not include_head:
-                return None
+                return None, "edge"
             adj_slots = np.zeros(QUERY_ADJ, dtype=np.int32)
             adj_w = np.zeros(QUERY_ADJ, dtype=np.float32)
             adj_mm = np.zeros(QUERY_ADJ, dtype=np.bool_)
@@ -2277,15 +2305,19 @@ class FusedWindowAggNode(Node):
             return self.ring.query_begin(
                 self._ring_state_now(), self.state, body_on=False,
                 f_on=False, f_slot=0, adj_slots=adj_slots,
-                adj_weights=adj_w, adj_mm=adj_mm)
+                adj_weights=adj_w, adj_mm=adj_mm), "fast"
         if self._rg_closed == e and self._rg_head == b_hi:
+            path = "fast"
             ok = not self._rg_dirty and self._ring_fast_ok(j)
             if not ok:
+                path = "flip"
                 self._ring_flip(j, e)
                 ok = not self._rg_dirty and self._ring_fast_ok(j)
             if ok:
-                return self._ring_query_fast(j, include_head, head_slot)
-        return self._ring_query_dyn(j, e, include_head, head_slot, shadow)
+                return self._ring_query_fast(j, include_head,
+                                             head_slot), path
+        pending = self._ring_query_dyn(j, e, include_head, head_slot, shadow)
+        return pending, ("dyn" if pending is not None else "edge")
 
     def _ring_fast_ok(self, j: int) -> bool:
         """Can the running partials serve a body starting at bucket j?"""
@@ -2320,9 +2352,10 @@ class FusedWindowAggNode(Node):
                 return  # rows exist but the pane is gone — dyn fallback
             valid[b - j] = live
             tot_entries.append((b, s, live))
-        self._ring_dev = self.ring.flip(
-            self._ring_state_now(), self.state, j % self.n_ring_panes,
-            valid)
+        with self.stats.stage("slide_advance", flip=True):
+            self._ring_dev = self.ring.flip(
+                self._ring_state_now(), self.state, j % self.n_ring_panes,
+                valid)
         self._rg_tot = _deque(tot_entries)
         self._rg_flip_lo, self._rg_flip_hi = j, e
         self._rg_anchor = self._rg_closes

@@ -121,6 +121,8 @@ def _synthetic_scrape() -> str:
             self.stats.process_end()
             if pooled:
                 self.pool_depths = lambda: (1, 0)
+            # kuiper_sliding_triggers_total renders from this attribute
+            self.sliding_triggers = {"fast": 2, "flip": 1}
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]
