@@ -415,8 +415,12 @@ def _derive_ring(ks: KernelShape, op: str, rule: Optional[str],
     advance — scalar closed/evict slot indices + on flags,
     flip    — int32[R] age-ordered slot rotation + bool[R] validity,
     query   — body/front flags + front row index + QUERY_ADJ adjustment
-              slot/weight/include vectors."""
-    from ..ops.slidingring import QUERY_ADJ
+              slot/weight/include vectors,
+    tail    — no ring or pane state: the query's stacked [capacity, W]
+              components, one edge buffer (every column with its
+              validity mask, slots) at the one static edge shape, and
+              the scalar row count."""
+    from ..ops.slidingring import QUERY_ADJ, TAIL_EDGE_CHUNKS
 
     sigs: List[str] = []
     deriv = [
@@ -428,7 +432,17 @@ def _derive_ring(ks: KernelShape, op: str, rule: Optional[str],
         "(n/s1/s2/hist/hh/act) vs two-stack front/back partials "
         "(mn/mx/hll)",
     ]
+    edge_rows = TAIL_EDGE_CHUNKS * ks.micro_batch
+    width = 1 + sum(k * (wide or 1) for k, wide in ks.comps.values())
     for cap in _ladder(ks.base_capacity, grows):
+        if tail == "tail":
+            sigs.append(_sig(
+                [_arr("float32", cap, width)]
+                + _col_leaves(ks.columns, edge_rows, frozenset(),
+                              masks_always=True, col_dtypes=ks.col_dtypes)
+                + [_arr("uint16" if cap <= 65535 else "int32", edge_rows),
+                   _arr("int32")]))
+            continue
         ring = _ring_leaves(ks.comps, cap, ring_slots)
         pane = _state_leaves(ks.comps, ks.n_panes, cap, touch=ks.touch)
         if tail == "advance":
@@ -448,6 +462,13 @@ def _derive_ring(ks: KernelShape, op: str, rule: Optional[str],
     elif tail == "flip":
         deriv.append(f"tail: int32[{ring_slots}] slot rotation + "
                      f"bool[{ring_slots}] validity (the amortized rebuild)")
+    elif tail == "tail":
+        deriv.append(
+            f"tail: float32[capacity,{width}] query components, one edge "
+            f"buffer of {TAIL_EDGE_CHUNKS} x micro_batch = {edge_rows} rows "
+            "(validity masks always materialized; slots uint16 under the "
+            "65,535 boundary of the buffer's own capacity, int32 above), "
+            "scalar row count")
     else:
         deriv.append(f"tail: body/front flags, front row, and "
                      f"{QUERY_ADJ} pane-slice adjustment slots "
@@ -456,7 +477,7 @@ def _derive_ring(ks: KernelShape, op: str, rule: Optional[str],
                     {"base_capacity": ks.base_capacity, "grows": grows,
                      "ring_slots": ring_slots, "n_panes": ks.n_panes,
                      "tail": tail, "query_adj": QUERY_ADJ,
-                     "touch": ks.touch,
+                     "edge_rows": edge_rows, "touch": ks.touch,
                      "comps": {c: list(v) for c, v in ks.comps.items()}},
                     frozenset(sigs), deriv, len(sigs) > ENUM_CAP,
                     full_count=grows + 1)
@@ -687,6 +708,7 @@ def _sliding_ring_certs(kernel, rule: Optional[str]) -> List[SiteCert]:
         _derive_ring(ks, "slidingring.advance", rule, slots, "advance"),
         _derive_ring(ks, "slidingring.flip", rule, slots, "flip"),
         _derive_ring(ks, "slidingring.query", rule, slots, "query"),
+        _derive_ring(ks, "slidingring.tail", rule, slots, "tail"),
     ]
 
 
@@ -770,6 +792,7 @@ SITE_DERIVATIONS: Dict[str, str] = {
     "slidingring.advance": "_derive_ring(advance)",
     "slidingring.flip": "_derive_ring(flip)",
     "slidingring.query": "_derive_ring(query)",
+    "slidingring.tail": "_derive_ring(tail)",
     "tierstore.demote": "_derive_tier(demote)",
     "tierstore.promote": "_derive_tier(promote)",
     "joinring.match": "_derive_join",
@@ -953,7 +976,7 @@ def estimate_plan_signatures(plan, n_panes: int, micro_batch: int,
     price its TRUE 2^n surface, or the signature budget inverts —
     admitting the compile-heaviest rules while rejecting narrower
     ones. `sliding_ring_slots` > 0 prices a DABA sliding rule's extra
-    surface (slidingring.advance/flip/query + the components_dyn
+    surface (slidingring.advance/flip/query/tail + the components_dyn
     fallback) so the budget cannot under-price sliding candidates;
     `tier_demote_batch` > 0 prices a tiered rule's demote/promote sites
     (the touch column changes every state signature, so the whole shape
@@ -994,7 +1017,8 @@ def estimate_plan_certs(plan, n_panes: int, micro_batch: int,
                                       "pane_mask", grows=0))
         for op, tail in (("slidingring.advance", "advance"),
                          ("slidingring.flip", "flip"),
-                         ("slidingring.query", "query")):
+                         ("slidingring.query", "query"),
+                         ("slidingring.tail", "tail")):
             certs.append(_derive_ring(ks, op, None, sliding_ring_slots,
                                       tail, grows=0))
     if tier_demote_batch > 0 and not ks.host_finalize_only:

@@ -229,6 +229,21 @@ def render(rule_registry) -> str:
             out.append(
                 f"kuiper_sliding_triggers_total{{{op_labels(rule_id, node)},"
                 f'path="{_esc(path)}"}} {n}')
+    # ... and by where the trigger was finished: on the device (the tail
+    # program: edge rows scattered, body and edges combined, final values;
+    # a compact fetch) or on the host (shadow, merge and final values in
+    # numpy over the fetched sketch) — device / (device + host) is the
+    # share of triggers that never moved a sketch over the link
+    _family(out, "kuiper_sliding_tail_total", "counter",
+            "sliding-window triggers by where their tail ran: device (paths "
+            "fast and flip: slidingring.tail returns final values), host "
+            "(paths dyn and edge: edge shadow, merge and final values in "
+            "numpy)")
+    for rule_id, node in rows:
+        for tail, n in sorted(getattr(node, "sliding_tails", {}).items()):
+            out.append(
+                f"kuiper_sliding_tail_total{{{op_labels(rule_id, node)},"
+                f'tail="{_esc(tail)}"}} {n}')
     # the SLO headline: per-rule ingest→emit latency as a real Prometheus
     # histogram (_bucket/_sum/_count with le labels) — histogram_quantile()
     # over it answers "is p99 emit under 50ms" directly

@@ -36,6 +36,13 @@ every GROUP BY key, with statically bounded shapes (capacity ladder ×
 plan-time ring geometry) so jitcert can certify the closed signature
 set (`observability/jitcert.py _derive_ring`).
 
+A fourth program, ``tail``, finishes a trigger the running partials
+served: it takes the query's stacked components where they lie (on the
+device), scatters the rows of the window's partial edge buckets with the
+fold's own code, combines body and edges per component class and applies
+the final values — the trigger fetches ``(n_specs + 1, capacity)``
+floats, not the sketch.
+
 The ring caches are pure functions of the pane state: a checkpoint
 restore or any host-side confusion (late rows into closed buckets, time
 gaps) simply marks the cache dirty and the next trigger rebuilds it with
@@ -62,6 +69,15 @@ ADD_COMBINE = frozenset({"n", "s1", "s2", "hist", "hh", "act", "touch"})
 MIN_COMBINE = frozenset({"mn"})
 #: max-merge components (two-stack discipline; hll registers merge by max)
 MAX_COMBINE = frozenset({"mx", "hll"})
+
+#: micro-batches of edge rows one call of the tail program takes: its one
+#: static edge shape is TAIL_EDGE_CHUNKS x micro_batch rows (the edge
+#: buckets of a trigger hold about one bucket of rows: 270,000 at 1.3M
+#: rows/s into 208 ms buckets, against 524,288 at a 32,768-row
+#: micro-batch); a trigger with more loops over it. The program scatters
+#: only the micro-batches that hold rows, so the spare room costs an
+#: upload, not device time.
+TAIL_EDGE_CHUNKS = 16
 
 #: pane-slice adjustment slots a query carries: up to two low-edge
 #: subtractions (the running total trails the window start by at most the
@@ -171,7 +187,7 @@ def ring_layout_for(window, plan, capacity: Optional[int] = None,
 class SlidingRing:
     """Device-resident DABA ring over a DeviceGroupBy's pane state.
 
-    Owns three jit sites (`slidingring.advance/flip/query`), each
+    Owns four jit sites (`slidingring.advance/flip/query/tail`), each
     certified by jitcert (`_derive_ring`); the host-side bucket
     bookkeeping (which bucket is closed/evicted/queried) lives in the
     fused node — this class is the pure device kernel."""
@@ -204,6 +220,16 @@ class SlidingRing:
         self._query = aot_jit(self._query_impl,
                                   op=self._watch_op("query"),
                                   kind="boundary")
+        # the query's components are the tail's to overwrite: nothing
+        # else reads them on this path. Watched at the fold's cadence
+        # ("hot": one call in 64 sampled), not a boundary's one in 4: a
+        # sampled call waits for the program on the dispatching thread,
+        # the fused worker, and this one scatters a bucket of rows
+        self._tail = aot_jit(self._tail_impl,
+                                 op=self._watch_op("tail"),
+                                 donate_argnums=(0,))
+        #: rows of the tail's one static edge shape
+        self.edge_rows = TAIL_EDGE_CHUNKS * int(gb.micro_batch)
         from ..observability import jitcert
 
         jitcert.register_kernel(self)
@@ -370,6 +396,61 @@ class SlidingRing:
         with jax.named_scope("kuiper/slide_query/stack"):
             return jnp.concatenate(parts, axis=1)
 
+    def _tail_impl(self, body, cols, slots, n_valid):
+        """Finish a trigger on the device: `body` is the query's stacked
+        (capacity, W) components, `cols`/`slots` one edge buffer of
+        `edge_rows` rows of which the first `n_valid` count. The edge
+        rows scatter into a one-pane identity state with the fold's own
+        code, a micro-batch at a time and only as many as hold rows; body
+        and edges combine per component class as `merge_components` does
+        (add / min / max); `_final_value` per spec gives the
+        `(n_specs + 1, capacity)` values + `act` that `_finalize_dyn`
+        returns. The merged components come back too, in the body's
+        layout: a trigger with more edge rows than one buffer holds feeds
+        them to the next call."""
+        import jax
+        import jax.numpy as jnp
+
+        gb = self.gb
+        cap = body.shape[0]
+        mb = gb.micro_batch
+        layout = gb._components_layout()
+        with jax.named_scope("kuiper/slide_tail/edge_scatter"):
+            edge = {comp: jnp.full((1, cap) + shape, _INIT[comp],
+                                   dtype=jnp.float32)
+                    for comp, _col, _w, shape in layout}
+            pane = jnp.zeros((), dtype=jnp.int32)
+
+            def scatter(i, state):
+                at = i * mb
+                rows = {k: jax.lax.dynamic_slice_in_dim(v, at, mb)
+                        for k, v in cols.items()}
+                base = at + jnp.arange(mb, dtype=jnp.int32) < n_valid
+                return gb._fold_core(
+                    dict(state), rows,
+                    jax.lax.dynamic_slice_in_dim(slots, at, mb), base, pane)
+
+            edge = jax.lax.fori_loop(0, (n_valid + mb - 1) // mb, scatter,
+                                     edge)
+        merged = {}
+        for comp, col, w, shape in layout:
+            with jax.named_scope(f"kuiper/slide_tail/merge_{comp}"):
+                b = body[:, col:col + w].reshape((cap,) + shape)
+                e = edge[comp][0]
+                merged[comp] = (b + e if comp in ADD_COMBINE
+                                else self._combine(comp, b, e))
+        with jax.named_scope("kuiper/slide_tail/values"):
+            outs = []
+            for i, spec in enumerate(gb.plan.specs):
+                outs.append(gb._final_value(spec, {
+                    comp: merged[comp][:, gb.comp_specs[comp].index(i)]
+                    for comp in spec.components}))
+            final = jnp.stack(outs + [merged["act"]], axis=0)
+        with jax.named_scope("kuiper/slide_tail/stack"):
+            return jnp.concatenate(
+                [merged[comp].reshape(cap, -1)
+                 for comp, _col, _w, _shape in layout], axis=1), final
+
     # ---------------------------------------------------------- wrappers
     def advance(self, ring, pane_state, closed_slot: int, closed_on: bool,
                 evict_slot: int, evict_on: bool):
@@ -394,22 +475,78 @@ class SlidingRing:
         return self._flip(ring, pane_state, jnp.asarray(order),
                           jnp.asarray(np.asarray(valid, dtype=np.bool_)))
 
-    def query_begin(self, ring, pane_state, *, body_on: bool, f_on: bool,
-                    f_slot: int, adj_slots: np.ndarray,
-                    adj_weights: np.ndarray, adj_mm: np.ndarray):
-        """Dispatch the O(1) window-body combine and start the async
-        device→host copy; returns a PendingFinalize the emit worker
-        merges with the host edge shadow (ops/prefinalize.py)."""
+    def query(self, ring, pane_state, *, body_on: bool, f_on: bool,
+              f_slot: int, adj_slots: np.ndarray,
+              adj_weights: np.ndarray, adj_mm: np.ndarray):
+        """Dispatch the O(1) window-body combine; returns the stacked
+        (capacity, W) components on the device — the tail's input."""
         import jax.numpy as jnp
 
-        from .prefinalize import begin_pending
-
-        out = self._query(
+        return self._query(
             ring, pane_state,
             jnp.asarray(bool(body_on)), jnp.asarray(bool(f_on)),
             jnp.asarray(int(f_slot), dtype=jnp.int32),
             jnp.asarray(np.asarray(adj_slots, dtype=np.int32)),
             jnp.asarray(np.asarray(adj_weights, dtype=np.float32)),
             jnp.asarray(np.asarray(adj_mm, dtype=np.bool_)))
-        return begin_pending(out, self.capacity,
-                             self.gb._components_layout())
+
+    def edge_buffers(self, segs):
+        """A trigger's edge rows gathered into buffers at the tail's one
+        static shape. `segs` is a list of (cols, valid, slots, sel): a
+        retained segment's kernel inputs and the rows of it inside the
+        window's cut (an index array; None: all of them). Returns a list
+        of (cols, valid, slots, n), one entry but where the rows pass
+        `edge_rows` (none still gives one, with n = 0): the plan's columns
+        at their upload dtypes, a validity mask for every one of them
+        (always there, so that one program serves batches with and
+        without nulls), rows past n zeroed."""
+        from .groupby import col_np_dtype, slot_dtype
+
+        counts = [len(slots) if sel is None else len(sel)
+                  for _c, _v, slots, sel in segs]
+        n = sum(counts)
+        rows = self.edge_rows
+        n_buf = max(-(-n // rows), 1)
+
+        def gather(parts, dtype):
+            flat = np.empty(n_buf * rows, dtype=dtype)
+            flat[n:] = 0
+            if parts:
+                np.concatenate(parts, out=flat[:n], casting="unsafe")
+            return flat
+
+        def pick(a, sel):
+            return a if sel is None else a[sel]
+
+        plan = self.gb.plan
+        cols = {name: gather([pick(c[name], sel) for c, _v, _s, sel in segs],
+                             col_np_dtype(plan, name))
+                for name in plan.columns}
+        valid = {name: gather(
+            [np.ones(k, dtype=np.bool_) if v.get(name) is None
+             else pick(v[name], sel)
+             for (_c, v, _s, sel), k in zip(segs, counts)], np.bool_)
+            for name in plan.columns}
+        slots = gather([pick(s, sel) for _c, _v, s, sel in segs],
+                       slot_dtype(self.capacity))
+        return [({k: v[at:at + rows] for k, v in cols.items()},
+                 {k: v[at:at + rows] for k, v in valid.items()},
+                 slots[at:at + rows], min(n - at, rows))
+                for at in range(0, n_buf * rows, rows)]
+
+    def tail_begin(self, body, buffers):
+        """Dispatch the device tail over the query's `body` and the
+        trigger's edge rows (`buffers`: what `edge_buffers` returns) and
+        start the async copy of the compact result; returns the
+        `(n_specs + 1, capacity)` device array."""
+        import jax.numpy as jnp
+
+        final = None
+        for cols, valid, slots, n in buffers:
+            dev = {name: jnp.asarray(c) for name, c in cols.items()}
+            for name, v in valid.items():
+                dev["__valid_" + name] = jnp.asarray(v)
+            body, final = self._tail(body, dev, jnp.asarray(slots),
+                                     jnp.asarray(int(n), dtype=jnp.int32))
+        final.copy_to_host_async()
+        return final

@@ -433,6 +433,11 @@ class FusedWindowAggNode(Node):
         # merge, the exact fallback), edge (nothing on the device: every
         # row in the host edge shadow) — kuiper_sliding_triggers_total
         self.sliding_triggers: Dict[str, int] = {}
+        # ... and by where the trigger was finished: device (fast and
+        # flip: the tail program, a compact fetch), host (dyn and edge:
+        # the shadow, the merge and the final values in numpy) —
+        # kuiper_sliding_tail_total
+        self.sliding_tails: Dict[str, int] = {}
 
     def _make_gb(self, plan, capacity: int, micro_batch: int, mesh):
         """Build the group-by kernel; subclasses override (MultiRuleFusedNode
@@ -582,7 +587,7 @@ class FusedWindowAggNode(Node):
         self.gb.reset_pane(dummy, self.cur_pane)
 
     def _warmup_ring(self, dummy) -> None:
-        """Probe/compile the DABA trigger path (advance/flip/query +
+        """Probe/compile the DABA trigger path (advance/flip/query/tail +
         the traced-mask components fallback) on throwaway state."""
         from ..ops.slidingring import QUERY_ADJ
 
@@ -592,12 +597,14 @@ class FusedWindowAggNode(Node):
         ring = self.ring.advance(ring, dummy, 0, True, 0, False)
         ring = self.ring.flip(ring, dummy, 0,
                               np.zeros(self.n_ring_panes, dtype=np.bool_))
-        pend = self.ring.query_begin(
+        body = self.ring.query(
             ring, dummy, body_on=False, f_on=False, f_slot=0,
             adj_slots=np.zeros(QUERY_ADJ, dtype=np.int32),
             adj_weights=np.zeros(QUERY_ADJ, dtype=np.float32),
             adj_mm=np.zeros(QUERY_ADJ, dtype=np.bool_))
-        pend.get()
+        # the tail at its one static edge shape, no row in it
+        # kuiperlint: ignore[host-sync]: warm-up on throwaway state, before the first row
+        np.asarray(self.ring.tail_begin(body, self.ring.edge_buffers([])))
         self.gb.components_begin_dyn(
             dummy, np.zeros(self.gb.n_panes, dtype=np.bool_)).get()
 
@@ -1455,33 +1462,38 @@ class FusedWindowAggNode(Node):
         """One deferred delivery on the emit worker: wait for the device→
         host copy (`fetch`), assemble the window (`merge`), hand it down;
         returns the groups emitted."""
-        from ..ops.groupby import apply_int_semantics
-
         if kind == "pf":
             return self._deliver_pf(*payload, n_keys, wr)
         if kind == "ring":
-            # sliding DABA trigger: fetch the O(1) body combine, merge the
-            # host edge shadow, final values in numpy — the same component
-            # tail as the prefinalize emit. Dispatch -> landed of the ring's
-            # query program and the host tail are stages of their own
-            # inside `emit` (one call a trigger each: counters a reader
-            # can take); the dyn fallback's fetch is another program's
-            # and stays a `fetch` span
+            # sliding DABA trigger. Device tail (no shadow): the compact
+            # final values land, the window's key slots are cut from them.
+            # Host tail: the O(1) body combine's components land, the host
+            # edge shadow is merged, final values in numpy — the same
+            # component tail as the prefinalize emit. Dispatch -> landed
+            # of the ring's programs and what is left on the host are
+            # stages of their own inside `emit` (one call a trigger each:
+            # counters a reader can take); the dyn fallback's fetch is
+            # another program's and stays a `fetch` span
             pending, shadow, path = payload
-            if pending is not None:
-                with (self.stats.span("fetch") if path == "dyn"
-                      else self.stats.stage("slide_query", n_keys,
-                                            within="emit",
-                                            since_ns=int(t_issue * 1e9))):
-                    pending.get()
-            with self.stats.stage("slide_merge", n_keys, within="emit"):
-                outs, act = self.gb.prefinalize_merge(pending, shadow,
-                                                      n_keys)
-            self.last_emit_info = {
-                "source": "device-ring",
-                "fetch_ms": (pending.fetch_ms() if pending is not None else
-                             (time.perf_counter() - t_issue) * 1000.0),
-            }
+            if shadow is None:
+                with self.stats.stage("slide_query", n_keys, within="emit",
+                                      since_ns=int(t_issue * 1e9)):
+                    # kuiperlint: ignore[host-sync]: emit worker thread — THE intended sync point; the fold thread already dispatched and moved on
+                    arr = np.asarray(pending)
+                fetch_ms = (time.perf_counter() - t_issue) * 1000.0
+                with self.stats.stage("slide_merge", n_keys, within="emit"):
+                    outs, act = self._outs_from_stacked(arr, n_keys)
+            else:
+                if pending is not None:
+                    with self.stats.span("fetch"):
+                        pending.get()
+                fetch_ms = (pending.fetch_ms() if pending is not None else
+                            (time.perf_counter() - t_issue) * 1000.0)
+                with self.stats.stage("slide_merge", n_keys, within="emit"):
+                    outs, act = self.gb.prefinalize_merge(pending, shadow,
+                                                          n_keys)
+            self.last_emit_info = {"source": "device-ring",
+                                   "fetch_ms": fetch_ms}
             return self._emit_active(outs, act, wr)
         # heavy hitters: dispatch → landed and the host tail below are
         # stages of their own inside `emit` (counters a reader can take:
@@ -1504,12 +1516,17 @@ class FusedWindowAggNode(Node):
                 outs, act = self.gb.hh_assemble(arr, n_keys, self._hh_items)
             return self._emit_active(outs, act, wr, hh_decoded=True)
         with self.stats.span("merge"):
-            outs = [arr[i][:n_keys]
-                    for i in range(len(self.plan.specs))]
-            outs = apply_int_semantics(self.plan.specs, outs)
-            # kuiperlint: ignore[host-sync]: `arr` already landed on host above
-            act = np.asarray(arr[-1][:n_keys])
+            outs, act = self._outs_from_stacked(arr, n_keys)
         return self._emit_active(outs, act, wr)
+
+    def _outs_from_stacked(self, arr: np.ndarray, n_keys: int):
+        """(per-spec values, act) over the window's key slots from a
+        landed `(n_specs + 1, capacity)` finalize result."""
+        from ..ops.groupby import apply_int_semantics
+
+        outs = [arr[i][:n_keys] for i in range(len(self.plan.specs))]
+        return (apply_int_semantics(self.plan.specs, outs),
+                arr[-1][:n_keys])
 
     def _fetch_and_merge(self, pending, shadow, n_keys: int):
         """Complete a pre-issued finalize on this thread: wait for its
@@ -2211,12 +2228,17 @@ class FusedWindowAggNode(Node):
     def _emit_sliding_ring(self, t: int) -> None:
         """DABA-ring emission for trigger time t: the full-pane window
         body is ONE device combine of the ring's running partials (plus at
-        most QUERY_ADJ pane slices); the partial edge buckets fold on HOST
-        from the row ring into a HostShadow merged by the emit worker — no
-        per-trigger device refold of cached batch history, no
-        window-length pane merge. Exactness matches the refold path: the
-        panes remain the ground truth and every off-discipline shape
-        (delay, recycled panes, restores) takes an exact fallback."""
+        most QUERY_ADJ pane slices). Where that program served the body
+        (paths fast and flip) the trigger is finished on the device: the
+        rows of the partial edge buckets, cut by stamp from the row ring,
+        go up in one fixed-shape buffer and the tail program scatters
+        them, combines body and edges and returns final values — no
+        per-trigger shadow, no fetch of the sketch. Every off-discipline
+        shape (a head that moved on, a window inside one bucket, an empty
+        body, delay, recycled panes, restores) keeps the exact host tail:
+        a HostShadow of the edge rows merged by the emit worker with
+        whatever the traced-mask pane merge returns. The panes remain the
+        ground truth on both."""
         from ..ops.prefinalize import HostShadow
 
         n_keys = self.kt.n_keys
@@ -2226,67 +2248,92 @@ class FusedWindowAggNode(Node):
         hi = t + self.delay_ms  # inclusive
         b_lo, b_hi = lo // self.bucket_ms, hi // self.bucket_ms
         include_head = False
-        # the trigger's host part on this (the fused) thread: its shadow
-        # and the numpy fold of the partial edge buckets' rows into it —
-        # a stage of its own, inside neither `fold` nor `emit`
-        with self.stats.stage("slide_edge") as st:
-            # as many rows as key slots are in use: the window has no
-            # other, and for a wide sketch the allocation is most of the
-            # stage (4 KB a row at 1,024 bins)
-            shadow = HostShadow(self.plan, self.gb.comp_specs, n_keys)
-            if b_lo == b_hi:
-                # window inside one bucket: the host edge fold IS the window
-                self._shadow_ring_rows(shadow, b_lo, lo_excl=lo, hi_incl=hi)
-                body = None
+        if b_lo == b_hi:
+            # window inside one bucket: the edge rows ARE the window
+            cuts = [(b_lo, lo, hi)]
+            body = None
+        else:
+            cuts = [(b_lo, lo, None)]
+            body = (b_lo + 1, b_hi - 1)
+            # high edge served straight from the live PANE when exact:
+            # it holds precisely bucket b_hi's rows folded so far, which
+            # equals (b_hi*B, hi] when no received row exceeds hi
+            if (self._pane_bucket.get(b_hi % self.n_ring_panes) == b_hi
+                    and self._bucket_max_ts.get(b_hi, hi + 1) <= hi):
+                include_head = True
             else:
-                self._shadow_ring_rows(shadow, b_lo, lo_excl=lo)
-                body = (b_lo + 1, b_hi - 1)
-                # high edge served straight from the live PANE when exact:
-                # it holds precisely bucket b_hi's rows folded so far, which
-                # equals (b_hi*B, hi] when no received row exceeds hi
-                if (self._pane_bucket.get(b_hi % self.n_ring_panes) == b_hi
-                        and self._bucket_max_ts.get(b_hi, hi + 1) <= hi):
-                    include_head = True
-                else:
-                    self._shadow_ring_rows(shadow, b_hi, hi_incl=hi)
-            st.rows = shadow.n_rows
+                cuts.append((b_hi, None, hi))
+        # the path first, then the edge rows once: for the device where
+        # the ring's program took the body, for the shadow where not
         t_issue = time.perf_counter()  # slide_query: dispatch -> landed
-        pending, path = self._ring_body_query(body, include_head, b_hi,
-                                              shadow)
+        body_dev, path = self._ring_body_query(body, include_head, b_hi)
+        pending = shadow = None
+        # the trigger's edge rows on this (the fused) thread — a stage of
+        # its own, inside neither `fold` nor `emit`
+        with self.stats.stage("slide_edge") as st:
+            if body_dev is not None:
+                buffers = self.ring.edge_buffers(
+                    [seg for cut in cuts for seg in self._ring_rows_cut(*cut)])
+                st.rows = sum(n for _c, _v, _s, n in buffers)
+                pending = self.ring.tail_begin(body_dev, buffers)
+            else:
+                # as many rows as key slots are in use: the window has no
+                # other, and for a wide sketch the allocation is most of
+                # the stage (4 KB a row at 1,024 bins)
+                shadow = HostShadow(self.plan, self.gb.comp_specs, n_keys)
+                for cut in cuts:
+                    self._shadow_ring_rows(shadow, *cut)
+                st.rows = shadow.n_rows
+        if path == "dyn":
+            pending = self._ring_query_dyn(*body, include_head,
+                                           b_hi % self.n_ring_panes, shadow)
+            if pending is None:
+                path = "edge"
+        tail = "host" if shadow is not None else "device"
         self.sliding_triggers[path] = self.sliding_triggers.get(path, 0) + 1
+        self.sliding_tails[tail] = self.sliding_tails.get(tail, 0) + 1
         self._emit_submit("ring", (pending, shadow, path), n_keys,
                           WindowRange(lo, hi), t_issue=t_issue)
 
-    def _shadow_ring_rows(self, shadow, b: int, lo_excl: Optional[int] = None,
-                          hi_incl: Optional[int] = None) -> None:
-        """Numpy-fold bucket b's retained rows (optionally time-cut) into
-        the trigger's HostShadow — bounded by ONE bucket of rows, not the
-        window history."""
+    def _ring_rows_cut(self, b: int, lo_excl: Optional[int] = None,
+                       hi_incl: Optional[int] = None):
+        """Bucket b's retained rows inside a time cut, segment by segment:
+        yields (cols, valid, slots, sel) — the segment's kernel inputs
+        and the rows of it that count (an index array; None: all of
+        them). Bounded by ONE bucket of rows, not the window history."""
         for cols, valid, slots, ts in self._ring.get(b, []):
             m = np.ones(len(ts), dtype=np.bool_)
             if lo_excl is not None:
                 m &= ts > lo_excl
             if hi_incl is not None:
                 m &= ts <= hi_incl
-            if not m.any():
-                continue
-            if m.all():
+            if m.any():
+                yield cols, valid, slots, (None if m.all()
+                                           else np.nonzero(m)[0])
+
+    def _shadow_ring_rows(self, shadow, b: int, lo_excl: Optional[int] = None,
+                          hi_incl: Optional[int] = None) -> None:
+        """Numpy-fold bucket b's retained rows (optionally time-cut) into
+        the trigger's HostShadow."""
+        for cols, valid, slots, sel in self._ring_rows_cut(b, lo_excl,
+                                                           hi_incl):
+            if sel is None:
                 shadow.fold(cols, slots, valid)
             else:
-                sel = np.nonzero(m)[0]
                 shadow.fold({k: v[sel] for k, v in cols.items()},
                             slots[sel],
                             {k: v[sel] for k, v in valid.items()})
 
-    def _ring_body_query(self, body, include_head: bool, b_hi: int,
-                         shadow):
-        """Dispatch the device body combine for one trigger: the O(1)
-        ring query when the running partials cover the body, a one-off
-        flip (rebuild from panes) when they don't, and the traced-mask
-        components fallback for shapes outside the in-order discipline
-        (delayed emissions, recycled panes). Returns the PendingFinalize
-        (None: empty body, nothing on the device) and the path that
-        served it — fast, flip, dyn or edge."""
+    def _ring_body_query(self, body, include_head: bool, b_hi: int):
+        """Resolve the path that serves one trigger's window body and,
+        where it is the ring's own program, dispatch it: the O(1) ring
+        query when the running partials cover the body (fast), after a
+        one-off flip (rebuild from panes) when they don't (flip). Returns
+        the query's components on the device and the path; (None, "dyn")
+        for shapes outside the in-order discipline (delayed emissions,
+        recycled panes), whose traced-mask pane merge the caller
+        dispatches once the shadow is there, and (None, "edge") for an
+        empty body."""
         from ..ops.slidingring import QUERY_ADJ
 
         head_slot = b_hi % self.n_ring_panes
@@ -2302,7 +2349,7 @@ class FusedWindowAggNode(Node):
             adj_slots[0] = head_slot
             adj_w[0] = 1.0
             adj_mm[0] = True
-            return self.ring.query_begin(
+            return self.ring.query(
                 self._ring_state_now(), self.state, body_on=False,
                 f_on=False, f_slot=0, adj_slots=adj_slots,
                 adj_weights=adj_w, adj_mm=adj_mm), "fast"
@@ -2316,8 +2363,7 @@ class FusedWindowAggNode(Node):
             if ok:
                 return self._ring_query_fast(j, include_head,
                                              head_slot), path
-        pending = self._ring_query_dyn(j, e, include_head, head_slot, shadow)
-        return pending, ("dyn" if pending is not None else "edge")
+        return None, "dyn"
 
     def _ring_fast_ok(self, j: int) -> bool:
         """Can the running partials serve a body starting at bucket j?"""
@@ -2382,7 +2428,7 @@ class FusedWindowAggNode(Node):
             adj_w[k] = 1.0
             adj_mm[k] = True
         f_on = bool(self.ring.mm_comps) and j <= self._rg_flip_hi
-        return self.ring.query_begin(
+        return self.ring.query(
             self._ring_state_now(), self.state, body_on=True, f_on=f_on,
             f_slot=j % self.n_ring_panes, adj_slots=adj_slots,
             adj_weights=adj_w, adj_mm=adj_mm)

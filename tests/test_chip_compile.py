@@ -192,7 +192,7 @@ def test_slidingring_advance_flip_query(one_chip):
         "_advance": lambda: ring.advance(rs, panes, 0, True, 1, True),
         "_flip": lambda: ring.flip(
             rs, panes, 0, np.ones(layout.n_ring_panes, dtype=np.bool_)),
-        "_query": lambda: ring.query_begin(
+        "_query": lambda: ring.query(
             rs, panes, body_on=True, f_on=True, f_slot=0,
             adj_slots=np.zeros(QUERY_ADJ, dtype=np.int32),
             adj_weights=np.zeros(QUERY_ADJ, dtype=np.float32),
@@ -203,6 +203,39 @@ def test_slidingring_advance_flip_query(one_chip):
         mem = _compile(site, args, one_chip).memory_analysis()
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < HBM_BYTES, attr
+
+
+def test_slidingring_tail(one_chip):
+    """The trigger's device tail at `slidingpct10k`'s size: the query's
+    (16,384 x 1,026) components, one edge buffer of 16 micro-batches of
+    32,768 rows. Beside the panes (3.56 GB) it may take a few copies of
+    the 67 MB sketch, not more."""
+    from ekuiper_tpu.ops.slidingring import (QUERY_ADJ, SlidingRing,
+                                             ring_layout_for)
+
+    stmt = parse_select(SLIDING_SQL)
+    plan = extract_kernel_plan(stmt)
+    layout = ring_layout_for(stmt.window, plan, capacity=16384,
+                             budget_mb=256)
+    gb = DeviceGroupBy(plan, capacity=16384, n_panes=layout.n_panes,
+                       micro_batch=32768)
+    ring = SlidingRing(gb, layout)
+    assert ring.edge_rows == 524288
+    body = jax.eval_shape(
+        ring._query_impl, jax.eval_shape(ring.init_state),
+        jax.eval_shape(gb.init_state), np.bool_(True), np.bool_(True),
+        np.int32(0), np.zeros(QUERY_ADJ, np.int32),
+        np.zeros(QUERY_ADJ, np.float32), np.zeros(QUERY_ADJ, np.bool_))
+    assert body.shape == (16384, 1026)
+    site, args = _capture(
+        ring, "_tail",
+        lambda: ring.tail_begin(body, ring.edge_buffers([])))
+    assert args[2].shape == (524288,) and args[2].dtype == np.uint16
+    compiled = _compile(site, args, one_chip)
+    mem = compiled.memory_analysis()
+    sketch = 16384 * 1026 * 4
+    assert mem.output_size_in_bytes < sketch + (1 << 20)
+    assert mem.temp_size_in_bytes < 4 * sketch, mem.temp_size_in_bytes
 
 
 # ---------------------------------------------------------------- joinring

@@ -150,6 +150,31 @@ def endspike_batches(n_batches=3, rows=32, keys=4, t0=10_000, step=100,
     return out
 
 
+def spike_batches(at_end: bool, n_batches=24, rows=32, keys=4, seed=9):
+    """Batches 205 ms apart whose rows lie within 5 ms, inside one ring
+    bucket at either bucket width (25 ms; 41 ms for a wide sketch): every
+    third batch holds a trigger — its last row (`at_end`: nothing received
+    is newer than the trigger), or one in its middle with later rows of
+    the same bucket behind it."""
+    rng = np.random.default_rng(seed)
+    out = []
+    for i in range(n_batches):
+        ids = np.array([f"d{j}" for j in rng.integers(0, keys, rows)],
+                       dtype=np.object_)
+        temp = rng.uniform(0, 88, rows).astype(np.float32)
+        ts = 10_250 + 205 * i + np.sort(
+            rng.integers(0, 5, rows)).astype(np.int64)
+        ts[-1] = ts[0] + 4
+        if i % 3 == 2:
+            at = rows - 1 if at_end else int(np.argmax(ts > ts[0] + 1))
+            temp[at] = 95.0
+            assert at_end or ts[at] < ts[-1]
+        out.append(ColumnBatch(
+            n=rows, columns={"deviceId": ids, "temp": temp},
+            timestamps=ts, emitter="s"))
+    return out
+
+
 def random_trigger_batches(seed=7, n_batches=12, rows=48, keys=5,
                            t0=10_000, step=100, spike_every=17):
     rng = np.random.default_rng(seed)
@@ -236,6 +261,213 @@ class TestWindowShapes:
                 node._emit_sliding(t)
             node._drain_async_emits()
         assert_parity(per_trigger(got_d), per_trigger(got_r))
+
+
+# ------------------------------------------------------- the device tail
+SQL_PCT = ("SELECT deviceId, percentile_approx(temp, 0.99) AS p99, "
+           "count(*) AS c FROM s GROUP BY deviceId, "
+           "SLIDINGWINDOW(ss, 2) OVER (WHEN temp > 90)")
+TAIL_MB = 16  # the tail's static edge shape is then 256 rows
+
+
+def tail_kernel(sql, capacity=64):
+    from ekuiper_tpu.ops.groupby import DeviceGroupBy
+    from ekuiper_tpu.ops.slidingring import ring_layout_for
+
+    stmt = parse_select(sql)
+    plan = extract_kernel_plan(stmt)
+    layout = ring_layout_for(stmt.window, plan)
+    gb = DeviceGroupBy(plan, capacity=capacity, n_panes=layout.n_panes,
+                       micro_batch=TAIL_MB)
+    return gb, SlidingRing(gb, layout)
+
+
+def tail_rows(gb, rng, n, key_lo, key_hi, nulls=False):
+    """(cols, valid, slots) of n rows over the slots [key_lo, key_hi): the
+    plan's columns as the node's row ring holds them."""
+    from ekuiper_tpu.ops.aggspec import materialize_hll_columns
+
+    temp = np.round(rng.normal(20.0, 15.0, n), 2).astype(np.float32)
+    valid = {}
+    if nulls:
+        mask = rng.random(n) > 0.3
+        valid = {name: mask for name in gb.plan.columns}
+        temp[rng.random(n) > 0.8] = np.nan
+    return (materialize_hll_columns(gb.plan.columns, {"temp": temp}, n),
+            valid, rng.integers(key_lo, key_hi, n).astype(np.int32))
+
+
+class _Landed:
+    """A fetched components array, as `prefinalize_merge` takes it."""
+
+    def __init__(self, arr, layout):
+        from ekuiper_tpu.ops.prefinalize import unpack_components
+
+        self._comps = unpack_components(arr, layout)
+
+    def get(self):
+        return self._comps
+
+
+class TestDeviceTail:
+    """A trigger the ring's running partials served is finished on the
+    device (`slidingring.tail`): against the host tail it replaced —
+    HostShadow + merge_components + numpy final values — on the same
+    query result and the same edge rows."""
+
+    @pytest.mark.parametrize("edge", ["no_rows", "one_buffer", "two_buffers",
+                                      "new_key", "nulls", "cut_rows"])
+    @pytest.mark.parametrize("sql", [SQL_INV, SQL_MM, SQL_SKETCH, SQL_PCT],
+                             ids=["sums", "minmax", "sketches", "pct"])
+    def test_device_tail_equals_host_tail(self, sql, edge):
+        from ekuiper_tpu.ops.groupby import apply_int_semantics
+        from ekuiper_tpu.ops.prefinalize import HostShadow, hist_bin_np
+        from ekuiper_tpu.ops.slidingring import QUERY_ADJ
+
+        gb, ring = tail_kernel(sql)
+        assert ring.edge_rows == 16 * TAIL_MB
+        rng = np.random.default_rng(len(sql) * 31 + len(edge))
+        n_keys = 12
+        # the body: three closed panes of keys 0..7, the partials rebuilt
+        # from them, one combine of the partials
+        state = gb.init_state()
+        for pane in range(3):
+            cols, valid, slots = tail_rows(gb, rng, 300, 0, 8)
+            state = gb.fold(state, cols, slots, valid, pane)
+        rs = ring.flip(ring.init_state(), state, 0,
+                       np.arange(ring.n_ring_panes) < 3)
+        body = ring.query(
+            rs, state, body_on=True, f_on=True, f_slot=0,
+            adj_slots=np.zeros(QUERY_ADJ, dtype=np.int32),
+            adj_weights=np.zeros(QUERY_ADJ, dtype=np.float32),
+            adj_mm=np.zeros(QUERY_ADJ, dtype=np.bool_))
+        body_np = np.array(body)  # the tail overwrites its input
+        assert body_np[:8, -1].min() > 0 and body_np[8:, -1].max() == 0
+        # the edge rows, as the node's row ring holds them
+        segs = {
+            "no_rows": [],
+            "one_buffer": [tail_rows(gb, rng, 90, 0, 8), tail_rows(gb, rng, 70, 0, 8)],
+            # 600 rows: three calls of the program at 256 rows each
+            "two_buffers": [tail_rows(gb, rng, 250, 0, 8),
+                            tail_rows(gb, rng, 350, 0, 8)],
+            # keys 8..11 have no row in the body
+            "new_key": [tail_rows(gb, rng, 120, 4, n_keys)],
+            "nulls": [tail_rows(gb, rng, 150, 0, n_keys, nulls=True),
+                      tail_rows(gb, rng, 40, 0, 8)],
+            "cut_rows": [tail_rows(gb, rng, 200, 0, n_keys)],
+        }[edge]
+        segs = [seg + (None,) for seg in segs]
+        if edge == "cut_rows":  # a stamp cut keeps part of a segment
+            segs[0] = segs[0][:3] + (np.nonzero(rng.random(200) > 0.5)[0],)
+        buffers = ring.edge_buffers(segs)
+        n_edge = sum(len(s) if sel is None else len(sel)
+                     for _c, _v, s, sel in segs)
+        assert sum(n for _c, _v, _s, n in buffers) == n_edge
+        assert len(buffers) == max(-(-n_edge // ring.edge_rows), 1)
+        for cols, valid, slots, _n in buffers:  # one static shape
+            assert {a.shape for a in (*cols.values(), *valid.values(),
+                                      slots)} == {(ring.edge_rows,)}
+            assert set(valid) == set(cols) == set(gb.plan.columns)
+        fin = np.asarray(ring.tail_begin(body, buffers))
+        assert fin.shape == (len(gb.plan.specs) + 1, gb.capacity)
+        dev = apply_int_semantics(
+            gb.plan.specs, [fin[i][:n_keys] for i in range(len(fin) - 1)])
+        # the host tail
+        shadow = HostShadow(gb.plan, gb.comp_specs, n_keys)
+        for cols, valid, slots, sel in segs:
+            pick = (lambda a: a) if sel is None else (lambda a: a[sel])
+            shadow.fold({k: pick(v) for k, v in cols.items()}, pick(slots),
+                        {k: pick(v) for k, v in valid.items()})
+        assert shadow.n_rows == n_edge
+        host, act = gb.prefinalize_merge(
+            _Landed(body_np, gb._components_layout()), shadow, n_keys)
+        assert np.array_equal(fin[-1][:n_keys], act)
+        seen = 8 if edge in ("no_rows", "one_buffer", "two_buffers") \
+            else n_keys
+        assert (act[:seen] > 0).all() and (act[seen:] == 0).all()
+        for spec, d, h in zip(gb.plan.specs, dev, host):
+            if spec.kind in ("count", "hll", "min", "max"):
+                assert d.dtype == h.dtype
+                assert np.array_equal(d, h, equal_nan=True), spec.kind
+            elif spec.kind == "percentile_approx":
+                # the same bin for every key (bin centres lie 10 % apart);
+                # the value may differ in the last ulps of exp
+                assert np.array_equal(np.isnan(d), np.isnan(h))
+                ok = ~np.isnan(h)
+                assert np.array_equal(hist_bin_np(d[ok].astype(np.float32)),
+                                      hist_bin_np(h[ok].astype(np.float32)))
+                np.testing.assert_allclose(d[ok], h[ok], rtol=1e-5)
+            else:
+                np.testing.assert_allclose(d, h, rtol=1e-4, atol=1e-4,
+                                           err_msg=spec.kind)
+
+    @pytest.mark.parametrize("head", ["live_pane", "edge_rows"])
+    @pytest.mark.parametrize("sql", [SQL_INV, SQL_MM, SQL_PCT],
+                             ids=["sums", "minmax", "pct"])
+    def test_node_device_tail_against_host_tail(self, sql, head, monkeypatch):
+        """Two DABA nodes over the same batches, one held to the host tail
+        (its ring path answered `dyn`: shadow + pane merge + numpy, the
+        exact fallback). `live_pane`: each trigger is the last row
+        received, so the high edge is the live pane (`include_head`);
+        `edge_rows`: rows of its micro-batch follow it in its bucket, so
+        the high edge goes up as rows too. No shadow is built for a
+        trigger the device finished, and what it fetches is
+        (n_specs + 1) x capacity floats."""
+        import ekuiper_tpu.ops.prefinalize as pf
+
+        shadows = []
+
+        class CountedShadow(pf.HostShadow):
+            def __init__(self, *a, **kw):
+                shadows.append(1)
+                super().__init__(*a, **kw)
+
+        monkeypatch.setattr(pf, "HostShadow", CountedShadow)
+        node_d, got_d = mknode(sql, "daba")
+        node_h, got_h = mknode(sql, "daba")
+        node_h._ring_body_query = lambda *a: (None, "dyn")
+        heads, fetched = [], []
+        body_query = node_d._ring_body_query
+        node_d._ring_body_query = lambda body, include_head, b_hi: (
+            heads.append(include_head) or body_query(body, include_head,
+                                                     b_hi))
+        deliver = node_d._deliver_async
+
+        def record(kind, payload, *rest):
+            fetched.append((getattr(payload[0], "shape", None),
+                            getattr(payload[0], "dtype", None), payload[1]))
+            return deliver(kind, payload, *rest)
+        node_d._deliver_async = record
+        batches = spike_batches(at_end=head == "live_pane")
+        for b in batches:
+            node_d.process(b)
+        n_shadows_device = len(shadows)
+        for b in batches:
+            node_h.process(b)
+        node_d._drain_async_emits()
+        node_h._drain_async_emits()
+        trig_d, trig_h = per_trigger(got_d), per_trigger(got_h)
+        assert len(trig_d) >= 6
+        assert_parity(trig_d, trig_h)
+        n = len(trig_d)
+        assert node_h.sliding_tails == {"host": n}
+        assert node_d.sliding_tails["device"] >= n - 1, node_d.sliding_tails
+        assert sum(node_d.sliding_tails.values()) == n \
+            == sum(node_d.sliding_triggers.values())
+        assert node_d.sliding_tails["device"] == \
+            node_d.sliding_triggers.get("fast", 0) \
+            + node_d.sliding_triggers.get("flip", 0)
+        if head == "live_pane":
+            assert all(heads)
+        else:
+            assert not any(heads)
+        # no shadow but for the triggers the host finished
+        assert n_shadows_device == node_d.sliding_tails.get("host", 0)
+        assert len(shadows) == n_shadows_device + n
+        shape = (len(node_d.plan.specs) + 1, node_d.gb.capacity)
+        on_device = [f for f in fetched if f[2] is None]
+        assert len(on_device) == node_d.sliding_tails["device"]
+        assert all(f[:2] == (shape, np.float32) for f in on_device)
 
 
 class TestClockModes:
@@ -417,7 +649,7 @@ class TestRingGuardrails:
 
     def test_admission_prices_ring_sites(self):
         """QoS admission must price a DABA sliding rule's extra compile
-        surface (3 ring sites + components_dyn), not just the shared
+        surface (4 ring sites + components_dyn), not just the shared
         group-by sites — the signature budget would otherwise invert."""
         from ekuiper_tpu.observability import jitcert
 
@@ -425,7 +657,7 @@ class TestRingGuardrails:
         base = jitcert.estimate_plan_signatures(plan, 1, 128, 64)
         ring = jitcert.estimate_plan_signatures(plan, 1, 128, 64,
                                                 sliding_ring_slots=83)
-        assert ring == base + 4
+        assert ring == base + 5
 
     def test_rule_option_plumbs(self):
         from ekuiper_tpu.planner.planner import RuleDef, merged_options

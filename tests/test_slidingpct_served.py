@@ -6,8 +6,9 @@ holds what a plain reference says it holds (the semantics of
 stamped in (t - L, t], the percentile within the sketch's stated error of the
 exact order statistic) and what the host operator path answers; and the
 trigger's work is seen (stages `slide_edge`, `slide_advance`, `slide_query`,
-`slide_merge`; `kuiper_sliding_triggers_total`; the `kuiper/slide_query/*`
-scopes)."""
+`slide_merge`; `kuiper_sliding_triggers_total`, `kuiper_sliding_tail_total`;
+the `kuiper/slide_query/*` and `kuiper/slide_tail/*` scopes). Since PR 35 a
+trigger the ring's running partials served is finished on the device."""
 import json
 import math
 import time
@@ -228,6 +229,27 @@ def test_served_sliding_percentiles_against_the_reference(mock_clock, seed):
         assert fused.sliding_triggers == {
             "flip": 1, "dyn": moved_on,
             "fast": len(triggers) - 1 - moved_on}
+        # ... and by where the trigger was finished: the device for what
+        # the ring's program served, the host for the exact fallback
+        assert fused.sliding_tails == {
+            "device": len(triggers) - moved_on, "host": moved_on}
+        code, text = api.dispatch("GET", "/metrics", None, {})
+        by_tail = {
+            ln.split('tail="')[1].split('"')[0]: float(ln.rsplit(" ", 1)[1])
+            for ln in text.splitlines()
+            if ln.startswith("kuiper_sliding_tail_total{")
+            and f'rule="{rule_id}"' in ln}
+        assert by_tail == {k: float(v)
+                           for k, v in fused.sliding_tails.items()}
+        by_path = {
+            ln.split('path="')[1].split('"')[0]: float(ln.rsplit(" ", 1)[1])
+            for ln in text.splitlines()
+            if ln.startswith("kuiper_sliding_triggers_total{")
+            and f'rule="{rule_id}"' in ln}
+        assert sum(by_tail.values()) == sum(by_path.values()) \
+            == len(triggers)
+        assert by_tail["device"] == by_path["fast"] + by_path["flip"]
+        assert by_tail["host"] == by_path["dyn"] + by_path.get("edge", 0)
     finally:
         api.rules.stop_all()
 
@@ -411,6 +433,12 @@ def test_slide_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
                            for k, v in fused.sliding_triggers.items()}
         assert sum(by_path.values()) == n and by_path.get("dyn", 0) == 0
         assert by_path.get("flip", 0) >= 1 and by_path.get("fast", 0) >= 1
+        # every one of them finished on the device: the three stages are
+        # those of the device-tail path
+        assert fused.sliding_tails == {"device": n}
+        assert any(ln.startswith("kuiper_sliding_tail_total{")
+                   and f'rule="{rule_id}"' in ln and 'tail="device"' in ln
+                   and ln.endswith(f" {n}") for ln in text.splitlines())
 
         # ---- the rule's trace: the nested stages under `emit`, the
         # fused worker's two beside `fold` under the node's dispatch
@@ -434,8 +462,8 @@ def test_slide_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         names = _host_event_names(str(tmp_path))
         assert {"kuiper:slide_edge", "kuiper:slide_advance",
                 "kuiper:slide_query", "kuiper:slide_merge",
-                "kuiper:jit:query", "kuiper:jit:advance", "kuiper:emit",
-                "kuiper:fold"} <= names, sorted(names)
+                "kuiper:jit:query", "kuiper:jit:tail", "kuiper:jit:advance",
+                "kuiper:emit", "kuiper:fold"} <= names, sorted(names)
     finally:
         api.rules.stop_all()
 
@@ -471,6 +499,29 @@ def test_ring_query_program_name_and_scopes():
         assert scope in text, scope
     assert ring._query.rec.trace_name == "kuiper:jit:query"
     assert ring._advance.rec.trace_name == "kuiper:jit:advance"
+    # the tail is a program of its own: `query_impl` stays what it was
+    # (its roofline's bytes are reckoned for it alone), and the tail is
+    # found neither by `query_impl` nor by `fold` (the fold's roofline
+    # sums the programs so named) though it runs the fold's scatter
+    cols, valid, slots, n = ring.edge_buffers([])[0]
+    text = jax.jit(ring._tail_impl).lower(
+        jax.eval_shape(ring._query_impl, ring.init_state(), gb.init_state(),
+                       np.bool_(True), np.bool_(False), np.int32(0),
+                       np.zeros(QUERY_ADJ, np.int32),
+                       np.zeros(QUERY_ADJ, np.float32),
+                       np.zeros(QUERY_ADJ, np.bool_)),
+        {**cols, **{"__valid_" + k: v for k, v in valid.items()}}, slots,
+        np.int32(n)).as_text(debug_info=True)
+    head = next(ln for ln in text.splitlines() if ln.startswith("module @"))
+    name = head.split()[1]
+    assert name == "@jit__tail_impl", head
+    assert "query_impl" not in name and "fold" not in name
+    for scope in ("kuiper/slide_tail/edge_scatter",
+                  "kuiper/slide_tail/merge_hist", "kuiper/slide_tail/merge_n",
+                  "kuiper/slide_tail/merge_act", "kuiper/slide_tail/values",
+                  "kuiper/slide_tail/stack"):
+        assert scope in text, scope
+    assert ring._tail.rec.trace_name == "kuiper:jit:tail"
 
 
 # ------------------------------------- the trigger's host tail, piece by piece

@@ -122,7 +122,9 @@ def _synthetic_scrape() -> str:
             if pooled:
                 self.pool_depths = lambda: (1, 0)
             # kuiper_sliding_triggers_total renders from this attribute
-            self.sliding_triggers = {"fast": 2, "flip": 1}
+            self.sliding_triggers = {"fast": 2, "flip": 1, "dyn": 1}
+            # ... and kuiper_sliding_tail_total from this one
+            self.sliding_tails = {"device": 3, "host": 1}
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]

@@ -322,12 +322,19 @@ def _drive(kernels) -> None:
                 np.ones(ring_kernel.n_ring_panes, dtype=np.bool_))
             from ekuiper_tpu.ops.slidingring import QUERY_ADJ
 
-            adj = np.zeros(QUERY_ADJ, dtype=np.int32)
-            ring_kernel.query_begin(
-                ring, state, body_on=True, f_on=True, f_slot=0,
-                adj_slots=adj,
-                adj_weights=np.zeros(QUERY_ADJ, dtype=np.float32),
-                adj_mm=np.zeros(QUERY_ADJ, dtype=np.bool_)).get()
+            def tail(segs):
+                """A query and the tail over its result + `segs`."""
+                body = ring_kernel.query(
+                    ring, state, body_on=True, f_on=True, f_slot=0,
+                    adj_slots=np.zeros(QUERY_ADJ, dtype=np.int32),
+                    adj_weights=np.zeros(QUERY_ADJ, dtype=np.float32),
+                    adj_mm=np.zeros(QUERY_ADJ, dtype=np.bool_))
+                np.asarray(ring_kernel.tail_begin(
+                    body, ring_kernel.edge_buffers(segs)))
+
+            # an edge segment without masks and one with
+            masks = {c: np.ones(len(slots), dtype=np.bool_) for c in cols}
+            tail([(cols, {}, slots, None), (cols, masks, slots, None)])
             gb2.components_begin_dyn(
                 state, np.zeros(gb2.n_panes, dtype=np.bool_)).get()
             # capacity growth across a doubling: ring re-specialization
@@ -335,6 +342,7 @@ def _drive(kernels) -> None:
             state = gb2.grow(state, gb2.capacity * 2)
             ring = ring_kernel.grow(ring, gb2.capacity)
             ring = ring_kernel.advance(ring, state, 0, True, 1, False)
+            tail([])
             continue
         state = gb.init_state()
         cols, valid, slots, pane = feed(gb, with_masks=False,
