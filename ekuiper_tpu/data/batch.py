@@ -48,6 +48,11 @@ class ColumnBatch:
     # stamped at the source, carried through every hop so emit/sink nodes
     # can record true ingest→emit latency (observability/histogram.py)
     ingest_ms: Optional[int] = None
+    # the column set the source decoded this batch with (None: every
+    # column the payload bore). A shared source decodes the union of what
+    # its riders read (runtime/subtopo.py); a rider that joined after this
+    # batch was decoded, and reads more, must not take it for its own
+    decoded: Optional[frozenset] = None
 
     # unannotated -> a plain class attribute, not a dataclass field
     _SHARE_INIT_LOCK = _threading.Lock()
@@ -84,11 +89,31 @@ class ColumnBatch:
     def __len__(self) -> int:
         return self.n
 
+    def covers(self, columns) -> bool:
+        """Whether the source decoded at least `columns` (None: all of
+        them) for this batch."""
+        return self.decoded is None or (
+            columns is not None and columns <= self.decoded)
+
     def names(self) -> List[str]:
         return list(self.columns.keys())
 
     def column(self, name: str) -> np.ndarray:
         return self.columns[name]
+
+    def key_column(self, name: str) -> np.ndarray:
+        """Column `name` as a group key: an absent column is all null, and
+        a typed column's null cells (valid mask false) read None — never
+        the zero the decoder left in their place, which is a key of its
+        own (bidder 0 is not "no bidder")."""
+        col = self.columns.get(name)
+        if col is None:
+            return np.full(self.n, None, dtype=np.object_)
+        vm = self.valid.get(name)
+        if vm is not None and col.dtype != np.object_ and not vm.all():
+            col = col.astype(np.object_)
+            col[~vm] = None
+        return col
 
     def is_valid(self, name: str) -> np.ndarray:
         v = self.valid.get(name)
@@ -111,6 +136,7 @@ class ColumnBatch:
             timestamps=None if self.timestamps is None else self.timestamps[idx],
             emitter=self.emitter,
             ingest_ms=self.ingest_ms,
+            decoded=self.decoded,
         )
 
     def to_messages(self) -> List[Dict[str, Any]]:

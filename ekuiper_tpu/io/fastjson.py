@@ -113,22 +113,26 @@ def has_keytab() -> bool:
 
 def decode_columns(
     payloads: List[bytes], field_spec, shards: int = 1,
+    tally: Optional[Dict[str, int]] = None,
 ) -> Optional[Tuple[Dict[str, Any], Dict[str, Any], Any]]:
     """(columns, valid, bad) via the native decoder, or None to fall back.
     shards > 1 splits the GIL-free parse pass across that many native
     threads (contiguous payload slices into one shared allocation) —
-    output is byte-identical for any shard count."""
+    output is byte-identical for any shard count. A `tally` dict is given
+    the parse's own counts: `kept` / `skipped` (object members decoded
+    into a column of `field_spec` / stepped over because it names no such
+    column) and `bytes` (payload bytes read)."""
     mod = _load()
     if mod is None:
         return None
     try:
-        try:
-            return mod.decode(list(payloads), field_spec, int(shards))
-        except TypeError:
-            # stale prebuilt .so without the shard API
-            return mod.decode(list(payloads), field_spec)
+        cols, valid, bad, (kept, skipped, n_bytes) = mod.decode(
+            list(payloads), field_spec, int(shards))
     except mod.Fallback:
         return None
     except Exception as e:
         logger.warning("ekjsoncol decode error (%s); python fallback", e)
         return None
+    if tally is not None:
+        tally.update(kept=kept, skipped=skipped, bytes=n_bytes)
+    return cols, valid, bad

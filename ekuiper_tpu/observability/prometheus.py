@@ -193,6 +193,26 @@ def render(rule_registry) -> str:
             out.append(
                 f'kuiper_{mname}{{rule="{_esc(rule_id)}",'
                 f'op="{_esc(node.name)}"}} {depths[idx]}')
+    # what the native decoder met, by its own tallies (added once a
+    # micro-batch by the source): object members decoded into a column or
+    # stepped over because no attached rule reads them, and payload bytes
+    tally_rows = [(rule_id, node, node.decode_tally)
+                  for rule_id, node in rows
+                  if getattr(node, "decode_tally", None) is not None]
+    _family(out, "kuiper_source_decode_fields_total", "counter",
+            "JSON object members the native decoder met, by fate: kept "
+            "(decoded into a column) or skipped (no attached rule reads it)")
+    for rule_id, node, tally in tally_rows:
+        for fate in ("kept", "skipped"):
+            out.append(
+                f'kuiper_source_decode_fields_total{{rule="{_esc(rule_id)}",'
+                f'op="{_esc(node.name)}",fate="{fate}"}} {tally[fate]}')
+    _family(out, "kuiper_source_decode_bytes_total", "counter",
+            "payload bytes the native decoder read")
+    for rule_id, node, tally in tally_rows:
+        out.append(
+            f'kuiper_source_decode_bytes_total{{rule="{_esc(rule_id)}",'
+            f'op="{_esc(node.name)}"}} {tally["bytes"]}')
     # shared pane folds (runtime/nodes_sharedfold.py): pool-level gauges —
     # members per store and the fold-dedup ratio (1 - folds run / folds N
     # private rules would have run). The store node's own op metrics (incl.

@@ -723,8 +723,11 @@ def _subtopo_spec(stream_name: str, src_name: str, opts, store,
             decode_shards=opts.decode_shards,
             ring_depth=opts.ingest_ring_depth,
             prep_upload=opts.ingest_prep_upload,
-            # private pipeline: prune at decode. Shared pipelines must stay
-            # unpruned (other riders need other columns) — see the entry.
+            # private pipeline: prune at decode, to this rule's columns.
+            # A shared pipeline is built unpruned and decodes the union of
+            # what its riders read: each hands its set over on attach
+            # (SrcSubTopo.attach -> SourceNode.set_decode_columns), the
+            # rider's own projection stays in its entry.
             project_columns=(None if opts.share_source and opts.qos == 0
                              else project_columns),
         )
@@ -1407,4 +1410,39 @@ def explain(rule: RuleDef, store) -> Dict[str, Any]:
         if isinstance(out.get("expressions"), dict):
             out["expressions"]["relational_error"] = str(exc)
     take_expr_fallbacks()  # drop probe-recorded notes (explain is read-only)
+    try:
+        out["source_columns"] = _explain_source_columns(stmt, opts, store)
+    except Exception as exc:  # explain must never fail on the probe
+        out["source_columns"] = {"error": str(exc)}
+    return out
+
+
+def _explain_source_columns(stmt, opts, store) -> Dict[str, Any]:
+    """Per stream of the statement: the columns this rule reads, and the
+    columns the stream's source pipeline decodes — for a shared pipeline
+    that is live, the union over the rules attached to it right now; else
+    what this rule alone would make it decode. "*" = every column."""
+    from ..runtime import subtopo as subtopo_pool
+    from .optimizer import referenced_columns
+
+    needed = referenced_columns(stmt)
+    lookups = {j.table.name for j in stmt.joins
+               if _is_lookup_table(j.table.name, store)}
+    tables = list(stmt.sources) + [j.table for j in stmt.joins
+                                   if j.table.name not in lookups]
+    multi = len(tables) > 1 or bool(stmt.joins)
+    shared = bool(opts.share_source and opts.qos == 0)
+    out: Dict[str, Any] = {}
+    for tbl in tables:
+        key, _, stream = _subtopo_spec(
+            tbl.name, tbl.ref_name if multi else tbl.name, opts, store)
+        reads = _with_ts_field(needed, stream, opts)
+        mine = "*" if reads is None else sorted(reads)
+        live = subtopo_pool.peek(key) if shared else None
+        out[tbl.name] = {
+            "pipeline": "shared" if shared else "private",
+            "reads": mine,
+            "decoded": (live.source.decoded_columns() if live is not None
+                        else mine),
+        }
     return out

@@ -34,6 +34,7 @@ the inline path produces — the pool stays invisible downstream.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time as _time
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -357,6 +358,15 @@ def pad_slots_for_device(slots, mb: int, u16: bool, sharding=None):
     return _put(s.astype(np.uint16 if u16 else np.int32), sharding)
 
 
+def key_encode_stage(stats, rows: int):
+    """The `key_encode` stage of `stats` (nested in `upload`): the group
+    key's slot encode of one micro-batch, wherever it runs. Without a
+    StatManager (a bare ctx in a test) nothing is timed."""
+    if stats is None:
+        return contextlib.nullcontext()
+    return stats.stage("key_encode", rows, within="upload")
+
+
 class IngestPrepCtx:
     """Shared ingest prep + the pipelined upload stage.
 
@@ -409,22 +419,19 @@ class IngestPrepCtx:
         self.n_precomputed_cols = 0
 
     # ----------------------------------------------------------- encoding
-    def encode(self, batch, key_name: str):
+    def encode(self, batch, key_name: str, stats=None):
         """(slots int32, n_keys, kt) for `key_name` over `batch`, computed
-        once per batch across all consumers."""
+        once per batch across all consumers — by whichever of them asks
+        first (the pool's drainer, else a fused worker), whose `stats`
+        times it as the `key_encode` stage inside its `upload`."""
         def factory():
-            import numpy as np
-
             from ..ops.keytable import KeyTable
 
-            with self.lock:
+            with self.lock, key_encode_stage(stats, batch.n):
                 kt = self.key_tables.get(key_name)
                 if kt is None:
                     kt = self.key_tables[key_name] = KeyTable()
-                col = batch.columns.get(key_name)
-                if col is None:
-                    col = np.full(batch.n, None, dtype=np.object_)
-                slots, _ = kt.encode_column(col)
+                slots, _ = kt.encode_column(batch.key_column(key_name))
                 return slots, kt.n_keys, kt
 
         return batch.share(("slots", key_name), factory)
@@ -468,10 +475,11 @@ class IngestPrepCtx:
             if fn not in self._tier_hooks:
                 self._tier_hooks.append(fn)
 
-    def precompute(self, batch) -> int:
+    def precompute(self, batch, stats=None) -> int:
         """Build padded device inputs for `batch` under the fused node's
         share keys. Returns the number of device arrays created. Failures
-        are non-fatal: the fused node rebuilds anything missing inline."""
+        are non-fatal: the fused node rebuilds anything missing inline.
+        `stats` (the caller's StatManager) times the key encode."""
         import numpy as np
 
         with self.lock:
@@ -505,8 +513,12 @@ class IngestPrepCtx:
                 # (fold's device-input contract); source flushes are
                 # micro-batch aligned so this is the rare tail only
                 continue
+            if key_name is not None and not batch.covers({key_name}):
+                # decoded before the rider that groups by this column
+                # attached: no key to encode, and the rider turns it away
+                continue
             if key_name is not None:
-                slots, n_keys, kt = self.encode(batch, key_name)
+                slots, n_keys, kt = self.encode(batch, key_name, stats)
                 from ..ops.groupby import slot_dtype
 
                 with self.lock:

@@ -35,6 +35,7 @@ from ..observability.tracer import Tracer
 from ..utils import timex
 from ..utils.infra import logger
 from .events import EOF, PreTrigger, Trigger
+from .ingest import key_encode_stage
 from .node import _NO_OVERRIDE, Node, _emit_ctx, _stamp_item
 
 
@@ -632,9 +633,12 @@ class FusedWindowAggNode(Node):
         now = timex.now_ms()
         interval = self._tick_interval()
         next_end = timex.align_to_window(now + 1, interval)
+        # the trigger carries the SCHEDULED boundary, not the fire time: a
+        # real clock calls back with the time it woke at, and window_end()
+        # would then lie off the grid by the timer's lateness
         self._timer = timex.after(
-            next_end - now, lambda ts: self.put_control(Trigger(ts=ts))
-        )
+            next_end - now,
+            lambda ts, end=next_end: self.put_control(Trigger(ts=end)))
         if self._prefinalize_ok:
             # two chances per boundary: a pre-issue at 2x lead, and one at
             # 1x lead that on_pre_trigger skips when the first has landed
@@ -711,7 +715,7 @@ class FusedWindowAggNode(Node):
             self._shared_slots_ok = False
             return None
         try:
-            slots, n_keys, nkt = ctx.encode(sub, key_name)
+            slots, n_keys, nkt = ctx.encode(sub, key_name, self.stats)
         except Exception as exc:
             logger.debug("%s: shared key encode failed (%s) — self-encoding",
                          self.name, exc)
@@ -724,9 +728,11 @@ class FusedWindowAggNode(Node):
                 return None
         self._shared_nkt = nkt
         if self.kt.n_keys < n_keys:
-            new = np.array(nkt.keys_slice(self.kt.n_keys, n_keys),
-                           dtype=np.object_)
-            _, grew = self.kt.encode_column(new)
+            # mirror the neutral table's new keys into our own
+            with key_encode_stage(self.stats, n_keys - self.kt.n_keys):
+                new = np.array(nkt.keys_slice(self.kt.n_keys, n_keys),
+                               dtype=np.object_)
+                _, grew = self.kt.encode_column(new)
             if grew:
                 self.state = self.gb.grow(self.state, self.kt.capacity)
         if self.kt.n_keys < n_keys:
@@ -888,17 +894,13 @@ class FusedWindowAggNode(Node):
     def _build_kernel_inputs(self, sub: ColumnBatch):
         """Encode group keys + materialize the kernel's numeric columns and
         validity masks for `sub`. Returns (cols, valid, slots)."""
-        key_cols = []
-        for d in self.dims:
-            col = sub.columns.get(d.name)
-            if col is None:
-                col = np.full(sub.n, None, dtype=np.object_)
-            key_cols.append(col)
+        key_cols = [sub.key_column(d.name) for d in self.dims]
         if key_cols:
             slots = (self._shared_encode(sub)
                      if len(self.dims) == 1 else None)
             if slots is None:
-                slots, grew = self.kt.encode_multi(key_cols)
+                with key_encode_stage(self.stats, sub.n):
+                    slots, grew = self.kt.encode_multi(key_cols)
                 if grew:
                     self.state = self.gb.grow(self.state, self.kt.capacity)
         else:

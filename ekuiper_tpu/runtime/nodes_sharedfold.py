@@ -42,6 +42,7 @@ from ..ops.panestore import PaneStore, build_value_columns, spec_map_into
 from ..utils import timex
 from ..utils.infra import logger
 from .events import EOF, Trigger, Watermark
+from .ingest import key_encode_stage
 from .node import Node
 
 
@@ -418,7 +419,9 @@ class SharedFoldNode(Node):
             else:
                 self.broadcast(item)
                 return
-        if item.n == 0:
+        if item.n == 0 or not item.covers(None):
+            # (a micro-batch the shared source decoded for narrower riders
+            # before this store attached: the store reads every column)
             return
         if item.shared_ctx is None and self.prep_ctx is not None:
             item.ensure_share_state()
@@ -539,13 +542,9 @@ class SharedFoldNode(Node):
             slots = self._shared_encode(sub)
             if slots is not None:
                 return slots
-        key_cols = []
-        for name in self.dims:
-            col = sub.columns.get(name)
-            if col is None:
-                col = np.full(sub.n, None, dtype=np.object_)
-            key_cols.append(col)
-        slots, _ = kt.encode_multi(key_cols)
+        key_cols = [sub.key_column(name) for name in self.dims]
+        with key_encode_stage(self.stats, sub.n):
+            slots, _ = kt.encode_multi(key_cols)
         return slots
 
     def _shared_encode(self, sub: ColumnBatch) -> Optional[np.ndarray]:
@@ -559,7 +558,7 @@ class SharedFoldNode(Node):
             return None
         kt = self.store.kt
         try:
-            slots, n_keys, nkt = ctx.encode(sub, self.dims[0])
+            slots, n_keys, nkt = ctx.encode(sub, self.dims[0], self.stats)
         except Exception as exc:
             logger.debug("%s: shared key encode failed (%s) — self-encoding",
                          self.name, exc)
@@ -572,9 +571,10 @@ class SharedFoldNode(Node):
                 return None
         self._shared_nkt = nkt
         if kt.n_keys < n_keys:
-            new = np.array(nkt.keys_slice(kt.n_keys, n_keys),
-                           dtype=np.object_)
-            kt.encode_column(new)
+            with key_encode_stage(self.stats, n_keys - kt.n_keys):
+                new = np.array(nkt.keys_slice(kt.n_keys, n_keys),
+                               dtype=np.object_)
+                kt.encode_column(new)
         if kt.n_keys < n_keys:
             self._shared_slots_ok = False  # diverged: self-encode from now
             return None

@@ -11,7 +11,7 @@ per-tuple goroutine hops into whole-batch device work.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 from ..data import cast
 from ..data.batch import ColumnBatch, from_tuples
@@ -21,6 +21,18 @@ from ..utils import timex
 from ..utils.infra import logger
 from .events import EOF
 from .node import Node
+
+
+class _DecodePlan(NamedTuple):
+    """What one micro-batch is decoded with: the column set, the declared
+    schema cut to it, and the native decoder's field spec (None: the
+    stream is not natively decodable). A hand-over reads the node's
+    current plan under the pending lock and every job carries it, so a
+    micro-batch is never decoded with two plans."""
+
+    project: Optional[frozenset]  # None = every declared column
+    schema: Optional[Schema]
+    fast_spec: Optional[tuple]
 
 
 class SourceNode(Node):
@@ -45,23 +57,10 @@ class SourceNode(Node):
         super().__init__(name, op_type="source", buffer_length=buffer_length)
         self.connector = connector
         self.converter = converter
-        self.schema = schema
         self.timestamp_field = timestamp_field
         self.strict = cast.STRICT if strict_validation else cast.CONVERT_ALL
         self.micro_batch_rows = micro_batch_rows
         self.linger_ms = linger_ms
-        self.project_columns = (set(project_columns)
-                                if project_columns is not None else None)
-        if self.project_columns is not None and self.schema is not None \
-                and not self.schema.schemaless:
-            # restrict the declared schema too: from_tuples materializes a
-            # column per schema field, so pruning must reach it or typed
-            # streams would re-grow zero-filled columns at batch build
-            from ..data.types import Schema
-
-            self.schema = Schema(fields=[
-                f for f in self.schema.fields
-                if f.name in self.project_columns])
         self.emit_batches = emit_batches
         # batch mode buffers RAW decoded messages; schema coercion +
         # event-time extraction run COLUMNAR at flush (data/batch.py
@@ -71,8 +70,11 @@ class SourceNode(Node):
         self._pending_ts: List[int] = []
         # native fast path: JSON bytes payloads for a fully-scalar typed
         # schema buffer RAW and decode straight to columns in C at flush
-        # (io/fastjson.py over native/jsoncol.cpp)
-        self._fast_spec = None
+        # (io/fastjson.py over native/jsoncol.cpp). Eligibility is the
+        # DECLARED schema's; which of its columns a micro-batch decodes
+        # is the plan's (set_decode_columns)
+        self._declared_schema = schema
+        self._declared_spec = None
         self._pending_raw: List[bytes] = []
         self._pending_raw_ts: List[int] = []
         if converter is not None and schema is not None:
@@ -83,18 +85,23 @@ class SourceNode(Node):
                     self.strict != cast.STRICT:
                 # STRICT streams keep the python cast path — the C decoder
                 # hard-codes CONVERT_ALL coercion
-                spec = schema_field_spec(self.schema)
+                spec = schema_field_spec(schema)
                 if spec is not None and timestamp_field:
                     # event-time via the fast path needs an exact int64
                     # column; other shapes keep the python extractor
-                    ftypes = {f.name: f.type for f in self.schema.fields}
+                    ftypes = {f.name: f.type for f in schema.fields}
                     from ..data.types import DataType
 
                     if ftypes.get(timestamp_field) != DataType.BIGINT:
                         spec = None
-                self._fast_spec = spec
+                self._declared_spec = spec
                 if spec is not None:
                     ensure_native()
+        # the native decoder's own counts, added once a micro-batch:
+        # object members decoded into a column / stepped over, payload bytes
+        self.decode_tally: Dict[str, int] = {
+            "kept": 0, "skipped": 0, "bytes": 0}
+        self._plan = self._make_plan(project_columns)
         self._pending_lock = threading.Lock()
         self._linger_timer = None
         # sharded ingest pipeline (runtime/ingest.py): flush-time decode
@@ -121,6 +128,55 @@ class SourceNode(Node):
             from .ingest import IngestPrepCtx
 
             self.prep_ctx = IngestPrepCtx()
+
+    # ------------------------------------------------------- decoded columns
+    def _make_plan(self, project_columns) -> _DecodePlan:
+        project = (None if project_columns is None
+                   else frozenset(project_columns))
+        schema, spec = self._declared_schema, self._declared_spec
+        if project is not None:
+            if schema is not None and not schema.schemaless:
+                # restrict the declared schema too: from_tuples
+                # materializes a column per schema field, so pruning must
+                # reach it or typed streams would re-grow zero-filled
+                # columns at batch build
+                schema = Schema(fields=[f for f in schema.fields
+                                        if f.name in project])
+            if spec is not None:
+                spec = tuple(f for f in spec if f[0] in project)
+        return _DecodePlan(project, schema, spec)
+
+    def set_decode_columns(self, project_columns) -> None:
+        """Decode `project_columns` (None: every declared column) from the
+        next micro-batch handed over on: pending rows are still raw, so
+        swapping the plan under the pending lock is a flush edge. A shared
+        subtopo calls this with the union of what its riders read
+        (runtime/subtopo.py); a private source keeps its rule's set."""
+        plan = self._make_plan(project_columns)
+        with self._pending_lock:
+            self._plan = plan
+
+    @property
+    def schema(self) -> Optional[Schema]:
+        return self._plan.schema
+
+    @property
+    def project_columns(self) -> Optional[frozenset]:
+        return self._plan.project
+
+    @property
+    def _fast_spec(self) -> Optional[tuple]:
+        return self._plan.fast_spec
+
+    def decoded_columns(self):
+        """The columns a micro-batch is decoded with now, for the status
+        and /explain: sorted names, or "*" (everything the payload bears)."""
+        plan = self._plan
+        if plan.project is None:
+            if plan.schema is None or plan.schema.schemaless:
+                return "*"
+            return sorted(f.name for f in plan.schema.fields)
+        return sorted(plan.project)
 
     # ------------------------------------------------------------------ ingest
     def on_open(self) -> None:
@@ -301,9 +357,10 @@ class SourceNode(Node):
     def _preprocess(self, t: Tuple) -> Optional[Tuple]:
         """Schema validation/coercion + event-time extraction
         (reference: internal/topo/operator/preprocessor.go)."""
-        if self.schema is not None and not self.schema.schemaless:
+        plan = self._plan  # one plan a tuple, whatever a rider does meanwhile
+        if plan.schema is not None and not plan.schema.schemaless:
             msg = {}
-            for f in self.schema.fields:
+            for f in plan.schema.fields:
                 if f.name in t.message:
                     try:
                         msg[f.name] = cast.to_typed(t.message[f.name], f, self.strict)
@@ -323,11 +380,11 @@ class SourceNode(Node):
             except cast.CastError as exc:
                 self.stats.inc_exception(str(exc))
                 return None
-        if self.project_columns is not None:
+        if plan.project is not None:
             # column pruning (planner/optimizer.py): drop unreferenced
             # fields before batching — smaller batches, tuples, uploads
             t.message = {k: v for k, v in t.message.items()
-                         if k in self.project_columns}
+                         if k in plan.project}
         return t
 
     # ------------------------------------------------------------------ state
@@ -403,9 +460,9 @@ class SourceNode(Node):
                     self._pending_raw_ts = rtss[cut:]
                     raws, rtss = raws[:cut], rtss[:cut]
                 if msgs:
-                    jobs.append(("msgs", msgs, tss))
+                    jobs.append(("msgs", msgs, tss, self._plan))
                 if raws:
-                    jobs.append(("raw", raws, rtss))
+                    jobs.append(("raw", raws, rtss, self._plan))
         n_rows = sum(len(job[1]) for job in jobs)
         if self.decode_pool_size <= 0:
             return jobs, n_rows
@@ -441,7 +498,7 @@ class SourceNode(Node):
         balance stays observable per node."""
         with self.stats.stage("upload", batch.n) as st:
             # nothing registered to build: no row of the stage for it
-            st.counted = bool(self.prep_ctx.precompute(batch))
+            st.counted = bool(self.prep_ctx.precompute(batch, self.stats))
 
     def pool_depths(self):
         """(ring occupancy, decode queue depth) for the Prometheus gauges;
@@ -490,25 +547,18 @@ class SourceNode(Node):
             self.emit(batch, count=batch.n)
 
     def _decode_job(self, job) -> Optional[ColumnBatch]:
-        """One decode unit: ("raw", payloads, tss) | ("msgs", msgs, tss)
-        -> ColumnBatch | None. Runs on pool workers — touches only
-        immutable config, the converter, and the (locked) StatManager."""
-        from ..data.batch import from_messages
-
-        kind, items, tss = job
+        """One decode unit: ("raw", payloads, tss, plan) | ("msgs", msgs,
+        tss, plan) -> ColumnBatch | None, decoded with the plan the
+        hand-over took. Runs on pool workers — touches only immutable
+        config, the converter, and the (locked) StatManager."""
+        kind, items, tss, plan = job
         with self.stats.stage("decode", len(items)):
             if kind == "raw":
-                batch = self._decode_raw_to_batch(items, tss)
+                batch = self._decode_raw_to_batch(items, tss, plan)
             else:
-                batch, n_drop = from_messages(
-                    items, tss, schema=self.schema, emitter=self.name,
-                    strict=self.strict,
-                    timestamp_field=self.timestamp_field,
-                    on_error=self.stats.inc_exception,
-                    project=self.project_columns)
-                if n_drop:
-                    logger.debug("source %s dropped %d rows at columnarize",
-                                 self.name, n_drop)
+                batch = self._messages_to_batch(items, tss, plan)
+        if batch is not None:
+            batch.decoded = plan.project
         if batch is not None and batch.ingest_ms is None and tss:
             # e2e provenance: the batch speaks for its OLDEST row (arrival
             # order == tss order), so micro-batch linger and every later
@@ -522,19 +572,35 @@ class SourceNode(Node):
             batch.shared_ctx = self.prep_ctx
         return batch
 
-    def _decode_raw_to_batch(self, raws: List[bytes],
-                             rtss: List[int]) -> Optional[ColumnBatch]:
+    def _messages_to_batch(self, msgs, tss,
+                           plan: _DecodePlan) -> Optional[ColumnBatch]:
+        from ..data.batch import from_messages
+
+        batch, n_drop = from_messages(
+            msgs, tss, schema=plan.schema, emitter=self.name,
+            strict=self.strict, timestamp_field=self.timestamp_field,
+            on_error=self.stats.inc_exception, project=plan.project)
+        if n_drop:
+            logger.debug("source %s dropped %d rows at columnarize",
+                         self.name, n_drop)
+        return batch
+
+    def _decode_raw_to_batch(self, raws: List[bytes], rtss: List[int],
+                             plan: _DecodePlan) -> Optional[ColumnBatch]:
         """Native columnar decode of buffered raw JSON payloads
         (io/fastjson.py); python fallback preserves row↔timestamp pairing."""
         import numpy as np
 
         from ..io.fastjson import decode_columns
 
-        out = decode_columns(raws, self._fast_spec,
-                             shards=self._decode_shards)
+        tally: Dict[str, int] = {}
+        out = decode_columns(raws, plan.fast_spec,
+                             shards=self._decode_shards, tally=tally)
+        if tally:
+            with self._pending_lock:
+                for k, v in tally.items():
+                    self.decode_tally[k] += v
         if out is None:
-            from ..data.batch import from_messages
-
             msgs: List[Dict[str, Any]] = []
             tss: List[int] = []
             for p, t in zip(raws, rtss):
@@ -554,12 +620,7 @@ class SourceNode(Node):
                             tss.append(t)
             if not msgs:
                 return None
-            batch, _ = from_messages(
-                msgs, tss, schema=self.schema, emitter=self.name,
-                strict=self.strict, timestamp_field=self.timestamp_field,
-                on_error=self.stats.inc_exception,
-                project=self.project_columns)
-            return batch
+            return self._messages_to_batch(msgs, tss, plan)
         cols, valid, bad = out
         keep = ~np.asarray(bad, dtype=np.bool_)
         n_bad = len(raws) - int(keep.sum())

@@ -161,9 +161,11 @@ class WindowNode(Node):
         interval = self._tick_interval()
         # epoch-aligned boundaries like the reference's getAlignedWindowEndTime
         next_end = timex.align_to_window(now + 1, interval)
+        # the trigger carries the scheduled boundary: a real clock calls
+        # back with the time it woke at, which lies past the grid
         self._timer = timex.after(
-            next_end - now, lambda ts: self.put_control(Trigger(ts=ts))
-        )
+            next_end - now,
+            lambda ts, end=next_end: self.put_control(Trigger(ts=end)))
 
     # --------------------------------------------------------------- ingest
     def process(self, item: Any) -> None:

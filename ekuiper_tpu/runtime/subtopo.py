@@ -8,6 +8,14 @@ broadcasts ColumnBatches to each attached rule's entry node. Attach/detach
 are refcounted; the pipeline opens on the first attach and closes when the
 last rule detaches.
 
+The source decodes the UNION of the columns its attached riders read: each
+rider hands over its pruning set on attach (`project_columns`; None = every
+column, e.g. `SELECT *` or a rider that does not say), the subtopo keeps the
+union and gives it to its SourceNode, which takes it up at its next
+hand-over (one micro-batch, one column set). A rider that widens the union
+is handed only micro-batches decoded with its columns (`ColumnBatch.covers`);
+a detach narrows the union to what the riders that stay read.
+
 Sharing is restricted to qos=0 rules (the planner enforces it): checkpoint
 barriers are injected at sources, and a shared source cannot carry
 rule-private barriers. This matches the reference's default deployments —
@@ -87,6 +95,9 @@ class SrcSubTopo:
             n.stats.rule_id = "__shared__"
         self._lock = threading.RLock()
         self._attached: Dict[str, Tuple[Node, Any]] = {}
+        # rider id -> the columns it reads (None = all): what the source
+        # decodes is their union
+        self._reads: Dict[str, Optional[frozenset]] = {}
         self._opened = False
         self._closed = False
         # adopt the source's prep ctx when it has one (prep-enabled source:
@@ -111,6 +122,21 @@ class SrcSubTopo:
         with self._lock:
             return len(self._attached)
 
+    def read_union(self) -> Optional[frozenset]:
+        """The union of what the attached riders read (None = every
+        column): what the source is told to decode."""
+        with self._lock:
+            reads = list(self._reads.values())
+        if any(r is None for r in reads):
+            return None
+        return frozenset().union(*reads)
+
+    def _decode_union(self) -> None:
+        """Hand the riders' union to the source (under self._lock)."""
+        set_columns = getattr(self.source, "set_decode_columns", None)
+        if set_columns is not None and self._reads:
+            set_columns(self.read_union())
+
     def attach(self, rule_id: str, entry: Node, topo: Any) -> bool:
         """Returns False when this instance already closed (caller resolves
         a fresh one from the pool)."""
@@ -120,6 +146,13 @@ class SrcSubTopo:
             if rule_id in self._attached:
                 raise ValueError(f"rule {rule_id} already attached to {self.key}")
             self._attached[rule_id] = (entry, topo)
+            # widen the decode BEFORE the entry joins the fan-out: the
+            # micro-batches still in flight are narrower and the entry
+            # turns them away (SharedEntryNode.process)
+            reads = getattr(entry, "project_columns", None)
+            self._reads[rule_id] = (None if reads is None
+                                    else frozenset(reads))
+            self._decode_union()
             entry.prep_ctx = self.prep_ctx  # shared fan-out ingest prep
             # plan-time upload specs stashed on the entry reach the shared
             # ctx here (the subtopo instance resolves only at open)
@@ -145,6 +178,8 @@ class SrcSubTopo:
                 return
             entry, _ = got
             self.tail.outputs = [o for o in self.tail.outputs if o is not entry]
+            self._reads.pop(rule_id, None)
+            self._decode_union()
             if not self._attached and self._opened:
                 # mark closed + evict BEFORE releasing the lock: a concurrent
                 # attach on this instance now returns False, and a concurrent
@@ -168,9 +203,10 @@ class SharedEntryNode(Node):
     the rule its own queue (backpressure isolation — one slow rule drops its
     own oldest items, reference subtopo semantics) and its own stats.
 
-    Column pruning happens HERE for shared sources: the pooled pipeline
-    serves rules with different column needs, so each rule prunes its own
-    copy of the stream (planner/optimizer.py)."""
+    The pooled source decodes the union of its riders' columns; each rule
+    prunes its own copy of the stream down to its own set HERE
+    (planner/optimizer.py), and turns away a micro-batch decoded before it
+    attached with fewer columns than it reads."""
 
     def __init__(self, name: str, project_columns=None, **kw) -> None:
         super().__init__(name, op_type="op", **kw)
@@ -188,9 +224,12 @@ class SharedEntryNode(Node):
         cols = self.project_columns
         from ..data.batch import ColumnBatch
 
-        if isinstance(item, ColumnBatch) and item.shared_ctx is None:
-            item.ensure_share_state()  # BEFORE any pruned copy forks it
-            item.shared_ctx = self.prep_ctx
+        if isinstance(item, ColumnBatch):
+            if not item.covers(cols):
+                return  # rows from before this rider's attach
+            if item.shared_ctx is None:
+                item.ensure_share_state()  # BEFORE any pruned copy forks it
+                item.shared_ctx = self.prep_ctx
         if cols is not None:
             from ..data.rows import Tuple as Row
 
@@ -209,6 +248,7 @@ class SharedEntryNode(Node):
                     shared_ctx=item.shared_ctx,
                     share_state=item.share_state,
                     ingest_ms=item.ingest_ms,
+                    decoded=item.decoded,
                 )
             elif isinstance(item, Row) and not (
                 set(item.message) <= cols
@@ -250,6 +290,12 @@ def get_or_create(key: str, builder: Callable[[], List[Node]]) -> SrcSubTopo:
             _pool[key] = candidate
             return candidate
     return st  # lost the race; unopened candidate is garbage-collected
+
+
+def peek(key: str) -> Optional[SrcSubTopo]:
+    """The live pooled pipeline under `key`, or None (never builds one)."""
+    with _pool_lock:
+        return _pool.get(key)
 
 
 def _pool_remove(key: str, subtopo: SrcSubTopo) -> None:

@@ -219,6 +219,16 @@ class Topo:
             if srcs:
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_emit_sources"] = dict(srcs)
+        # the columns each source decodes now (a shared source: the union
+        # of what its riders read)
+        sources = list(self.sources) + [
+            st.source for st, _ in self._live_shared
+            if getattr(st, "source", None) is not None]
+        for n in sources:
+            cols = getattr(n, "decoded_columns", None)
+            if cols is not None:
+                out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
+                    "_decoded_columns"] = cols()
         # rule-level SLO summary: the ingest→emit distribution percentiles
         out["e2e_latency_ms"] = self.e2e_hist.snapshot()
         # ... and its engine-side phases per window boundary, ms

@@ -8,7 +8,11 @@
 //
 //   decode(payloads: list[bytes], fields: ((name, type), ...), shards=1)
 //     -> (columns: dict[str, ndarray], valid: dict[str, ndarray],
-//         bad: ndarray[bool])
+//         bad: ndarray[bool], (fields_kept, fields_skipped, bytes))
+//   the last: the object members the parse met whose key is in `fields` (a
+//   value was decoded) or is not (stepped over by skip_value: no value, no
+//   StrRef, no Python object is ever built for it), and the payload bytes
+//   it read.
 //
 // shards > 1 runs the GIL-free parse pass over `shards` contiguous slices
 // of the payload list on native threads concurrently. Every shard writes
@@ -307,12 +311,20 @@ struct Arena {
   }
 };
 
+// What one parse pass met: members decoded into a column, members stepped
+// over because no field of the spec bears their key.
+struct Tally {
+  uint64_t kept = 0;
+  uint64_t skipped = 0;
+};
+
 // Parse one object payload into row r of the field buffers.
 // Returns: 0 ok, 1 bad row (cast/shape error), 2 batch fallback.
 // Runs WITHOUT the GIL: string values are recorded as StrRefs (payload
 // spans or arena copies) and materialized in a later GIL'd intern pass.
 int parse_row(Parser& ps, std::vector<Field>& fields, npy_intp r,
-              std::vector<StrRef>& strs, Arena& arena, std::string& tmp) {
+              std::vector<StrRef>& strs, Arena& arena, std::string& tmp,
+              Tally& tally) {
   ps.ws();
   if (ps.p < ps.end && *ps.p == '[')
     return 2;  // array payload: rows-per-payload is the python path's job
@@ -355,8 +367,10 @@ int parse_row(Parser& ps, std::vector<Field>& fields, npy_intp r,
       }
     }
     if (f == nullptr) {
+      tally.skipped++;
       if (!ps.skip_value()) return 1;
     } else {
+      tally.kept++;
       ps.ws();
       if (ps.p >= ps.end) return 1;
       char c = *ps.p;
@@ -509,6 +523,7 @@ struct Shard {
   npy_intp end = 0;
   std::vector<StrRef> strs;
   Arena arena;
+  Tally tally;
   bool fallback = false;
 };
 
@@ -522,7 +537,7 @@ void parse_shard(Shard& sh,
   for (npy_intp r = sh.begin; r < sh.end; r++) {
     Parser ps(bufs[(size_t)r].first,
               bufs[(size_t)r].first + bufs[(size_t)r].second);
-    int rc = parse_row(ps, fields, r, sh.strs, sh.arena, tmp);
+    int rc = parse_row(ps, fields, r, sh.strs, sh.arena, tmp, sh.tally);
     if (rc == 2) {
       sh.fallback = true;
       break;
@@ -708,7 +723,20 @@ PyObject* jc_decode(PyObject*, PyObject* args) {
       f.obj[sr.row] = u;
     }
   }
-  PyObject* out = PyTuple_Pack(3, cols, valids, bad_arr);
+  Tally all;
+  unsigned long long n_bytes = 0;
+  for (auto& sh : shards) {
+    all.kept += sh.tally.kept;
+    all.skipped += sh.tally.skipped;
+  }
+  for (auto& b : bufs) n_bytes += (unsigned long long)b.second;
+  PyObject* out = nullptr;
+  PyObject* tally = Py_BuildValue("(KKK)", (unsigned long long)all.kept,
+                                  (unsigned long long)all.skipped, n_bytes);
+  if (tally != nullptr) {
+    out = PyTuple_Pack(4, cols, valids, bad_arr, tally);
+    Py_DECREF(tally);
+  }
   Py_DECREF(cols);
   Py_DECREF(valids);
   Py_DECREF(bad_arr);
@@ -910,7 +938,8 @@ PyObject* kt_encode(PyObject*, PyObject* args) {
 
 PyMethodDef methods[] = {
     {"decode", jc_decode, METH_VARARGS,
-     "decode(payloads, fields, shards=1) -> (columns, valid, bad)"},
+     "decode(payloads, fields, shards=1) -> (columns, valid, bad, "
+     "(fields_kept, fields_skipped, bytes))"},
     {"keytab_new", kt_new, METH_NOARGS,
      "keytab_new() -> persistent key-slot table capsule"},
     {"keytab_encode", kt_encode, METH_VARARGS,

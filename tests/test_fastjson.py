@@ -353,3 +353,76 @@ class TestReviewRegressions:
         got = cols["deviceId"].tolist()
         assert got[:5000] == [f"dev_{i}" for i in range(5000)]
         assert got[5000:] == got[:5000]
+
+
+class TestSkippedFields:
+    """A member whose key the field spec does not bear is stepped over
+    (PR 37: a shared source's spec is what its rules read)."""
+
+    WIDE = [json.dumps({"id": i, "url": "http://x/%06d/" % i + "u" * 500,
+                        "extra": "e" * 300, "v": i / 2}).encode()
+            for i in range(4000)]
+
+    @staticmethod
+    def _python_bytes_at_peak(fn) -> int:
+        import tracemalloc
+
+        tracemalloc.start()
+        try:
+            fn()
+            return tracemalloc.get_traced_memory()[1]
+        finally:
+            tracemalloc.stop()
+
+    def test_a_skipped_string_builds_no_object_and_is_counted(self, native):
+        tally = {}
+        out = []
+        narrow = self._python_bytes_at_peak(lambda: out.append(
+            fastjson.decode_columns(self.WIDE, (("id", 1),), 2, tally)))
+        cols, valid, bad = out[0]
+        assert list(cols) == ["id"] and list(valid) == ["id"]
+        assert cols["id"].tolist() == list(range(4000)) and not bad.any()
+        assert tally == {"kept": 4000, "skipped": 3 * 4000,
+                         "bytes": sum(len(p) for p in self.WIDE)}
+        # 4,000 int64 and two masks; the 800 bytes of strings a row are in
+        # no Python object. With `url` in the spec they are.
+        assert narrow < 300_000
+        wide = self._python_bytes_at_peak(lambda: fastjson.decode_columns(
+            self.WIDE, (("id", 1), ("url", 3))))
+        assert wide > 2_000_000
+
+    @pytest.mark.parametrize("shards", [1, 3, 8])
+    def test_the_tally_is_the_same_for_any_shard_count(self, native, shards):
+        tally = {}
+        fastjson.decode_columns(self.WIDE, (("v", 0), ("extra", 3)), shards,
+                                tally)
+        assert tally == {"kept": 2 * 4000, "skipped": 2 * 4000,
+                         "bytes": sum(len(p) for p in self.WIDE)}
+
+    def test_every_kind_of_value_is_stepped_over(self, native):
+        payloads = [
+            b'{"a": "q\\"uote\\\\ \\u00e9", "id": 1, "b": {"x": [1, {"y": "}"}]},'
+            b' "c": [true, null, "]"], "d": null, "e": -1.5e3, "f": false}',
+            b'{"id": 2}',
+            b'{"a": "unterminated, "id": 3}',
+        ]
+        tally = {}
+        cols, valid, bad = fastjson.decode_columns(
+            payloads, (("id", 1),), 1, tally)
+        assert bad.tolist() == [False, False, True]
+        assert cols["id"][:2].tolist() == [1, 2]
+        assert tally["kept"] == 2  # row 3 broke inside its skipped member
+        assert tally["skipped"] == 6 + 0 + 1
+
+    def test_an_empty_spec_still_counts_rows_and_fields(self, native):
+        tally = {}
+        cols, valid, bad = fastjson.decode_columns(
+            self.WIDE[:10] + [b"not json"], (), 1, tally)
+        assert cols == {} and valid == {}
+        assert bad.tolist() == [False] * 10 + [True]
+        assert tally["kept"] == 0 and tally["skipped"] == 40
+
+    def test_without_a_tally_the_result_is_the_three_as_before(self, native):
+        out = fastjson.decode_columns(self.WIDE[:5], (("id", 1),))
+        assert len(out) == 3
+        assert len(native.decode(self.WIDE[:5], (("id", 1),))) == 4

@@ -319,7 +319,8 @@ def test_hh_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         # time twice: `health_sample` is what the health plane's covered
         # time and `kuiper_bottleneck_stage` are computed from
         assert fused.stats.nested_stages == {
-            "hh_encode", "hh_encode_new", "hh_finalize", "hh_assemble"}
+            "hh_encode", "hh_encode_new", "hh_finalize", "hh_assemble",
+            "key_encode"}  # (the mirror of new keys, PR 37)
         sample = fused.stats.health_sample()["stages"]
         assert set(sample) == {"upload", "fold", "emit"}
         code, text = api.dispatch("GET", "/metrics", None, {})

@@ -48,9 +48,9 @@ BAD[-1] = b'{"dev": "' + b'x' * 5000 + b'"}'  # oversized string tail
 
 for shards in (1, 2, 4):
     for _ in range(3):
-        cols, valid, bad = ekjsoncol.decode(ROWS, SPEC, shards)
+        cols, valid, bad, _ = ekjsoncol.decode(ROWS, SPEC, shards)
         assert not bad.any()
-        cols, valid, bad = ekjsoncol.decode(BAD, SPEC, shards)
+        cols, valid, bad, _ = ekjsoncol.decode(BAD, SPEC, shards)
         assert bad[17] and not bad[4090]
 
 tab = ekjsoncol.keytab_new()

@@ -46,9 +46,9 @@ errs = []
 def decode_loop():
     try:
         for _ in range(6):
-            cols, valid, bad = ekjsoncol.decode(ROWS, SPEC, 4)
+            cols, valid, bad, _ = ekjsoncol.decode(ROWS, SPEC, 4)
             assert not bad.any()
-            cols, valid, bad = ekjsoncol.decode(BAD, SPEC, 4)
+            cols, valid, bad, _ = ekjsoncol.decode(BAD, SPEC, 4)
             assert bad[17] and not bad[4090]
     except BaseException as exc:  # noqa: BLE001 - surfaced below
         errs.append(exc)

@@ -414,7 +414,8 @@ def test_slide_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         assert st["slide_query"]["total_us"] >= st["slide_query"]["cpu_us"]
         assert 0 < st["slide_merge"]["cpu_us"] <= \
             st["slide_merge"]["total_us"]
-        assert fused.stats.nested_stages == {"slide_query", "slide_merge"}
+        assert fused.stats.nested_stages == {
+            "slide_query", "slide_merge", "key_encode"}
         assert set(fused.stats.health_sample()["stages"]) == {
             "upload", "fold", "emit", "slide_edge", "slide_advance"}
         code, text = api.dispatch("GET", "/metrics", None, {})

@@ -146,3 +146,190 @@ class TestSubtopoPool:
             assert any(m["deviceId"] == "after" for m in _results(t2.sinks[0]))
         finally:
             t2.close()
+
+
+# ------------------------------------------------- union pruning (PR 37)
+WIDE_FIELDS = ["id", "url", "note", "v"]
+
+
+def _mk_wide(store):
+    StreamProcessor(store).exec_stmt(
+        'CREATE STREAM wide (id BIGINT, url STRING, note STRING, v FLOAT) '
+        'WITH (DATASOURCE="t/wide", TYPE="memory", FORMAT="JSON")')
+
+
+def _wide_rule(rule_id, select, **options):
+    return RuleDef(
+        id=rule_id, sql=f"SELECT {select} FROM wide",
+        actions=[{"memory": {"topic": f"res/{rule_id}"}}],
+        options={"micro_batch_rows": 64, "micro_batch_linger_ms": 10,
+                 "decodePoolSize": 2, **options})
+
+
+def _wide_row(i):
+    import json
+
+    return json.dumps({"id": i, "url": f"http://x/{i}", "note": f"n{i}",
+                       "v": float(i)}).encode()
+
+
+def _wait(cond, timeout=5.0):
+    deadline = time.time() + timeout
+    while time.time() < deadline and not cond():
+        time.sleep(0.01)
+    return cond()
+
+
+class TestDecodeUnion:
+    def test_source_decodes_the_union_and_a_detach_leaves_the_others(self):
+        store = kv.get_store()
+        _mk_wide(store)
+        topos = {name: plan_rule(_wide_rule(name, select), store)
+                 for name, select in
+                 (("ua", "id"), ("ub", "id, url"), ("uc", "v"))}
+        topos["ua"].open()
+        st = topos["ua"]._live_shared[0][0]
+        try:
+            assert st.source.decoded_columns() == ["id"]
+            assert st.source._fast_spec == (("id", 1),)
+            topos["ub"].open()
+            assert st.source.decoded_columns() == ["id", "url"]
+            topos["uc"].open()
+            assert st.read_union() == {"id", "url", "v"}
+            topos["ub"].close()  # the others' columns stay, url goes
+            assert st.source.decoded_columns() == ["id", "v"]
+            assert [f.name for f in st.source.schema.fields] == ["id", "v"]
+        finally:
+            for t in topos.values():
+                t.close()
+
+    def test_select_star_keeps_every_column(self, mock_clock):
+        store = kv.get_store()
+        _mk_wide(store)
+        narrow = plan_rule(_wide_rule("sa", "id"), store)
+        star = plan_rule(_wide_rule("sb", "*"), store)
+        narrow.open()
+        st = narrow._live_shared[0][0]
+        star.open()
+        try:
+            assert st.read_union() is None
+            assert st.source.decoded_columns() == sorted(WIDE_FIELDS)
+            mem.publish("t/wide", [_wide_row(i) for i in range(5)])
+            mock_clock.advance(20)
+            assert _wait(lambda: len(_results(star.sinks[0])) == 5
+                         and len(_results(narrow.sinks[0])) == 5)
+            assert all(set(m) == set(WIDE_FIELDS)
+                       for m in _results(star.sinks[0]))
+            # the narrow rider's own projection stays in its entry
+            assert all(set(m) == {"id"} for m in _results(narrow.sinks[0]))
+        finally:
+            narrow.close()
+            star.close()
+
+    def test_a_rule_that_widens_the_union_sees_its_column_from_its_first_batch(
+            self):
+        """A second rule that reads `url` attaches to a pipeline that is
+        decoding `id` alone, with micro-batches decoded for `id` held in
+        the ring: it is handed none of those, and every row it is handed
+        bears its url."""
+        import threading
+
+        store = kv.get_store()
+        _mk_wide(store)
+        first = plan_rule(_wide_rule("wa", "id"), store)
+        second = plan_rule(_wide_rule("wb", "id, url"), store)
+        first.open()
+        src = first._live_shared[0][0].source
+        gate = threading.Event()
+        gate.set()
+        emit, held = src._emit_decoded, []
+
+        def gated(batch):  # the ring's ordered drain, stopped at will
+            if not gate.is_set():
+                held.append(batch)
+            assert gate.wait(10)
+            emit(batch)
+
+        src._emit_decoded = gated  # the pool starts at the first rows
+        stop = threading.Event()
+        sent = [0]
+
+        def feed():
+            while not stop.is_set():  # a micro-batch fills every 64 rows
+                mem.publish("t/wide", [_wide_row(sent[0] + k)
+                                       for k in range(16)])
+                sent[0] += 16
+                time.sleep(0.001)
+
+        feeder = threading.Thread(target=feed, daemon=True)
+        feeder.start()
+        try:
+            assert _wait(lambda: len(_results(first.sinks[0])) > 640)
+            gate.clear()
+            assert _wait(lambda: src.extra_pending() >= 2)  # the ring fills
+            second.open()
+            gate.set()
+            assert _wait(lambda: len(_results(second.sinks[0])) > 640)
+        finally:
+            gate.set()
+            stop.set()
+            feeder.join(timeout=5)
+            assert not feeder.is_alive()
+            got = _results(second.sinks[0])
+            first.close()
+            second.close()
+        assert held and held[0].decoded == {"id"}
+        narrow_ids = {int(i) for b in held if not b.covers({"id", "url"})
+                      for i in b.columns["id"]}
+        assert narrow_ids and not narrow_ids & {m["id"] for m in got}
+        assert got and all(m.get("url") == f"http://x/{m['id']}"
+                           for m in got)
+
+    def test_one_micro_batch_is_decoded_with_one_column_set(self):
+        """Riders come and go while rows arrive: whatever the plan was when
+        a micro-batch was handed over is what all of it was decoded with."""
+        import threading
+
+        from ekuiper_tpu.data.types import DataType, Field, Schema
+        from ekuiper_tpu.io.converters import JsonConverter
+        from ekuiper_tpu.runtime.nodes_source import SourceNode
+
+        schema = Schema(fields=[
+            Field("id", DataType.BIGINT), Field("url", DataType.STRING),
+            Field("note", DataType.STRING), Field("v", DataType.FLOAT)])
+        src = SourceNode(
+            "s", connector=type("C", (), {
+                "open": lambda self, cb: None,
+                "close": lambda self: None})(),
+            schema=schema, converter=JsonConverter(), micro_batch_rows=128,
+            decode_pool_size=3, ring_depth=3, prep_upload=False)
+        got = []
+        src.broadcast = got.append
+        stop = threading.Event()
+        sets = [{"id"}, {"id", "url"}, None, {"v", "note"}, set()]
+
+        def flip():
+            i = 0
+            while not stop.is_set():
+                src.set_decode_columns(sets[i % len(sets)])
+                i += 1
+
+        flipper = threading.Thread(target=flip, daemon=True)
+        flipper.start()
+        try:
+            for start in range(0, 128 * 200, 32):
+                src.ingest([_wide_row(start + k) for k in range(32)])
+        finally:
+            stop.set()
+            flipper.join(timeout=5)
+        src._flush()
+        src.on_close()
+        assert not flipper.is_alive()
+        assert sum(b.n for b in got) == 128 * 200  # no row lost to a swap
+        seen = set()
+        for b in got:
+            want = set(WIDE_FIELDS) if b.decoded is None else set(b.decoded)
+            assert set(b.columns) == want
+            assert all(len(c) == b.n for c in b.columns.values())
+            seen.add(frozenset(want))
+        assert len(seen) > 1  # the plan did change between micro-batches

@@ -121,6 +121,8 @@ def _synthetic_scrape() -> str:
             self.stats.process_end()
             if pooled:
                 self.pool_depths = lambda: (1, 0)
+                # kuiper_source_decode_{fields,bytes}_total render from it
+                self.decode_tally = {"kept": 3, "skipped": 18, "bytes": 750}
             # kuiper_sliding_triggers_total renders from this attribute
             self.sliding_triggers = {"fast": 2, "flip": 1, "dyn": 1}
             # ... and kuiper_sliding_tail_total from this one
