@@ -104,11 +104,12 @@ def native_module():
     return _load()
 
 
-def has_keytab() -> bool:
+def has_keytab(api: str = "keytab_encode") -> bool:
     """True when the loaded native decoder carries the persistent key-slot
-    table API (a stale prebuilt .so may predate it)."""
+    table entry point `api` (`keytab_encode` for str keys,
+    `keytab_encode_i64` for integer keys)."""
     mod = _load()
-    return mod is not None and hasattr(mod, "keytab_encode")
+    return mod is not None and hasattr(mod, api)
 
 
 def decode_columns(

@@ -213,6 +213,19 @@ def render(rule_registry) -> str:
         out.append(
             f'kuiper_source_decode_bytes_total{{rule="{_esc(rule_id)}",'
             f'op="{_esc(node.name)}"}} {tally["bytes"]}')
+    # which encode served the GROUP BY key columns (ops/keytable.py): the
+    # column's dtype picks the path, this says which one a rule's keys take
+    _family(out, "kuiper_keytable_encode_rows_total", "counter",
+            "rows slot-encoded by a key table, by path: native_int (int64 "
+            "table), native_str (byte-keyed table), hashed (dict map), "
+            "sorted (np.unique)")
+    for rule_id, node in rows:
+        if not hasattr(node, "keytable_encode_rows"):
+            continue
+        for path, n in sorted((node.keytable_encode_rows() or {}).items()):
+            out.append(
+                f'kuiper_keytable_encode_rows_total{{rule="{_esc(rule_id)}",'
+                f'op="{_esc(node.name)}",path="{path}"}} {n}')
     # shared pane folds (runtime/nodes_sharedfold.py): pool-level gauges —
     # members per store and the fold-dedup ratio (1 - folds run / folds N
     # private rules would have run). The store node's own op metrics (incl.

@@ -3,10 +3,15 @@
 The reference builds a string group key per row and hashes into a Go map
 (internal/topo/operator/aggregate_operator.go:34-74). On TPU the per-key
 state lives in dense device arrays, so keys must become stable integer slots.
-The key table is the host-side dictionary: a C-level dict map per batch in
-steady state (no sort once all keys are known), a sort-based np.unique path
-for numeric/unicode and unhashable keys, and a reverse list for decoding
-emitted slots back to key values.
+The key table is the host-side dictionary. Which encode serves a column is
+decided by the column itself: an object column of str/None keys takes one
+native pass over a byte-keyed table (`native_str`), an integer column one
+native pass over an int64 table (`native_int`), other hashable keys a C-level dict map per batch
+(`hashed`), and float / fixed-width unicode / unhashable keys — or any
+column when the native module is missing — a sort-based np.unique path
+(`sorted`). The Python dict and the reverse list (emitted slots back to key
+values, checkpoints) are the source of truth on every path; the native
+tables mirror them. `encode_rows` counts the rows each path served.
 """
 from __future__ import annotations
 
@@ -14,14 +19,21 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..utils.infra import logger
 
-def _native_keytab_module():
-    """ekjsoncol when it is loaded AND carries the keytab API, else None.
-    Never triggers a build (io/fastjson.py owns that lifecycle)."""
+
+ENCODE_PATHS = ("native_int", "native_str", "hashed", "sorted")
+_I64 = np.iinfo(np.int64)
+
+
+def _native_keytab_module(api: str = "keytab_encode"):
+    """ekjsoncol when it is loaded AND carries `api` (a stale prebuilt .so
+    may predate it), else None. Never triggers a build (io/fastjson.py
+    owns that lifecycle)."""
     try:
         from ..io import fastjson
 
-        if fastjson.has_keytab():
+        if fastjson.has_keytab(api):
             return fastjson.native_module()
     except Exception:
         pass
@@ -33,17 +45,24 @@ class KeyTable:
         self.capacity = initial_capacity
         self._ids: Dict[Any, int] = {}
         self._keys: List[Any] = []
-        # native slot-encode fast path (native/jsoncol.cpp keytab_*): a
+        # native slot-encode fast paths (native/jsoncol.cpp keytab_*): a
         # persistent byte-keyed hash table assigns slots in one C pass for
-        # plain str/None key columns — the dominant GROUP BY shape. The
-        # Python table REMAINS the source of truth (reverse decode,
-        # checkpointing, every non-str shape); the native table mirrors it
+        # plain str/None key columns, an int64 table for integer columns.
+        # The Python table REMAINS the source of truth (reverse decode,
+        # checkpointing, every other shape); a native table mirrors it
         # via the ordered new-key appendix and a lazy catch-up, and any
-        # batch the C side can't represent byte-identically falls back
-        # here without ever diverging the two.
+        # batch the C side can't represent identically falls back here
+        # without ever diverging the two.
         self._ntab = None
         self._native_n = 0  # python keys already mirrored into the native tab
         self._native_ok = True
+        # the int64 table takes slots from `len(_keys)`, so it also serves
+        # a table that holds str keys (a null BIGINT key is ""); what pins
+        # it off is a key Python's dict could alias to an integer
+        self._itab = None
+        self._int_n = 0  # python keys already looked at for the int tab
+        self._int_ok = True
+        self.encode_rows: Dict[str, int] = dict.fromkeys(ENCODE_PATHS, 0)
         # tiered key state (ops/tierstore.py): retired (demoted) slots
         # recycle through this free list instead of forcing capacity
         # growth; `track_new` turns on the new-key log the tier manager
@@ -71,7 +90,9 @@ class KeyTable:
                 # order so both sides assign identical ids from here on
                 missing = self._keys[self._native_n:]
                 if not all(type(k) is str for k in missing):
-                    self._native_ok = False  # tuples/numerics: python-only
+                    # tuples / numbers: the str table owns its slot
+                    # counter, so it cannot skip them
+                    self._native_ok = False
                     return None
                 mod.keytab_encode(self._ntab, missing)
                 self._native_n = len(self._keys)
@@ -95,6 +116,72 @@ class KeyTable:
             grew = True
         return slots, grew
 
+    def _native_encode_int(self, col: np.ndarray
+                           ) -> Optional[Tuple[np.ndarray, bool]]:
+        """One-pass C slot encode of an integer column, no Python object
+        touched; None when the native path is unavailable, a value lies
+        outside int64, or this table's history holds a key the dict
+        could alias to an integer — the caller runs a Python path."""
+        if not self._int_ok:
+            return None
+        mod = _native_keytab_module("keytab_encode_i64")
+        if mod is None:
+            return None
+        if col.dtype == np.uint64 and int(col.max()) > _I64.max:
+            return None
+        try:
+            if self._itab is None:
+                self._itab = mod.keytab_i64_new()
+            if self._int_n < len(self._keys) and not self._int_catch_up(mod):
+                return None
+            slots, appendix = mod.keytab_encode_i64(
+                self._itab, np.ascontiguousarray(col, dtype=np.int64),
+                len(self._keys))
+        except Exception as exc:
+            # the native table was NOT mutated; one decision, not a retry
+            # (and a catch-up over the whole history) every batch
+            self._int_ok = False
+            logger.warning("key table: native int encode failed (%r); "
+                           "this table stays on the Python path", exc)
+            return None
+        if len(appendix):
+            # .tolist(): keys are emitted and checkpointed as Python ints
+            new = appendix.tolist()
+            start = len(self._keys)
+            self._ids.update(zip(new, range(start, start + len(new))))
+            self._keys.extend(new)
+            self._int_n = len(self._keys)
+            if self.track_new:
+                self._new_log.extend(
+                    zip(new, range(start, start + len(new))))
+        grew = False
+        while len(self._keys) > self.capacity:
+            self.capacity *= 2
+            grew = True
+        return slots, grew
+
+    def _int_catch_up(self, mod) -> bool:
+        """Mirror int keys that arrived via Python paths (sorted fallback,
+        object columns, restore) into the int table under the slots they
+        hold. Str keys, and ints beyond int64 (no int64 column can hold
+        their like), are skipped. False pins the table to the Python path:
+        a hole, or a key of another type (1.0, True, a Decimal — what the
+        dict may alias to an integer)."""
+        start = self._int_n
+        ints, slots = [], []
+        for slot, k in enumerate(self._keys[start:], start):
+            if type(k) is int:
+                if _I64.min <= k <= _I64.max:
+                    ints.append(k)
+                    slots.append(slot)
+            elif type(k) is not str:
+                self._int_ok = False
+                return False
+        mod.keytab_load_i64(self._itab, np.array(ints, dtype=np.int64),
+                            np.array(slots, dtype=np.int32))
+        self._int_n = len(self._keys)
+        return True
+
     def __len__(self) -> int:
         return len(self._keys)
 
@@ -105,22 +192,54 @@ class KeyTable:
     def encode_column(self, col: np.ndarray) -> Tuple[np.ndarray, bool]:
         """Encode a key column to int32 slots. Returns (slots, grew) where
         `grew` signals the device state must be re-allocated (capacity x2).
+        The column's dtype picks the path (module docstring); new keys take
+        dense slots, in first-seen order on the native and hashed paths, in
+        sorted order within a batch on the sorted one — slot numbers are
+        private, a key keeps its slot over every path.
 
-        Steady-state fast path: one C-level dict lookup per row
-        (map(dict.__getitem__) + np.fromiter ≈ 10M rows/s) — after warmup
-        every key already has a slot, so no sort is needed at all. A KeyError
-        (new key) drops to the insertion loop; unhashable values drop to the
-        sort-based legacy path below."""
+        Str/None steady state: one C pass, or one C-level dict lookup per
+        row (map(dict.__getitem__) + np.fromiter ≈ 10M rows/s) — after
+        warmup every key already has a slot, so no sort is needed at all."""
+        rows = self.encode_rows
         if col.dtype == np.object_ and len(col):
             lst = col.tolist()
             out = self._native_encode(lst)
             if out is not None:
+                rows["native_str"] += len(lst)
                 return out
             try:
-                return self._encode_hashed(lst)
+                out = self._encode_hashed(lst)
+                rows["hashed"] += len(lst)
+                return out
             except TypeError:
                 pass  # unhashable elements — legacy sort path
+        elif col.dtype.kind in "iu" and len(col):
+            out = self._native_encode_int(col)
+            if out is not None:
+                rows["native_int"] += len(col)
+                return out
+        rows["sorted"] += len(col)
         return self._encode_sorted(col)
+
+    def mirror(self, keys: List[Any]) -> bool:
+        """Take `keys` (a `keys_slice` of another table) in exactly their
+        order, so both tables hold identical ids; returns `grew`. An all-int
+        slice goes by the int table, the path the keys came by; where that
+        cannot serve (no native module, a pinned table) the slice goes as an
+        object column, whose paths number new keys first seen first — never
+        as an int column to the sorted path, which would renumber them."""
+        col = None
+        if keys and all(type(k) is int for k in keys):
+            try:
+                col = np.array(keys, dtype=np.int64)
+            except OverflowError:
+                pass
+        out = self._native_encode_int(col) if col is not None else None
+        if out is None:
+            out = self.encode_column(np.array(keys, dtype=np.object_))
+        else:
+            self.encode_rows["native_int"] += len(keys)
+        return out[1]
 
     def _encode_hashed(self, lst: list) -> Tuple[np.ndarray, bool]:
         """Dict-encode a list of hashable keys. Raises TypeError on
@@ -199,7 +318,7 @@ class KeyTable:
         represent holes, so retirement pins this table to the Python
         path. Callers must pass the keys currently holding the slots
         (the tier manager re-validates via decode before demoting)."""
-        self._native_ok = False
+        self._native_ok = self._int_ok = False
         for slot, key in zip(slots, keys):
             if self._keys[slot] != key:
                 continue  # raced a re-encode; leave the slot live
@@ -281,6 +400,7 @@ class KeyTable:
         slot — so steady state is still one dict lookup per row."""
         if len(cols) == 1:
             return self.encode_column(cols[0])
+        self.encode_rows["hashed"] += len(cols[0])
         try:
             combos = list(zip(*(c.tolist() for c in cols)))
             return self._encode_hashed(combos)
@@ -343,9 +463,9 @@ class KeyTable:
         self._keys.clear()
         # drop the native mirror; the next native encode re-feeds from
         # _keys (empty now), so both sides restart in lockstep
-        self._ntab = None
-        self._native_n = 0
-        self._native_ok = True
+        self._ntab = self._itab = None
+        self._native_n = self._int_n = 0
+        self._native_ok = self._int_ok = True
         self._free.clear()
         self._new_log.clear()
 
@@ -360,7 +480,7 @@ class KeyTable:
             self._keys.append(k)
             if k is None:
                 self._free.append(i)
-                self._native_ok = False
+                self._native_ok = self._int_ok = False
             else:
                 self._ids[k] = i
         while len(self._keys) > self.capacity:

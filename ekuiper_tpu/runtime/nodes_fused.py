@@ -727,24 +727,31 @@ class FusedWindowAggNode(Node):
             if not self._shared_slots_ok:
                 return None
         self._shared_nkt = nkt
-        if self.kt.n_keys < n_keys:
-            # mirror the neutral table's new keys into our own
-            with key_encode_stage(self.stats, n_keys - self.kt.n_keys):
-                new = np.array(nkt.keys_slice(self.kt.n_keys, n_keys),
-                               dtype=np.object_)
-                _, grew = self.kt.encode_column(new)
+        start = self.kt.n_keys
+        if start < n_keys:
+            # mirror the neutral table's new keys into our own, in order
+            new = nkt.keys_slice(start, n_keys)
+            with key_encode_stage(self.stats, n_keys - start):
+                grew = self.kt.mirror(new)
             if grew:
                 self.state = self.gb.grow(self.state, self.kt.capacity)
-        if self.kt.n_keys < n_keys:
-            # truly diverged (sync could not reach the snapshot): self-
-            # encode from now on. n_keys ABOVE the snapshot is normal with
-            # the pipelined upload stage — pool workers may encode batch
-            # k+1 before batch k's snapshot is consumed, so our table can
-            # legitimately run ahead of an older batch's n_keys; its slot
-            # values are all below the snapshot and stay valid.
-            self._shared_slots_ok = False
-            return None
+            if self.kt.keys_slice(start, n_keys) != new:
+                # truly diverged (our table numbered the keys otherwise):
+                # self-encode from now on. n_keys ABOVE the snapshot is
+                # normal with the pipelined upload stage — pool workers
+                # may encode batch k+1 before batch k's snapshot is
+                # consumed, so our table can legitimately run ahead of an
+                # older batch's n_keys; its slot values are all below the
+                # snapshot and stay valid.
+                self._shared_slots_ok = False
+                return None
         return slots
+
+    def keytable_encode_rows(self) -> Dict[str, int]:
+        """Rows this node's own key table encoded, by path: every row of
+        a rule that encodes for itself, only the mirrored new keys of one
+        that rides a shared source's encode."""
+        return self.kt.encode_rows
 
     def pane_occupancy(self) -> "Optional[float]":
         """Event-time pane-ring occupancy (dirty buckets / ring size),

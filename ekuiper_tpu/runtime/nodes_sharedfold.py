@@ -221,6 +221,10 @@ class SharedFoldNode(Node):
     def source(self) -> Optional[Node]:
         return self._subtopo.source if self._subtopo is not None else None
 
+    def keytable_encode_rows(self) -> Dict[str, int]:
+        """Rows the store's own key table encoded, by path."""
+        return self.store.kt.encode_rows
+
     def fold_dedup_ratio(self) -> float:
         """1 - actual folds / folds N private rules would have run."""
         if self.folds_would <= 0:
@@ -570,14 +574,14 @@ class SharedFoldNode(Node):
             if not self._shared_slots_ok:
                 return None
         self._shared_nkt = nkt
-        if kt.n_keys < n_keys:
-            with key_encode_stage(self.stats, n_keys - kt.n_keys):
-                new = np.array(nkt.keys_slice(kt.n_keys, n_keys),
-                               dtype=np.object_)
-                kt.encode_column(new)
-        if kt.n_keys < n_keys:
-            self._shared_slots_ok = False  # diverged: self-encode from now
-            return None
+        start = kt.n_keys
+        if start < n_keys:
+            new = nkt.keys_slice(start, n_keys)
+            with key_encode_stage(self.stats, n_keys - start):
+                kt.mirror(new)
+            if kt.keys_slice(start, n_keys) != new:
+                self._shared_slots_ok = False  # diverged: self-encode from now
+                return None
         return slots
 
     def _device_inputs(self, sub, cols, valid, slots):

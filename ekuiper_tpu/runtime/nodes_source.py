@@ -168,6 +168,17 @@ class SourceNode(Node):
     def _fast_spec(self) -> Optional[tuple]:
         return self._plan.fast_spec
 
+    def keytable_encode_rows(self) -> Optional[Dict[str, int]]:
+        """Rows the ingest prep's key tables encoded, by path
+        (ops/keytable.py ENCODE_PATHS); None without the prep stage."""
+        if self.prep_ctx is None:
+            return None
+        out: Dict[str, int] = {}
+        for kt in list(self.prep_ctx.key_tables.values()):
+            for path, n in kt.encode_rows.items():
+                out[path] = out.get(path, 0) + n
+        return out
+
     def decoded_columns(self):
         """The columns a micro-batch is decoded with now, for the status
         and /explain: sorted names, or "*" (everything the payload bears)."""

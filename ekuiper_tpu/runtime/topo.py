@@ -229,6 +229,13 @@ class Topo:
             if cols is not None:
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_decoded_columns"] = cols()
+        # rows each node's key tables encoded, by path (ops/keytable.py)
+        for n in sources + self.ops:
+            fn = getattr(n, "keytable_encode_rows", None)
+            enc = fn() if fn is not None else None
+            if enc is not None:
+                out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
+                    "_keytable_encode_rows"] = dict(enc)
         # rule-level SLO summary: the ingest→emit distribution percentiles
         out["e2e_latency_ms"] = self.e2e_hist.snapshot()
         # ... and its engine-side phases per window boundary, ms

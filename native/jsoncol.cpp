@@ -936,6 +936,254 @@ PyObject* kt_encode(PyObject*, PyObject* args) {
   return out;
 }
 
+// ---------------------------------------------------------------------------
+// Persistent int64 -> slot table (an integer GROUP BY key: an id, a code).
+//
+// A numeric key column is already a flat buffer, so its dictionary encode
+// needs no Python object at all: one pass over the int64 values, open
+// addressing (linear probing, load <= 1/2) over two flat arrays. As with
+// KeyTab the Python KeyTable stays the source of truth: the caller passes
+// the next free slot, misses take dense slots from there on in first-seen
+// order and come back as an int64 appendix; keytab_load_i64 mirrors
+// (key, slot) pairs the Python paths assigned (catch-up, restore).
+//
+// Contract: encode/load either complete or raise having changed nothing.
+// The pass keeps the interpreter lock (0.5 ms a 32,768-row column; giving
+// it up cost the caller ~2.4 ms of queueing to get it back and bought
+// nothing end to end, PERF.md PR 39), so callers are serialised as KeyTab's.
+
+struct I64Tab {
+  std::vector<int64_t> keys;
+  std::vector<int32_t> slots;  // -1 = empty cell
+  size_t mask = 0;             // cells - 1 (cells is a power of two)
+  size_t count = 0;
+
+  static size_t mix(int64_t k) {  // splitmix64 finalizer
+    uint64_t x = (uint64_t)k;
+    x ^= x >> 30; x *= 0xbf58476d1ce4e5b9ULL;
+    x ^= x >> 27; x *= 0x94d049bb133111ebULL;
+    x ^= x >> 31;
+    return (size_t)x;
+  }
+
+  // the cell holding `k`, or the empty cell where it would go
+  size_t probe(int64_t k) const {
+    size_t i = mix(k) & mask;
+    while (slots[i] >= 0 && keys[i] != k) i = (i + 1) & mask;
+    return i;
+  }
+
+  // room for `extra` more keys at load <= 1/2; throws std::bad_alloc
+  // BEFORE anything moved
+  void reserve(size_t extra) {
+    size_t cells = mask + 1;
+    if (!slots.empty() && (count + extra) * 2 <= cells) return;
+    size_t want = slots.empty() ? 1024 : cells;
+    while ((count + extra) * 2 > want) want *= 2;
+    std::vector<int64_t> nk(want, 0);
+    std::vector<int32_t> ns(want, -1);
+    const size_t nmask = want - 1;
+    for (size_t i = 0; i < slots.size(); i++) {
+      if (slots[i] < 0) continue;
+      size_t j = mix(keys[i]) & nmask;
+      while (ns[j] >= 0) j = (j + 1) & nmask;
+      nk[j] = keys[i];
+      ns[j] = slots[i];
+    }
+    keys.swap(nk);
+    slots.swap(ns);
+    mask = nmask;
+  }
+
+  // roll a key of a failed call back out (backward-shift deletion keeps
+  // every probe chain whole without tombstones)
+  void erase(int64_t k) {
+    size_t i = probe(k);
+    if (slots[i] < 0) return;
+    size_t j = i;
+    for (;;) {
+      j = (j + 1) & mask;
+      if (slots[j] < 0) break;
+      size_t home = mix(keys[j]) & mask;
+      // cell j may move into the hole at i unless its home lies
+      // cyclically in (i, j]
+      bool stays = (i <= j) ? (home > i && home <= j)
+                            : (home > i || home <= j);
+      if (stays) continue;
+      keys[i] = keys[j];
+      slots[i] = slots[j];
+      i = j;
+    }
+    slots[i] = -1;
+    count--;
+  }
+};
+
+void i64tab_destruct(PyObject* cap) {
+  delete (I64Tab*)PyCapsule_GetPointer(cap, "ekjsoncol.keytab_i64");
+}
+
+I64Tab* i64tab_of(PyObject* cap) {
+  return (I64Tab*)PyCapsule_GetPointer(cap, "ekjsoncol.keytab_i64");
+}
+
+// `obj` as a C-contiguous 1-D array of exactly `typenum` (new reference);
+// TypeError otherwise — a cast here could alias keys (2.0 -> 2)
+PyArrayObject* exact_1d(PyObject* obj, int typenum, const char* what) {
+  if (!PyArray_Check(obj) || PyArray_NDIM((PyArrayObject*)obj) != 1 ||
+      !PyArray_EquivTypenums(PyArray_TYPE((PyArrayObject*)obj), typenum) ||
+      !PyArray_ISBEHAVED_RO((PyArrayObject*)obj)) {
+    PyErr_Format(PyExc_TypeError, "%s: expected a 1-D aligned native array",
+                 what);
+    return nullptr;
+  }
+  return (PyArrayObject*)PyArray_GETCONTIGUOUS((PyArrayObject*)obj);
+}
+
+PyObject* i64_new(PyObject*, PyObject*) {
+  return PyCapsule_New(new I64Tab(), "ekjsoncol.keytab_i64",
+                       i64tab_destruct);
+}
+
+PyObject* i64_encode(PyObject*, PyObject* args) {
+  PyObject* cap;
+  PyObject* col_obj;
+  long long next_slot;
+  if (!PyArg_ParseTuple(args, "OOL", &cap, &col_obj, &next_slot))
+    return nullptr;
+  if (next_slot < 0 || next_slot > INT32_MAX) {
+    PyErr_SetString(PyExc_OverflowError, "next_slot outside int32");
+    return nullptr;
+  }
+  PyArrayObject* col = exact_1d(col_obj, NPY_INT64, "keytab_encode_i64");
+  if (col == nullptr) return nullptr;
+  npy_intp n = PyArray_DIM(col, 0);
+  PyObject* slots_arr = PyArray_SimpleNew(1, &n, NPY_INT32);
+  I64Tab* t = slots_arr != nullptr ? i64tab_of(cap) : nullptr;
+  if (t == nullptr) {
+    Py_XDECREF(slots_arr); Py_DECREF(col);
+    return nullptr;
+  }
+  const int64_t* in = (const int64_t*)PyArray_DATA(col);
+  int32_t* out = (int32_t*)PyArray_DATA((PyArrayObject*)slots_arr);
+  std::vector<int64_t> fresh;  // the appendix: new keys, first seen first
+  int fail = 0;  // 1 = out of memory, 2 = slots exhausted, 3 = no appendix
+
+  try {
+    t->reserve(1);
+    for (npy_intp i = 0; i < n; i++) {
+      const int64_t k = in[i];
+      const size_t c = t->probe(k);
+      int32_t slot = t->slots[c];
+      if (slot < 0) {
+        const long long next = next_slot + (long long)fresh.size();
+        if (next > INT32_MAX) {
+          fail = 2;
+          break;
+        }
+        fresh.push_back(k);
+        slot = (int32_t)next;
+        t->keys[c] = k;
+        t->slots[c] = slot;
+        t->count++;
+        t->reserve(1);  // may move every cell: `c` is dead from here
+      }
+      out[i] = slot;
+    }
+  } catch (const std::bad_alloc&) {
+    fail = 1;
+  }
+
+  PyObject* appendix = nullptr;
+  if (!fail) {
+    npy_intp m = (npy_intp)fresh.size();
+    appendix = PyArray_SimpleNew(1, &m, NPY_INT64);
+    if (appendix == nullptr) {
+      fail = 3;  // MemoryError is set
+    } else if (m > 0) {
+      std::memcpy(PyArray_DATA((PyArrayObject*)appendix), fresh.data(),
+                  (size_t)m * sizeof(int64_t));
+    }
+  }
+  if (fail) {
+    // the Python table will never hear of these slots: take them back
+    for (int64_t k : fresh) t->erase(k);
+  }
+  Py_DECREF(col);
+  PyObject* res = nullptr;
+  if (fail == 1) {
+    PyErr_NoMemory();
+  } else if (fail == 2) {
+    PyErr_SetString(PyExc_OverflowError, "slot ids exceed int32");
+  } else if (appendix != nullptr) {
+    res = PyTuple_Pack(2, slots_arr, appendix);
+  }
+  Py_DECREF(slots_arr);
+  Py_XDECREF(appendix);
+  return res;
+}
+
+PyObject* i64_load(PyObject*, PyObject* args) {
+  PyObject* cap;
+  PyObject* keys_obj;
+  PyObject* slots_obj;
+  if (!PyArg_ParseTuple(args, "OOO", &cap, &keys_obj, &slots_obj))
+    return nullptr;
+  PyArrayObject* ka = exact_1d(keys_obj, NPY_INT64, "keytab_load_i64 keys");
+  if (ka == nullptr) return nullptr;
+  PyArrayObject* sa = exact_1d(slots_obj, NPY_INT32, "keytab_load_i64 slots");
+  if (sa == nullptr) {
+    Py_DECREF(ka);
+    return nullptr;
+  }
+  const npy_intp n = PyArray_DIM(ka, 0);
+  const int64_t* ks = (const int64_t*)PyArray_DATA(ka);
+  const int32_t* ss = (const int32_t*)PyArray_DATA(sa);
+  I64Tab* t = nullptr;
+  if (PyArray_DIM(sa, 0) != n) {
+    PyErr_SetString(PyExc_ValueError, "keys and slots differ in length");
+  } else {
+    t = i64tab_of(cap);
+  }
+  if (t == nullptr) {
+    Py_DECREF(ka); Py_DECREF(sa);
+    return nullptr;
+  }
+  // a pair is new, or says again what the table holds; anything else (a
+  // negative slot, a key under another slot) rolls the call back whole
+  const char* err = nullptr;
+  std::vector<int64_t> added;
+  try {
+    t->reserve((size_t)n);
+    added.reserve((size_t)n);
+  } catch (const std::bad_alloc&) {
+    err = "";
+  }
+  for (npy_intp i = 0; err == nullptr && i < n; i++) {
+    const size_t c = t->probe(ks[i]);
+    if (t->slots[c] >= 0) {
+      if (t->slots[c] != ss[i]) err = "key already holds another slot";
+    } else if (ss[i] < 0) {
+      err = "negative slot";
+    } else {
+      t->keys[c] = ks[i];
+      t->slots[c] = ss[i];
+      t->count++;
+      added.push_back(ks[i]);
+    }
+  }
+  if (err != nullptr) {
+    for (int64_t k : added) t->erase(k);
+  }
+  Py_DECREF(ka); Py_DECREF(sa);
+  if (err != nullptr) {
+    if (*err == '\0') return PyErr_NoMemory();
+    PyErr_SetString(PyExc_ValueError, err);
+    return nullptr;
+  }
+  Py_RETURN_NONE;
+}
+
 PyMethodDef methods[] = {
     {"decode", jc_decode, METH_VARARGS,
      "decode(payloads, fields, shards=1) -> (columns, valid, bad, "
@@ -946,6 +1194,13 @@ PyMethodDef methods[] = {
      "keytab_encode(tab, keys) -> (slots int32, appendix list)"},
     {"keytab_len", kt_len, METH_VARARGS, "keytab_len(tab) -> int"},
     {"keytab_clear", kt_clear, METH_VARARGS, "keytab_clear(tab)"},
+    {"keytab_i64_new", i64_new, METH_NOARGS,
+     "keytab_i64_new() -> persistent int64 key-slot table capsule"},
+    {"keytab_encode_i64", i64_encode, METH_VARARGS,
+     "keytab_encode_i64(tab, keys int64, next_slot) -> (slots int32, "
+     "appendix int64): one pass, new keys first seen first"},
+    {"keytab_load_i64", i64_load, METH_VARARGS,
+     "keytab_load_i64(tab, keys int64, slots int32): mirror known pairs"},
     {nullptr, nullptr, 0, nullptr},
 };
 

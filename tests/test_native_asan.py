@@ -66,7 +66,40 @@ for round_ in range(6):
         ekjsoncol.keytab_encode(tab, ["ok", 42, "also-ok"])
     except Exception:
         pass  # non-str key: must roll back without touching storage
-print("ASAN_STRESS_OK", seen)
+
+# the int64 key table: growth rehashes (1,024 cells up), a pass that runs
+# out of slot ids after taking keys in (every one rolled back by
+# backward-shift deletion), refused loads, then the survivors read again
+import numpy as np
+
+itab = ekjsoncol.keytab_i64_new()
+rng = np.random.default_rng(3)
+known = {}
+for round_ in range(6):
+    col = rng.integers(-2**63, 2**63 - 1, 3000 * (round_ + 1), dtype=np.int64)
+    col[::3] = col[0]
+    slots, appendix = ekjsoncol.keytab_encode_i64(itab, col, len(known))
+    for k in appendix.tolist():
+        known[k] = len(known)
+    assert slots.tolist() == [known[k] for k in col.tolist()]
+    fresh = rng.integers(-2**63, 2**63 - 1, 5000, dtype=np.int64)
+    fresh[::2] = col[:2500]
+    try:
+        ekjsoncol.keytab_encode_i64(itab, fresh, 2**31 - 100)
+        raise SystemExit("slot ids past int32 were handed out")
+    except OverflowError:
+        pass
+    try:
+        ekjsoncol.keytab_load_i64(
+            itab, np.append(fresh[1:400:2], col[0]),
+            np.arange(201, dtype=np.int32) + len(known))
+        raise SystemExit("a key took a second slot")
+    except ValueError:
+        pass
+    slots, appendix = ekjsoncol.keytab_encode_i64(itab, col, len(known))
+    assert len(appendix) == 0
+    assert slots.tolist() == [known[k] for k in col.tolist()]
+print("ASAN_STRESS_OK", seen, len(known))
 """
 
 

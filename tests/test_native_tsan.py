@@ -6,8 +6,10 @@ had zero sanitizer coverage before this suite: a torn write there would
 corrupt columns silently, and only on multi-shard configs. The test
 builds the `make tsan` module, then stress-drives multi-shard decodes
 from several Python threads (plus keytab encodes, whose appendix/commit
-path shares the table across batches) in a subprocess running under
-libtsan, and fails on any ThreadSanitizer report.
+path shares the table across batches, and int64 key-table passes: two
+tables from two threads beside the decodes' shard threads) in a
+subprocess running under libtsan, and fails on any ThreadSanitizer
+report.
 
 Skips with an explicit reason when the sanitizer toolchain is missing
 (no g++, no libtsan, or the instrumented build fails) — the suite must
@@ -63,8 +65,32 @@ def keytab_loop():
     except BaseException as exc:  # noqa: BLE001
         errs.append(exc)
 
+# the int64 key table: two tables from two threads, their passes (growth
+# included) beside the decodes' shard threads
+import numpy as np
+
+INT_KEYS = np.random.default_rng(7).integers(-2**62, 2**62, 20000)
+
+def i64_loop(seed):
+    try:
+        rng = np.random.default_rng(seed)
+        tab = ekjsoncol.keytab_i64_new()
+        known = {}
+        for _ in range(8):
+            col = INT_KEYS[rng.integers(0, len(INT_KEYS), 200000)]
+            slots, appendix = ekjsoncol.keytab_encode_i64(
+                tab, col, len(known))
+            for k in appendix.tolist():
+                known[k] = len(known)
+            probe = rng.integers(0, len(col), 64)
+            assert [known[int(col[i])] for i in probe] == \
+                slots[probe].tolist()
+    except BaseException as exc:  # noqa: BLE001
+        errs.append(exc)
+
 threads = [threading.Thread(target=decode_loop) for _ in range(3)]
 threads.append(threading.Thread(target=keytab_loop))
+threads += [threading.Thread(target=i64_loop, args=(s,)) for s in (1, 2)]
 for t in threads:
     t.start()
 for t in threads:

@@ -223,3 +223,120 @@ def test_a_bid_without_a_bidder_groups_under_one_null_key(
         assert by_key == {1000: 2, 0: 1, 1001: 1, "" if device else None: 2}
     finally:
         api.rules.stop_all()
+
+
+def _encode_rows(topo):
+    """(the shared source's, the fused node's own) key-table rows by path."""
+    src = topo.live_shared()[0][0].source
+    fused = next(n for n in topo.ops
+                 if type(n).__name__ == "FusedWindowAggNode")
+    return dict(src.keytable_encode_rows()), dict(fused.keytable_encode_rows())
+
+
+def test_the_bigint_key_is_encoded_by_the_native_int_table(mock_clock):
+    """Every row after warm-up is slot-encoded on the `native_int` path —
+    also after a micro-batch with null bidders took the `hashed` one — and
+    the answers are the host operator path's, keys as JSON integers."""
+    from ekuiper_tpu.io import fastjson
+
+    if not fastjson.has_keytab("keytab_encode_i64"):
+        fastjson.ensure_native(background=False)
+    if not fastjson.has_keytab("keytab_encode_i64"):
+        pytest.skip("native key table unavailable (no toolchain)")
+    pool = seeded_pool(2 ** 31 + 39)
+    api, got = _start_rule("q12_int")
+    _, host = _start_rule("q12_int_host", {"use_device_kernel": False})
+    try:
+        topo = api.rules.state("q12_int").topo
+        _drive_window(mock_clock, pool.drains[:WINDOW_DRAINS], [got, host])
+        warm_src, _ = _encode_rows(topo)
+        assert warm_src["native_int"] == WINDOW_DRAINS * DRAIN, warm_src
+        # a drain whose every 8th bid has no bidder: one micro-batch's key
+        # column is an object column (None cells), the `hashed` path
+        holed = [json.dumps({**json.loads(r), "bidder": None}).encode()
+                 if i % 8 == 0 else r
+                 for i, r in enumerate(pool.drains[WINDOW_DRAINS])]
+        _drive_window(mock_clock, [holed], [got, host])
+        mid_src, _ = _encode_rows(topo)
+        assert mid_src["hashed"] == DRAIN
+        assert mid_src["native_int"] == warm_src["native_int"]
+        more = pool.drains[WINDOW_DRAINS + 1:3 * WINDOW_DRAINS + 1]
+        _drive_window(mock_clock, more[:WINDOW_DRAINS], [got, host])
+        _drive_window(mock_clock, more[WINDOW_DRAINS:], [got, host])
+        src_rows, own_rows = _encode_rows(topo)
+        n_more = len(more) * DRAIN
+        assert src_rows == {**mid_src,
+                            "native_int": mid_src["native_int"] + n_more}
+        assert src_rows["sorted"] == src_rows["native_str"] == 0
+        # the fused node mirrors new keys only, an all-int slice as int64
+        fused = next(n for n in topo.ops
+                     if type(n).__name__ == "FusedWindowAggNode")
+        fused._drain_async_emits()
+        assert sum(own_rows.values()) == fused.kt.n_keys
+        assert own_rows["native_int"] > 0 and own_rows["sorted"] == 0
+        assert all(type(k) is int or k == "" for k in fused.kt.decode_all())
+        # same answers as the host operator, window by window
+        assert len(got) == len(host) == 4
+        for dev_msgs, host_msgs in zip(got, host):
+            dev = {(m["bidder"], m["c"]) for m in dev_msgs}
+            assert dev == {("" if m["bidder"] is None else m["bidder"],
+                            m["c"]) for m in host_msgs}
+            assert all(type(b) is int or b == "" for b, _ in dev)
+        assert sum(m["c"] for m in got[1] if m["bidder"] == "") == DRAIN // 8
+        # ... and the counter where an operator reads it
+        status = topo.status()
+        assert [v for k, v in status.items()
+                if k.endswith("q12_bids_0_keytable_encode_rows")] == [src_rows]
+        _, text = api.dispatch("GET", "/metrics", None, {})
+        text = text if isinstance(text, str) else text.decode()
+        for path, n in src_rows.items():
+            assert ('kuiper_keytable_encode_rows_total{rule="__shared__",'
+                    f'op="q12_bids",path="{path}"}} {n}') in text
+        assert ('kuiper_keytable_encode_rows_total{rule="q12_int",'
+                f'op="{fused.name}",path="native_int"}} '
+                f'{own_rows["native_int"]}') in text
+    finally:
+        api.rules.stop_all()
+
+
+def test_without_the_native_int_table_the_mirror_keeps_the_order(
+        mock_clock, monkeypatch):
+    """No `keytab_encode_i64` (no toolchain, a stale module): the shared
+    table numbers a micro-batch's new bidders in sorted order, or first
+    seen when a null cell made the column an object column — the fused
+    node's mirror has to follow either, or its windows name other bidders
+    than the ones counted. Answers against the host operator path."""
+    import ekuiper_tpu.ops.keytable as ktmod
+
+    real = ktmod._native_keytab_module
+    monkeypatch.setattr(
+        ktmod, "_native_keytab_module",
+        lambda api="keytab_encode": None if api == "keytab_encode_i64"
+        else real(api))
+    pool = seeded_pool(2 ** 31 + 41)
+    api, got = _start_rule("q12_noint")
+    _, host = _start_rule("q12_noint_host", {"use_device_kernel": False})
+    try:
+        topo = api.rules.state("q12_noint").topo
+        holed = [json.dumps({**json.loads(r), "bidder": None}).encode()
+                 if i % 8 == 0 else r for i, r in enumerate(pool.drains[3])]
+        _drive_window(mock_clock, pool.drains[:3], [got, host])
+        _drive_window(mock_clock, [holed] + pool.drains[4:6], [got, host])
+        _drive_window(mock_clock, pool.drains[6:9], [got, host])
+        src_rows, own_rows = _encode_rows(topo)
+        assert src_rows == {"native_int": 0, "native_str": 0,
+                            "hashed": DRAIN, "sorted": 8 * DRAIN}
+        fused = next(n for n in topo.ops
+                     if type(n).__name__ == "FusedWindowAggNode")
+        fused._drain_async_emits()
+        assert fused._shared_slots_ok is True  # still riding the shared encode
+        assert own_rows["hashed"] == fused.kt.n_keys and own_rows["sorted"] == 0
+        nkt = fused._shared_nkt
+        assert fused.kt.decode_all() == nkt.keys_slice(0, fused.kt.n_keys)
+        assert len(got) == len(host) == 3
+        for dev_msgs, host_msgs in zip(got, host):
+            dev = {(m["bidder"], m["c"]) for m in dev_msgs}
+            assert dev == {("" if m["bidder"] is None else m["bidder"],
+                            m["c"]) for m in host_msgs}
+    finally:
+        api.rules.stop_all()

@@ -127,6 +127,8 @@ def _synthetic_scrape() -> str:
             self.sliding_triggers = {"fast": 2, "flip": 1, "dyn": 1}
             # ... and kuiper_sliding_tail_total from this one
             self.sliding_tails = {"device": 3, "host": 1}
+            # ... and kuiper_keytable_encode_rows_total from this method
+            self.keytable_encode_rows = lambda: {"native_int": 3, "sorted": 0}
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]
