@@ -88,6 +88,12 @@ STAGES = ("decode", "upload", "fold", "emit_combine", "sink",
 _STAGE_CANON = {"decode": "decode", "ring": "decode", "ingest": "decode",
                 "upload": "upload", "prep": "upload",
                 "fold": "fold", "host_expr": "host_expr",
+                # the fold's numpy mirror into an un-merged pre-issue's
+                # shadow, and a sliding rule's ring of panes and rows: what
+                # a micro-batch costs the folding thread beside `fold`
+                "shadow_fold": "fold", "slide_ring": "fold",
+                # after a boundary's emit: pane reset, the next tick's timers
+                "boundary_reset": "emit_combine",
                 "emit": "emit_combine", "sink": "sink"}
 
 #: classes whose UNSTAGED busy time is boundary work (finalize + window
@@ -439,18 +445,16 @@ class HealthEvaluator:
                 new_prev[node.name] = prev_s
                 continue
             new_prev[node.name] = cur_s
-            covered = 0.0
             best_stage, best_us = None, 0.0
             for stage, us in cur_s["stages"].items():
                 d = us - prev_s.get("stages", {}).get(stage, 0)
                 if d <= 0:
                     continue
-                covered += d
                 if stage.startswith("emit[") and stage.endswith("]"):
                     # shared-fold per-member emit stages
                     # (nodes_sharedfold stage="emit[<rule>]"): another
-                    # member's emit work is COVERED busy time (keep it
-                    # out of the unstaged remainder below) but must not
+                    # member's emit work is staged time (so not in the
+                    # unstaged remainder below) but must not
                     # be attributed to THIS rule's bottleneck
                     if stage[5:-1] != rid:
                         continue
@@ -462,7 +466,10 @@ class HealthEvaluator:
                 stage_us[canon] += d
                 if d > best_us:
                     best_stage, best_us = canon, d
-            rem = (cur_s["busy_us"] - prev_s.get("busy_us", 0)) - covered
+            # the worker's own ledger (utils/metrics.py cycle_end): its
+            # busy time outside every stage that closed on it — not busy
+            # less the stage rows, which other threads write too
+            rem = cur_s["unstaged_us"] - prev_s.get("unstaged_us", 0)
             if rem > 0:
                 op_type = getattr(node, "op_type", "op")
                 if op_type == "source":

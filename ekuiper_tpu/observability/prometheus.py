@@ -37,7 +37,19 @@ _COUNTERS = (
      "queue: starved (us)"),
     ("backpressure_us_total", "time senders waited for room in the op's "
      "full input queue, counted on their threads: blocked (us)"),
+    # the worker's cycle ledger: wall = idle + busy, busy = staged + unstaged
+    ("busy_us_total", "time the op's worker spent outside its get, a cycle "
+     "of its loop at a time (us): with idle_us_total, the worker's wall"),
+    ("busy_cpu_us_total", "thread-CPU time of the op's worker over the "
+     "same cycles (us)"),
+    ("unstaged_us_total", "busy time of the op's worker outside every "
+     "stage that closed on that thread (us)"),
+    ("unstaged_cpu_us_total", "thread-CPU time of the op's worker in its "
+     "cycles and outside every stage (us)"),
 )
+#: the one counter whose snapshot (and rule status) key is older than its
+#: metric name
+_SNAPSHOT_KEY = {"busy_us_total": "process_time_us_total"}
 _GAUGES = (
     ("buffer_length", "input queue occupancy"),
     ("process_latency_us", "last dispatch latency (engine clock, us)"),
@@ -106,9 +118,10 @@ def render(rule_registry) -> str:
 
     for mname, help_txt in _COUNTERS:
         _family(out, f"kuiper_op_{mname}", "counter", help_txt)
+        key = _SNAPSHOT_KEY.get(mname, mname)
         for rule_id, node, snap in snaps:
             out.append(f"kuiper_op_{mname}{{{op_labels(rule_id, node)}}} "
-                       f"{snap[mname]}")
+                       f"{snap[key]}")
     for mname, help_txt in _GAUGES:
         _family(out, f"kuiper_op_{mname}", "gauge", help_txt)
         for rule_id, node, snap in snaps:
@@ -226,6 +239,17 @@ def render(rule_registry) -> str:
             out.append(
                 f'kuiper_keytable_encode_rows_total{{rule="{_esc(rule_id)}",'
                 f'op="{_esc(node.name)}",path="{path}"}} {n}')
+    # host -> device staging of the window node's folds (ops/groupby.py
+    # `fold`): runtime calls made, what the `fold_h2d` stage's time buys
+    _family(out, "kuiper_fold_transfers_total", "counter",
+            "host->device runtime calls the staging of a window node's "
+            "folds made (padded columns, masks, slots, row count, pane)")
+    for rule_id, node in rows:
+        n = getattr(node, "fold_transfers", None)
+        if n is not None:
+            out.append(
+                f'kuiper_fold_transfers_total{{rule="{_esc(rule_id)}",'
+                f'op="{_esc(node.name)}"}} {n}')
     # shared pane folds (runtime/nodes_sharedfold.py): pool-level gauges —
     # members per store and the fold-dedup ratio (1 - folds run / folds N
     # private rules would have run). The store node's own op metrics (incl.

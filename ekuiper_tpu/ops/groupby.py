@@ -24,6 +24,7 @@ merges (parallel/) are elementwise add/min/max.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -42,6 +43,9 @@ _INIT = {
 }
 
 _WIDE_SIZE = {}  # filled lazily from sketches to avoid import cycle
+
+#: what a fold's staging runs inside when its caller hands no stage opener
+_NO_STAGE = contextlib.nullcontext()
 
 
 def _wide_size(comp: str) -> int:
@@ -129,6 +133,9 @@ class DeviceGroupBy:
         # counter rides the state pytree and is bumped inside the fold —
         # the placement policy's recency/frequency signal, no host sync
         self.track_touch = bool(track_touch)
+        # runtime calls the folds' host -> device staging has made
+        # (kuiper_fold_transfers_total); one writer, the folding thread
+        self.transfers_total = 0
         # component -> ordered spec indices holding a column in that array
         self.comp_specs: Dict[str, List[int]] = {}
         for i, spec in enumerate(plan.specs):
@@ -260,6 +267,7 @@ class DeviceGroupBy:
         valid: Optional[Dict[str, np.ndarray]] = None,
         pane_idx=0,
         n_rows: Optional[int] = None,
+        h2d: Optional[Callable[[int], Any]] = None,
     ) -> Dict[str, Any]:
         """Fold a host micro-batch into the device partials.
 
@@ -268,10 +276,11 @@ class DeviceGroupBy:
         pane_idx: the destination pane — a scalar (processing-time windows)
         or a per-row array (event-time windows route each row to its
         bucket's pane). Rows are chunked/padded to the static micro_batch
-        size.
+        size. h2d: the caller's stage opener (rows -> context manager): a
+        chunk's host -> device staging runs inside it, the jitted call
+        after it; `transfers_total` counts the staging's runtime calls.
         """
         import jax
-        import jax.numpy as jnp
 
         from .aggspec import materialize_hll_columns
 
@@ -290,59 +299,72 @@ class DeviceGroupBy:
             assert n <= mb, "pre-uploaded device inputs must be one chunk"
         for start in range(0, max(n, 1), mb):
             end = min(start + mb, n)
-            cnt = end - start
-            if cnt <= 0:
+            if end <= start:
                 break
-            pad = mb - cnt
-            dev_cols = {}
-            for name in self.plan.columns:
-                c = cols[name]
-                if isinstance(c, jax.Array):  # pre-padded shared upload
-                    dev_cols[name] = c
-                    dev_cols["__valid_" + name] = valid.get(name)
-                    continue
-                # kuiperlint: ignore[host-sync]: `c` is a HOST column here (device arrays took the pre-padded branch above) — this is H2D staging, not a sync
-                arr = np.asarray(c[start:end],
-                                 dtype=col_np_dtype(self.plan, name))
-                if pad:
-                    arr = np.pad(arr, (0, pad))
-                dev_cols[name] = jnp.asarray(arr)
-                vmask = valid.get(name)
-                if vmask is not None:
-                    vm = vmask[start:end]
-                    if pad:
-                        vm = np.pad(vm, (0, pad))
-                else:
-                    vm = None
-                dev_cols["__valid_" + name] = (
-                    jnp.asarray(vm) if vm is not None else None
-                )
-            if isinstance(slots, jax.Array):
-                s_dev = slots  # pre-padded + dtype-chosen by the sharer
-            else:
-                s = slots[start:end]
-                if pad:
-                    s = np.pad(s, (0, pad))
-                # upload-byte diet: slots ship as uint16 when capacity
-                # allows (halves the largest upload), and row validity
-                # ships as ONE scalar count compared against an iota on
-                # device instead of an mb-byte bool mask — HBM/link
-                # bandwidth is the bottleneck, not device compute
-                s_dev = jnp.asarray(
-                    s.astype(slot_dtype(self.capacity), copy=False))
-            if isinstance(pane_idx, np.ndarray):
-                pv = pane_idx[start:end]
-                if pad:
-                    pv = np.pad(pv, (0, pad))
-                pane_arg = jnp.asarray(pv.astype(np.uint8))  # n_panes <= 255
-            else:
-                pane_arg = jnp.asarray(pane_idx, dtype=jnp.int32)
-            state = self._fold(
-                state, dev_cols, s_dev,
-                jnp.asarray(cnt, dtype=jnp.int32),
-                pane_arg,
-            )
+            with (h2d(end - start) if h2d is not None else _NO_STAGE):
+                staged = self._stage_chunk(cols, slots, valid, pane_idx,
+                                           start, end)
+            state = self._fold(state, *staged)
         return state
+
+    def _stage_chunk(self, cols, slots, valid, pane_idx, start: int,
+                     end: int):
+        """Host -> device staging of rows [start:end): pad to the static
+        micro-batch, cast, one runtime call an array that is not on the
+        device yet. Returns the jitted fold's arguments after the state."""
+        import jax
+        import jax.numpy as jnp
+
+        cnt = end - start
+        pad = self.micro_batch - cnt
+        calls = 2  # the row count and the pane, below
+        dev_cols = {}
+        for name in self.plan.columns:
+            c = cols[name]
+            if isinstance(c, jax.Array):  # pre-padded shared upload
+                dev_cols[name] = c
+                dev_cols["__valid_" + name] = valid.get(name)
+                continue
+            # kuiperlint: ignore[host-sync]: `c` is a HOST column here (device arrays took the pre-padded branch above) — this is H2D staging, not a sync
+            arr = np.asarray(c[start:end],
+                             dtype=col_np_dtype(self.plan, name))
+            if pad:
+                arr = np.pad(arr, (0, pad))
+            dev_cols[name] = jnp.asarray(arr)
+            calls += 1
+            vmask = valid.get(name)
+            if vmask is not None:
+                vm = vmask[start:end]
+                if pad:
+                    vm = np.pad(vm, (0, pad))
+                dev_cols["__valid_" + name] = jnp.asarray(vm)
+                calls += 1
+            else:
+                dev_cols["__valid_" + name] = None
+        if isinstance(slots, jax.Array):
+            s_dev = slots  # pre-padded + dtype-chosen by the sharer
+        else:
+            s = slots[start:end]
+            if pad:
+                s = np.pad(s, (0, pad))
+            # upload-byte diet: slots ship as uint16 when capacity
+            # allows (halves the largest upload), and row validity
+            # ships as ONE scalar count compared against an iota on
+            # device instead of an mb-byte bool mask — HBM/link
+            # bandwidth is the bottleneck, not device compute
+            s_dev = jnp.asarray(
+                s.astype(slot_dtype(self.capacity), copy=False))
+            calls += 1
+        if isinstance(pane_idx, np.ndarray):
+            pv = pane_idx[start:end]
+            if pad:
+                pv = np.pad(pv, (0, pad))
+            pane_arg = jnp.asarray(pv.astype(np.uint8))  # n_panes <= 255
+        else:
+            pane_arg = jnp.asarray(pane_idx, dtype=jnp.int32)
+        n_valid = jnp.asarray(cnt, dtype=jnp.int32)
+        self.transfers_total += calls
+        return dev_cols, s_dev, n_valid, pane_arg
 
     def _fold_impl(self, state, cols, slots, n_valid, pane_idx):
         import jax

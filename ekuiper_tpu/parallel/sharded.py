@@ -29,12 +29,12 @@ rule is single-process) is the psum over "rows".
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from ..ops.aggspec import KernelPlan, WIDE_COMPONENTS
-from ..ops.groupby import DeviceGroupBy, _INIT
+from ..ops.groupby import DeviceGroupBy, _INIT, _NO_STAGE
 
 
 class ShardedGroupBy(DeviceGroupBy):
@@ -489,15 +489,16 @@ class ShardedGroupBy(DeviceGroupBy):
         valid: Optional[Dict[str, np.ndarray]] = None,
         pane_idx: int = 0,
         n_rows: Optional[int] = None,
+        h2d: Optional[Callable[[int], Any]] = None,
     ) -> Dict[str, Any]:
         """Host entry: chunk/pad to the static micro_batch, upload with
         row shardings, run the SPMD step. Signature matches DeviceGroupBy
         so FusedWindowAggNode drives either interchangeably (n_rows is the
         pre-padded-inputs convention — the mesh-aware ingest prep hands
         columns/slots already padded AND placed with this kernel's row
-        sharding, single-chunk by contract; host arrays re-pad here)."""
+        sharding, single-chunk by contract; host arrays re-pad here; h2d
+        is the caller's stage opener around each chunk's placement)."""
         import jax
-        import jax.numpy as jnp
 
         from ..ops.aggspec import materialize_hll_columns
 
@@ -510,120 +511,80 @@ class ShardedGroupBy(DeviceGroupBy):
             # host slot vector; the prep path's device slots are counted
             # by the driving node (it still holds the host vector)
             self.note_rows(slots, n)
-        pane_vec = pane_idx if isinstance(pane_idx, np.ndarray) else None
-        if pane_vec is not None and self._fold_vec is None:
+        vec = isinstance(pane_idx, np.ndarray)
+        if vec and self._fold_vec is None:
             self._fold_vec = self._build_fold_vec()
-        pane = None if pane_vec is not None else self._put(
-            jnp.asarray(pane_idx, dtype=jnp.int32), self.scalar_sharding
-        )
         # pre-padded device inputs (runtime/ingest.py pad_*_for_device
-        # with this kernel's shardings): single-chunk by contract — use
-        # them as-is, fill absent masks with the cached all-true buffer
+        # with this kernel's shardings): single-chunk by contract — used
+        # as they are
         has_dev = isinstance(slots, jax.Array) or any(
             isinstance(cols.get(name), jax.Array)
             for name in self.plan.columns)
         if has_dev:
             assert n <= mb, "pre-uploaded device inputs must be one chunk"
-            if n <= 0:
-                return state
-            dev_cols = {}
-            for name in self.plan.columns:
-                c = cols[name]
-                if isinstance(c, jax.Array):
-                    dev_cols[name] = c
-                else:
-                    arr = np.asarray(c[:n], dtype=np.float32)
-                    if n < mb:
-                        arr = np.pad(arr, (0, mb - n))
-                    dev_cols[name] = self._put(arr, self.batch_sharding)
-                vm = valid.get(name)
-                if isinstance(vm, jax.Array):
-                    dev_cols["__valid_" + name] = vm
-                elif vm is not None:
-                    m = np.asarray(vm[:n], dtype=np.bool_)
-                    if n < mb:
-                        m = np.pad(m, (0, mb - n))
-                    dev_cols["__valid_" + name] = self._put(
-                        m, self.batch_sharding)
-                else:
-                    if self._all_true is None:
-                        self._all_true = self._put(
-                            np.ones(mb, dtype=np.bool_),
-                            self.batch_sharding)
-                    dev_cols["__valid_" + name] = self._all_true
-            if isinstance(slots, jax.Array):
-                s_dev = slots
-            else:
-                s = np.asarray(slots[:n], dtype=np.int32)
-                if n < mb:
-                    s = np.pad(s, (0, mb - n))
-                s_dev = self._put(s, self.batch_sharding)
-            rv = np.zeros(mb, dtype=np.bool_)
-            rv[:n] = True
-            rv_dev = self._put(rv, self.batch_sharding)
-            if pane_vec is not None:
-                pv = np.asarray(pane_vec[:n], dtype=np.int32)
-                if n < mb:
-                    pv = np.pad(pv, (0, mb - n))
-                return self._fold_vec(
-                    state, dev_cols, s_dev, rv_dev,
-                    self._put(pv, self.batch_sharding))
-            return self._fold(state, dev_cols, s_dev, rv_dev, pane)
         for start in range(0, max(n, 1), mb):
             end = min(start + mb, n)
-            cnt = end - start
-            if cnt <= 0:
+            if end <= start:
                 break
-            pad = mb - cnt
-            dev_cols = {}
-            for name in self.plan.columns:
-                arr = np.asarray(cols[name][start:end], dtype=np.float32)
-                if pad:
-                    arr = np.pad(arr, (0, pad))
-                dev_cols[name] = self._put(arr, self.batch_sharding)
-                # masks are always materialized (all-true when absent) so the
-                # shard_map pytree structure is static across batches; the
-                # all-true mask is one cached device buffer, not a per-batch
-                # host allocation + upload
-                vmask = valid.get(name)
-                if vmask is not None:
-                    vm = np.asarray(vmask[start:end], dtype=np.bool_)
-                    if pad:
-                        vm = np.pad(vm, (0, pad))
-                    dev_cols["__valid_" + name] = self._put(
-                        vm, self.batch_sharding
-                    )
-                else:
-                    if self._all_true is None:
-                        self._all_true = self._put(
-                            np.ones(mb, dtype=np.bool_), self.batch_sharding
-                        )
-                    dev_cols["__valid_" + name] = self._all_true
-            s = np.asarray(slots[start:end], dtype=np.int32)
-            if pad:
-                s = np.pad(s, (0, pad))
-            rv = np.zeros(mb, dtype=np.bool_)
-            rv[:cnt] = True
-            if pane_vec is not None:
-                pv = np.asarray(pane_vec[start:end], dtype=np.int32)
-                if pad:
-                    pv = np.pad(pv, (0, pad))  # padded rows masked by rv
-                state = self._fold_vec(
-                    state,
-                    dev_cols,
-                    self._put(s, self.batch_sharding),
-                    self._put(rv, self.batch_sharding),
-                    self._put(pv, self.batch_sharding),
-                )
-            else:
-                state = self._fold(
-                    state,
-                    dev_cols,
-                    self._put(s, self.batch_sharding),
-                    self._put(rv, self.batch_sharding),
-                    pane,
-                )
+            with (h2d(end - start) if h2d is not None else _NO_STAGE):
+                staged = self._stage_chunk(cols, slots, valid, pane_idx,
+                                           start, end)
+            state = (self._fold_vec if vec else self._fold)(state, *staged)
         return state
+
+    def _stage_chunk(self, cols, slots, valid, pane_idx, start: int,
+                     end: int):
+        """Placement of rows [start:end) across the mesh: pad to the
+        static micro-batch, one `_put` an array that is not placed yet.
+        Returns the SPMD step's arguments after the state."""
+        import jax
+        import jax.numpy as jnp
+
+        mb = self.micro_batch
+        cnt = end - start
+        pad = mb - cnt
+        calls = 0
+
+        def put(arr, dtype):
+            nonlocal calls
+            calls += 1
+            arr = np.asarray(arr[start:end], dtype=dtype)
+            if pad:
+                arr = np.pad(arr, (0, pad))  # padded rows masked by rv
+            return self._put(arr, self.batch_sharding)
+
+        dev_cols = {}
+        for name in self.plan.columns:
+            c = cols[name]
+            dev_cols[name] = (c if isinstance(c, jax.Array)
+                              else put(c, np.float32))
+            # masks are always materialized (all-true when absent) so the
+            # shard_map pytree structure is static across batches; the
+            # all-true mask is one cached device buffer, not a per-batch
+            # host allocation + upload
+            vm = valid.get(name)
+            if isinstance(vm, jax.Array):
+                dev_cols["__valid_" + name] = vm
+            elif vm is not None:
+                dev_cols["__valid_" + name] = put(vm, np.bool_)
+            else:
+                if self._all_true is None:
+                    self._all_true = self._put(
+                        np.ones(mb, dtype=np.bool_), self.batch_sharding)
+                dev_cols["__valid_" + name] = self._all_true
+        s_dev = (slots if isinstance(slots, jax.Array)
+                 else put(slots, np.int32))
+        rv = np.zeros(mb, dtype=np.bool_)
+        rv[:cnt] = True
+        rv_dev = self._put(rv, self.batch_sharding)
+        if isinstance(pane_idx, np.ndarray):
+            pane = put(pane_idx, np.int32)
+        else:
+            pane = self._put(jnp.asarray(pane_idx, dtype=jnp.int32),
+                             self.scalar_sharding)
+            calls += 1
+        self.transfers_total += calls + 1  # + the row mask
+        return dev_cols, s_dev, rv_dev, pane
 
     # finalize / reset_pane / state_to_host / observe_dtypes inherited from
     # DeviceGroupBy: they are plain jit over the (sharded) state arrays, so

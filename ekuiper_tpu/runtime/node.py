@@ -288,17 +288,24 @@ class Node:
         self.on_worker_start()
         try:
             stats = self.stats
+            # the worker's wall, cut by one clock: idle while it waits in
+            # get(), a cycle of the ledger (StatManager.cycle_begin) from
+            # there to the next wait — the dispatch, this loop's own
+            # bookkeeping and the release of the item
+            t_mark = _time.perf_counter_ns()
             while not self._stop.is_set():
                 # starved time (kuiper_op_idle_us_total): what the worker
                 # spends in get() with nothing to dispatch
-                t_idle = _time.perf_counter_ns()
                 try:
                     entry = self.inq.get(timeout=0.2)
                 except queue.Empty:
                     continue
                 finally:
-                    stats.idle_us_total += (
-                        _time.perf_counter_ns() - t_idle) // 1000
+                    now = _time.perf_counter_ns()
+                    stats.idle_us_total += (now - t_mark) // 1000
+                    t_mark = now
+                stats.cycle_begin(now)
+                item = None
                 if self._enq_times:
                     try:
                         self.stats.observe_queue_wait(
@@ -316,8 +323,16 @@ class Node:
                     self.stats.set_buffer_length(self.inq.qsize())
                     self._dispatch(item, from_name)
                 finally:
+                    rows = getattr(item, "n", None)
+                    if isinstance(rows, int) and rows > 1:
+                        # a batch dies where its last reference goes: here,
+                        # unless another thread still holds it — an object
+                        # column's strings, its shared device uploads
+                        with stats.stage("release", rows):
+                            entry = item = None
                     # unfinished_tasks accounting backs Topo.wait_idle()
                     self.inq.task_done()
+                    t_mark = stats.cycle_end()
         finally:
             self.on_close()
 

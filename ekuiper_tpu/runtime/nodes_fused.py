@@ -685,8 +685,8 @@ class FusedWindowAggNode(Node):
         end = batch.n if end is None else end
         if end <= start:
             return 0
-        idx = np.arange(start, end)
-        sub = batch if (start == 0 and end == batch.n) else batch.take(idx)
+        sub = (batch if (start == 0 and end == batch.n)
+               else batch.take(np.arange(start, end)))
         if self.is_event_time and self.wt not in (
                 ast.WindowType.COUNT_WINDOW, ast.WindowType.STATE_WINDOW):
             # event-time COUNT/STATE fold like processing time: the
@@ -998,14 +998,27 @@ class FusedWindowAggNode(Node):
             self._dtypes_seen = True
         return cols, valid, slots
 
+    def _fold_h2d(self, rows: int):
+        """The stage a fold's host -> device staging runs in (handed to
+        `gb.fold`, which opens it once a chunk, inside `fold`)."""
+        return self.stats.stage("fold_h2d", rows, within="fold")
+
+    @property
+    def fold_transfers(self) -> int:
+        """Runtime calls that staging has made
+        (kuiper_fold_transfers_total)."""
+        return self.gb.transfers_total
+
     def _fold_rows(self, sub: ColumnBatch, pane_arg) -> int:
         """Encode keys + build kernel columns + device fold for `sub`,
         folding into `pane_arg` (scalar pane or per-row pane vector).
         Stage accounting: "upload" covers key encode + kernel-input build +
         shared device puts (the host-side work feeding the link), "fold"
-        the jitted fold dispatch (which carries the implicit H2D copy when
-        inputs weren't pre-uploaded) — together with the source's "decode"
-        these expose the ingest-pipeline balance per node."""
+        the whole `gb.fold` — of which "fold_h2d", nested, is the staging
+        of what was not pre-uploaded and the rest the jitted dispatch —,
+        "shadow_fold" the numpy mirror into the un-merged pre-issues'
+        shadows; together with the source's "decode" these expose the
+        ingest-pipeline balance per node."""
         with self.stats.stage("upload", sub.n):
             cols, valid, slots = self._build_kernel_inputs(sub)
             if self.gb.capacity < self.kt.capacity:
@@ -1028,10 +1041,12 @@ class FusedWindowAggNode(Node):
                 self.state = self.gb.fold(
                     self.state, {**cols, **dcols},
                     dslots if dslots is not None else slots,
-                    {**valid, **dvalid}, pane_arg, n_rows=sub.n)
+                    {**valid, **dvalid}, pane_arg, n_rows=sub.n,
+                    h2d=self._fold_h2d)
             else:
                 self.state = self.gb.fold(self.state, cols, slots,
-                                          valid, pane_arg)
+                                          valid, pane_arg,
+                                          h2d=self._fold_h2d)
         if hasattr(self.gb, "note_rows"):
             # per-shard accounting (kuiper_shard_*): the kernel counts
             # host slot vectors itself; the prep path hands it DEVICE
@@ -1041,9 +1056,12 @@ class FusedWindowAggNode(Node):
                 self.gb.note_rows(slots, sub.n, n_keys=self.kt.n_keys)
             else:
                 self.gb.n_keys_hint = self.kt.n_keys
-        # every un-merged pre-issue's shadow mirrors the fold
-        for _, shadow in self._pipeline:
-            shadow.fold(cols, slots, valid)
+        if self._pipeline:
+            # every un-merged pre-issue's shadow mirrors the fold
+            with self.stats.stage("shadow_fold",
+                                  sub.n * len(self._pipeline)):
+                for _, shadow in self._pipeline:
+                    shadow.fold(cols, slots, valid)
         return sub.n
 
     # ------------------------------------------------------------ event time
@@ -1184,7 +1202,8 @@ class FusedWindowAggNode(Node):
                     self._emit_count_async(wr)
                 else:
                     self._emit(wr)
-                self.state = self.gb.reset_pane(self.state, 0)
+                with self.stats.stage("boundary_reset"):
+                    self.state = self.gb.reset_pane(self.state, 0)
                 self._rows_in_window = 0
 
     # ---------------------------------------------------------- state window
@@ -1880,35 +1899,16 @@ class FusedWindowAggNode(Node):
         self._rg_closes += 1
         self._rg_closed = b
 
-    def _fold_sliding(self, sub: ColumnBatch) -> int:
-        """Sliding device path: fold rows into time panes keyed by row
-        timestamp, mirror them into the host ring (for edge-bucket refolds
-        at emission), and fire trigger rows."""
-        ts = sub.timestamps
-        if ts is None:
-            now = timex.now_ms()
-            ts = np.full(sub.n, now, dtype=np.int64)
-        buckets = ts // self.bucket_ms
-        # a single batch spanning >= n_ring_panes buckets would alias two
-        # buckets onto one pane WITHIN one fold call (replay/backfill
-        # bursts); split into alias-free chunks folded in bucket order so
-        # each recycle lands before its pane receives new rows
-        if int(buckets.max() - buckets.min()) >= self.n_ring_panes:
-            order = np.argsort(buckets, kind="stable")
-            sorted_b = buckets[order]
-            start = 0
-            base = int(sorted_b[0])
-            for i in range(1, len(order) + 1):
-                if i == len(order) or int(sorted_b[i]) - base >= self.n_ring_panes:
-                    self._fold_sliding(sub.take(order[start:i]))
-                    if i < len(order):
-                        base = int(sorted_b[i])
-                        start = i
-            return sub.n
-        # late guard: drop a row ONLY when its pane has been recycled past
-        # its bucket (folding it would corrupt newer live data). Rows merely
-        # out of order — pane still holds their bucket, or an older one the
-        # recycle loop will reset — fold exactly like the host path.
+    def _ring_admit(self, sub: ColumnBatch, ts, buckets):
+        """What a micro-batch's buckets do to the ring before its rows are
+        folded: the late guard, pane recycling, expiry of retained rows.
+        Returns (sub, ts, buckets) without the rows that came too late, or
+        None when none is left."""
+        # late guard: drop a row ONLY when its pane has been recycled
+        # past its bucket (folding it would corrupt newer live data).
+        # Rows merely out of order — pane still holds their bucket, or
+        # an older one the recycle loop will reset — fold exactly like
+        # the host path.
         if self._ring_max_bucket >= 0:
             drop_buckets = []
             for b in np.unique(buckets).tolist():
@@ -1923,14 +1923,15 @@ class FusedWindowAggNode(Node):
                     detail="sliding pane retention")
                 keep = np.nonzero(~late)[0]
                 if len(keep) == 0:
-                    return 0
+                    return None
                 sub = sub.take(keep)
                 ts = ts[keep]
                 buckets = buckets[keep]
-        # recycle panes: reset any pane about to receive a newer bucket.
-        # The recycled bucket's ROWS stay in the ring a while longer — a
-        # trigger whose window still needs that bucket detects the recycled
-        # pane and refolds the whole window from the ring (exact fallback)
+        # recycle panes: reset any pane about to receive a newer
+        # bucket. The recycled bucket's ROWS stay in the ring a while
+        # longer — a trigger whose window still needs that bucket
+        # detects the recycled pane and refolds the whole window from
+        # the ring (exact fallback)
         for b in np.unique(buckets).tolist():
             pane = int(b) % self.n_ring_panes
             held = self._pane_bucket.get(pane)
@@ -1939,8 +1940,9 @@ class FusedWindowAggNode(Node):
             self._pane_bucket[pane] = int(b)
         self._ring_max_bucket = max(self._ring_max_bucket,
                                     int(buckets.max()))
-        # ring outlives panes by a margin so the stale-window fallback can
-        # always reconstruct; beyond that the window is unrecoverable anyway
+        # ring outlives panes by a margin so the stale-window fallback
+        # can always reconstruct; beyond that the window is
+        # unrecoverable anyway
         floor_b = self._ring_max_bucket - self.n_ring_panes - 8
         expired = [b for b in self._ring if b < floor_b]
         for b in expired:
@@ -1951,11 +1953,50 @@ class FusedWindowAggNode(Node):
                     self._dev_entry_nbytes(e) for e in dropped)
             self._bucket_max_ts.pop(b, None)
         if expired:
-            # purge the expired buckets' fifo bookkeeping too: the evict
-            # loop only drains it when OVER budget, so an under-budget rule
-            # would otherwise grow the deque for the life of the stream
+            # purge the expired buckets' fifo bookkeeping too: the
+            # evict loop only drains it when OVER budget, so an
+            # under-budget rule would otherwise grow the deque for the
+            # life of the stream
             self._dev_ring_fifo = type(self._dev_ring_fifo)(
                 t for t in self._dev_ring_fifo if t[0] >= floor_b)
+        return sub, ts, buckets
+
+    def _fold_sliding(self, sub: ColumnBatch) -> int:
+        """Sliding device path: fold rows into time panes keyed by row
+        timestamp, mirror them into the host ring (for edge-bucket refolds
+        at emission), and fire trigger rows."""
+        # `slide_ring`: what a micro-batch costs this thread for the window
+        # being a ring of panes and retained rows, outside `upload`, `fold`,
+        # `slide_advance` and `slide_edge` — here the stamps' buckets, the
+        # guard, the recycle and the expiry, below the append and the
+        # trigger mask
+        with self.stats.stage("slide_ring"):
+            ts = sub.timestamps
+            if ts is None:
+                now = timex.now_ms()
+                ts = np.full(sub.n, now, dtype=np.int64)
+            buckets = ts // self.bucket_ms
+            aliased = int(buckets.max() - buckets.min()) >= self.n_ring_panes
+            kept = None if aliased else self._ring_admit(sub, ts, buckets)
+        # a single batch spanning >= n_ring_panes buckets would alias two
+        # buckets onto one pane WITHIN one fold call (replay/backfill
+        # bursts); split into alias-free chunks folded in bucket order so
+        # each recycle lands before its pane receives new rows
+        if aliased:
+            order = np.argsort(buckets, kind="stable")
+            sorted_b = buckets[order]
+            start = 0
+            base = int(sorted_b[0])
+            for i in range(1, len(order) + 1):
+                if i == len(order) or int(sorted_b[i]) - base >= self.n_ring_panes:
+                    self._fold_sliding(sub.take(order[start:i]))
+                    if i < len(order):
+                        base = int(sorted_b[i])
+                        start = i
+            return sub.n
+        if kept is None:
+            return 0
+        sub, ts, buckets = kept
         daba = self.sliding_impl == "daba"
         with self.stats.stage("upload", sub.n):
             cols, valid, slots = self._build_kernel_inputs(sub)
@@ -1975,39 +2016,44 @@ class FusedWindowAggNode(Node):
             pane_arg = (int(pane_vec[0]) if len(np.unique(pane_vec)) == 1
                         else pane_vec)
             self.state = self.gb.fold(self.state, fold_cols, fold_slots,
-                                      fold_valid, pane_arg, n_rows=n_rows)
+                                      fold_valid, pane_arg, n_rows=n_rows,
+                                      h2d=self._fold_h2d)
         if hasattr(self.gb, "note_rows"):
             self.gb.n_keys_hint = self.kt.n_keys  # fold counted host slots
-        for b in np.unique(buckets).tolist():
-            m = buckets == b
-            sel = np.nonzero(m)[0]
-            seg = (
-                {k: v[sel] for k, v in cols.items()},
-                {k: v[sel] for k, v in valid.items()},
-                slots[sel], ts[sel],
-            ) if not m.all() else (cols, valid, slots, ts)
-            self._ring.setdefault(int(b), []).append(seg)
-            if not daba:
-                # aligned device entry: whole-batch refs + this bucket's
-                # row mask (the refold ANDs the window time cut into it)
-                entry = None if dev is None else (dev[3], dev[2], m, ts)
-                lst = self._dev_ring.setdefault(int(b), [])
-                lst.append(entry)
-                if entry is not None:
-                    nb = self._dev_entry_nbytes(entry)
-                    self._dev_ring_bytes += nb
-                    self._dev_ring_fifo.append((int(b), len(lst) - 1, nb))
-                    self._dev_ring_evict()
-            bmax = int(ts[sel].max())
-            if bmax > self._bucket_max_ts.get(int(b), -1):
-                self._bucket_max_ts[int(b)] = bmax
+        with self.stats.stage("slide_ring", sub.n):
+            for b in np.unique(buckets).tolist():
+                m = buckets == b
+                sel = np.nonzero(m)[0]
+                seg = (
+                    {k: v[sel] for k, v in cols.items()},
+                    {k: v[sel] for k, v in valid.items()},
+                    slots[sel], ts[sel],
+                ) if not m.all() else (cols, valid, slots, ts)
+                self._ring.setdefault(int(b), []).append(seg)
+                if not daba:
+                    # aligned device entry: whole-batch refs + this
+                    # bucket's row mask (the refold ANDs the window time
+                    # cut into it)
+                    entry = (None if dev is None
+                             else (dev[3], dev[2], m, ts))
+                    lst = self._dev_ring.setdefault(int(b), [])
+                    lst.append(entry)
+                    if entry is not None:
+                        nb = self._dev_entry_nbytes(entry)
+                        self._dev_ring_bytes += nb
+                        self._dev_ring_fifo.append(
+                            (int(b), len(lst) - 1, nb))
+                        self._dev_ring_evict()
+                bmax = int(ts[sel].max())
+                if bmax > self._bucket_max_ts.get(int(b), -1):
+                    self._bucket_max_ts[int(b)] = bmax
+            # trigger rows: vectorized OVER(WHEN ...) on the raw batch columns
+            trig_mask = _host_mask(self._trigger_host, sub.columns, sub.n)
         if daba:
             self._ring_advance_buckets(buckets)
         # tier maintenance at bucket granularity (sliding's pane
         # boundary): throttled by the scan cadence inside
         self._tier_boundary()
-        # trigger rows: vectorized OVER(WHEN ...) on the raw batch columns;
-        trig_mask = _host_mask(self._trigger_host, sub.columns, sub.n)
         for i in np.nonzero(trig_mask)[0].tolist():
             t = int(ts[i])
             if self.delay_ms > 0:
@@ -2206,8 +2252,10 @@ class FusedWindowAggNode(Node):
                     self.state, dev_all, s_dev, m, self._scratch_pane)
             else:
                 _, cols, valid, slots = entry
-                self.state = self.gb.fold(self.state, cols, slots, valid,
-                                          self._scratch_pane)
+                with self.stats.stage("fold", len(slots)):
+                    self.state = self.gb.fold(
+                        self.state, cols, slots, valid, self._scratch_pane,
+                        h2d=self._fold_h2d)
             used_scratch = True
         panes = sorted({b % self.n_ring_panes for b in full})
         if used_scratch:
@@ -2519,14 +2567,19 @@ class FusedWindowAggNode(Node):
         # spilled (cold-tier) keys with live pane data contribute to this
         # window host-side, BEFORE the pane expiry marks them stale
         self._emit_tier_extras(wr)
-        if self.wt == ast.WindowType.TUMBLING_WINDOW:
-            self._reset_pane_tiered(0)
-        else:
-            # advance to the next pane; expire it (it held the oldest slice)
-            self.cur_pane = (self.cur_pane + 1) % self.n_panes
-            self._reset_pane_tiered(self.cur_pane)
-        self._tier_boundary()
-        self._schedule_next_tick()
+        # what the boundary leaves this thread to do once the window is on
+        # its way: the pane reset's dispatch, tier upkeep, the next tick's
+        # timers (a thread each)
+        with self.stats.stage("boundary_reset"):
+            if self.wt == ast.WindowType.TUMBLING_WINDOW:
+                self._reset_pane_tiered(0)
+            else:
+                # advance to the next pane; expire it (it held the oldest
+                # slice)
+                self.cur_pane = (self.cur_pane + 1) % self.n_panes
+                self._reset_pane_tiered(self.cur_pane)
+            self._tier_boundary()
+            self._schedule_next_tick()
 
     def on_eof(self, eof: EOF) -> None:
         if self.is_event_time and self.wt == ast.WindowType.SESSION_WINDOW:

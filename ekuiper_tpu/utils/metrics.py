@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import threading
 import time as _time
+from threading import get_ident as _get_ident
 from typing import Any, Dict, Optional
 
 from ..observability.histogram import LatencyHistogram
@@ -30,10 +31,15 @@ class _Stage:
     span is open on the thread. `rows` may be set inside the body when
     the count is known only afterwards. `since_ns` (perf clock) starts the
     wall interval earlier than the body — at a dispatch made on another
-    thread, whose completion the body waits for."""
+    thread, whose completion the body waits for.
+
+    A counted stage that closes on the thread of its node's open cycle
+    with no other counted stage open around it also adds its body's wall
+    and CPU to that cycle's `staged` time (the cycle ledger,
+    `StatManager.cycle_end`): one writer, no lock."""
 
     __slots__ = ("sm", "name", "rows", "attrs", "counted", "since_ns",
-                 "_ann", "_span", "_t0", "_c0")
+                 "_ann", "_span", "_t0", "_c0", "_own")
 
     def __init__(self, sm: "StatManager", name: str, rows: int,
                  attrs: Optional[dict], counted: bool,
@@ -61,19 +67,31 @@ class _Stage:
             if tracer is not None and tracer.current() is not None
             and tracer.is_enabled(sm.rule_id) else None)
         if self.counted:
+            self._own = own = _get_ident() == sm._dispatch_tid
+            if own:
+                sm._dispatch_depth += 1
             # wall read outside the CPU read on both ends: the CPU interval
             # lies inside the wall interval, so cpu <= wall per call. (The
             # CPU clock is a system call; a sub-stage does not pay for it.)
-            self._t0 = self.since_ns or _time.perf_counter_ns()
+            self._t0 = _time.perf_counter_ns()
             self._c0 = _time.thread_time_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         if self.counted:
+            sm = self.sm
             cpu_ns = _time.thread_time_ns() - self._c0
-            wall_ns = _time.perf_counter_ns() - self._t0
-            self.sm.observe_stage(self.name, wall_ns // 1000, self.rows,
-                                  cpu_ns // 1000)
+            now_ns = _time.perf_counter_ns()
+            if self._own:
+                sm._dispatch_depth -= 1
+                if sm._dispatch_depth == 0:
+                    # the body's own wall: `since_ns` reaches back before
+                    # this cycle began
+                    sm._staged_ns += now_ns - self._t0
+                    sm._staged_cpu_ns += cpu_ns
+            sm.observe_stage(
+                self.name, (now_ns - (self.since_ns or self._t0)) // 1000,
+                self.rows, cpu_ns // 1000)
         span = self._span
         if span is not None:
             span.rows = self.rows
@@ -106,8 +124,27 @@ class StatManager:
         self.last_invocation: int = 0
         self.process_latency_us: int = 0
         # cumulative busy time (wall-clock in-process), the engine's
-        # per-rule CPU-usage proxy (reference: /rules/usage/cpu)
+        # per-rule CPU-usage proxy (reference: /rules/usage/cpu): the
+        # worker's time outside its `get`, one cycle of its loop at a time
         self.process_time_us_total: int = 0
+        # the cycle ledger of the node's worker (docs/OBSERVABILITY.md,
+        # "Pipeline stages"): worker wall = idle + staged + unstaged.
+        # `busy` above is staged + unstaged; `staged` is the wall of the
+        # outermost counted stages that closed on the worker's thread
+        # during a cycle (another thread's stages on this node add
+        # nothing), `unstaged` what is left of each cycle, accrued in
+        # cycle_end; the same in thread-CPU time.
+        self.busy_cpu_us_total: int = 0
+        self.unstaged_us_total: int = 0
+        self.unstaged_cpu_us_total: int = 0
+        self._dispatch_tid: int = 0  # thread of the open cycle, else 0
+        self._dispatch_depth: int = 0  # counted stages open on that thread
+        self._staged_ns: int = 0  # of the open cycle
+        self._staged_cpu_ns: int = 0
+        self._cycle_t0: int = 0
+        self._cycle_c0: int = 0
+        self._own_cycle = False  # the open dispatch opened the cycle itself
+        self._dispatch_ann = None  # the open dispatch's profiler bracket
         self.buffer_length: int = 0
         # between the stages (runtime/node.py): time this node's worker
         # waited in its empty input queue, and time senders waited in this
@@ -211,8 +248,51 @@ class StatManager:
                 threshold=crossed,
                 **({"detail": detail} if detail else {}))
 
+    def cycle_begin(self, t_ns: Optional[int] = None) -> None:
+        """Open the ledger's books on the calling thread: the worker loop
+        does at the return of its `get` (`t_ns`, the clock reading that
+        closed its idle time), a dispatch made outside that loop at its
+        `process_begin`."""
+        self._staged_ns = self._staged_cpu_ns = 0
+        self._dispatch_depth = 0
+        self._dispatch_tid = _get_ident()
+        self._cycle_c0 = _time.thread_time_ns()
+        self._cycle_t0 = t_ns or _time.perf_counter_ns()
+
+    def cycle_end(self) -> int:
+        """Close them: everything since `cycle_begin` is busy time, and
+        what no stage of this thread covered is unstaged. Returns the clock
+        reading, where the worker's idle time starts again."""
+        cpu_ns = _time.thread_time_ns() - self._cycle_c0
+        now_ns = _time.perf_counter_ns()
+        self._dispatch_tid = 0
+        busy_us = (now_ns - self._cycle_t0) // 1000
+        cpu_us = cpu_ns // 1000
+        # stage intervals lie inside the cycle's on both clocks, so neither
+        # remainder is negative
+        unstaged_us = busy_us - self._staged_ns // 1000
+        unstaged_cpu_us = cpu_us - self._staged_cpu_ns // 1000
+        with self._lock:
+            self.process_time_us_total += busy_us
+            self.busy_cpu_us_total += cpu_us
+            self.unstaged_us_total += unstaged_us
+            self.unstaged_cpu_us_total += unstaged_cpu_us
+        return now_ns
+
     def process_begin(self) -> None:
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
         self._started_at = timex.now_ms()
+        # `kuiper:dispatch` brackets the dispatch on the profiler's host plane
+        self._dispatch_ann = _TraceAnnotation(
+            "kuiper:dispatch", rule=self.rule_id, op=self.op_id)
+        self._dispatch_ann.__enter__()
+        # a dispatch made outside the worker loop (a test, a replay) keeps
+        # the ledger's books itself
+        self._own_cycle = self._dispatch_tid == 0
+        if self._own_cycle:
+            self.cycle_begin()
         self.started_perf_ns = _time.perf_counter_ns()
 
     def process_end(self) -> None:
@@ -221,13 +301,15 @@ class StatManager:
             now = timex.now_ms()  # before the lock — see inc_in
             with self._lock:
                 # latency follows the engine clock (mock-deterministic in
-                # tests); the cumulative busy total uses a real perf
-                # counter — sub-ms work must still accrue
+                # tests); the dispatch's own time uses a real perf counter —
+                # sub-ms work must still accrue
                 self.process_latency_us = (now - self._started_at) * 1000
-                self.process_time_us_total += busy_us
                 self.messages_processed += 1
             self.proc_hist.record(busy_us)
             self._started_at = None
+            if self._own_cycle:
+                self.cycle_end()
+            self._dispatch_ann.__exit__(None, None, None)
 
     def observe_queue_wait(self, us: float) -> None:
         """One item's input-queue dwell (enqueue→dispatch), µs."""
@@ -294,7 +376,7 @@ class StatManager:
         for _ in range(4):
             try:
                 return {
-                    "busy_us": self.process_time_us_total,
+                    "unstaged_us": self.unstaged_us_total,
                     "stages": {k: v["total_us"]
                                for k, v in self.stages.items()
                                if k not in self.nested_stages},
@@ -307,7 +389,7 @@ class StatManager:
         # node for the tick instead of baselining empty stages/drops —
         # the next delta would otherwise replay the node's entire
         # cumulative history as one tick's worth
-        return {"busy_us": self.process_time_us_total, "stages": {},
+        return {"unstaged_us": self.unstaged_us_total, "stages": {},
                 "dropped": 0, "in": self.records_in, "partial": True}
 
     def snapshot(self) -> Dict[str, Any]:
@@ -318,6 +400,9 @@ class StatManager:
                 "messages_processed_total": self.messages_processed,
                 "process_latency_us": self.process_latency_us,
                 "process_time_us_total": self.process_time_us_total,
+                "busy_cpu_us_total": self.busy_cpu_us_total,
+                "unstaged_us_total": self.unstaged_us_total,
+                "unstaged_cpu_us_total": self.unstaged_cpu_us_total,
                 "idle_us_total": self.idle_us_total,
                 "backpressure_us_total": self.backpressure_us_total,
                 "buffer_length": self.buffer_length,

@@ -226,9 +226,39 @@ class TestBottleneckAttribution:
     def test_unstaged_busy_time_classified_by_node_kind(self):
         sink = FakeNode("sink", "sink")
         sink.stats.process_time_us_total = 50_000
+        sink.stats.unstaged_us_total = 50_000
         topo = FakeTopo([FakeNode("src", "source"), sink])
         ev = _evaluator(topo)
         assert ev.tick()["r1"]["bottleneck"]["stage"] == "sink"
+
+    def test_another_threads_stage_leaves_the_workers_remainder(self):
+        """The remainder is the worker's own ledger (PR 40). It was busy
+        less the node's stage rows, which the `<node>-emit` thread writes
+        too: 40 ms of `emit` there took 30 ms of unstaged dispatch on the
+        worker below zero, and out of the attribution."""
+        import threading
+        import time
+
+        node = FakeNode("fused", "op")
+        ev = _evaluator(FakeTopo([node]))
+
+        def emit_thread():
+            with node.stats.stage("emit"):
+                time.sleep(0.04)
+
+        other = threading.Thread(target=emit_thread)
+        other.start()
+        node.stats.process_begin()
+        time.sleep(0.03)  # the worker's dispatch, in no stage
+        node.stats.process_end()
+        other.join()
+        sample = node.stats.health_sample()
+        busy = node.stats.snapshot()["process_time_us_total"]
+        assert sample["stages"]["emit"] >= 40_000 > busy
+        assert sample["unstaged_us"] == busy >= 30_000
+        bn = ev.tick()["r1"]["bottleneck"]
+        assert bn["stage_us"]["emit_combine"] >= 40_000
+        assert bn["stage_us"]["other"] == sample["unstaged_us"]
 
     def test_backpressure_direction_upstream_of_bottleneck(self):
         src = FakeNode("src", "source")

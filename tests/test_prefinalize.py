@@ -561,23 +561,19 @@ class TestServedRule:
             f'(DATASOURCE="{name}/in", TYPE="memory", FORMAT="JSON")')
         return store
 
-    def test_half_of_a_one_second_window_reaches_the_shadow(
-            self, mock_clock):
-        """The two pre-triggers of `_schedule_next_tick` (2 x lead and 1 x
-        lead before the boundary; the second is skipped once the first has
-        landed) put the snapshot 500 ms before a 1 s window closes at the
-        default lead of 250 ms: every row after it is folded twice, on the
-        device and into the shadow. Rows spread evenly over the window:
-        one half of them reach the shadow (PERF.md, section 4)."""
+    def _one_even_window(self, mock_clock, name):
+        """A planned 1 s tumbling rule at the default lead, 100 rows every
+        100 ms over one window, the worker's loop pumped on this thread.
+        Returns (fused, items seen, shadow rows at the boundary, sink)."""
         import queue
 
         from ekuiper_tpu.planner.planner import RuleDef, plan_rule
         from ekuiper_tpu.runtime.events import PreTrigger, Trigger
         from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
 
-        store = self._stream("pf_half")
+        store = self._stream(name)
         topo = plan_rule(RuleDef(
-            id="pf_half_r", sql=self.SQL.format(s="pf_half"),
+            id=name + "_r", sql=self.SQL.format(s=name),
             actions=[{"nop": {}}], options={"sharedFold": False}), store)
         fused = next(n for n in topo.ops
                      if isinstance(n, FusedWindowAggNode))
@@ -616,11 +612,43 @@ class TestServedRule:
             fused._drain_async_emits()
         finally:
             fused.on_close()
+        return fused, seen, shadow_rows, got
+
+    def test_half_of_a_one_second_window_reaches_the_shadow(
+            self, mock_clock):
+        """The two pre-triggers of `_schedule_next_tick` (2 x lead and 1 x
+        lead before the boundary; the second is skipped once the first has
+        landed) put the snapshot 500 ms before a 1 s window closes at the
+        default lead of 250 ms: every row after it is folded twice, on the
+        device and into the shadow. Rows spread evenly over the window:
+        one half of them reach the shadow (PERF.md, section 4)."""
+        fused, seen, shadow_rows, got = self._one_even_window(
+            mock_clock, "pf_half")
         assert seen == ["PreTrigger", "PreTrigger", "Trigger"]
         assert shadow_rows == [[500]]  # one pre-issue, the one at 2 x lead
         assert len(got) == 1 and int(got[0].columns["c"].sum()) == 1000
         assert fused.emit_sources == {"device": 1}
         assert shadow_rows[0][0] / 1000 == 0.5
+
+    def test_shadow_fold_stage_counts_the_rows_the_shadow_took(
+            self, mock_clock):
+        """`HostShadow.fold` on the fused worker has a stage of its own
+        (PR 40): opened only while a pre-issue is un-merged, one call a
+        micro-batch, its rows the rows folded into shadows."""
+        fused, _seen, shadow_rows, _got = self._one_even_window(
+            mock_clock, "pf_stage")
+        stages = fused.stats.snapshot()["stage_timings"]
+        assert stages["fold"]["calls"] == 10  # every micro-batch
+        assert stages["fold"]["rows"] == 1000
+        # ... of which the five after the snapshot were mirrored
+        assert stages["shadow_fold"]["calls"] == 5
+        assert stages["shadow_fold"]["rows"] == sum(shadow_rows[0]) == 500
+        assert "shadow_fold" not in fused.stats.nested_stages
+        assert "shadow_fold" in fused.stats.health_sample()["stages"]
+        # the fold's staging is inside `fold`, one stage a micro-batch
+        assert "fold_h2d" in fused.stats.nested_stages
+        assert stages["fold_h2d"]["calls"] == 10
+        assert stages["fold_h2d"]["total_us"] <= stages["fold"]["total_us"]
 
     def test_rule_with_the_removed_tail_mode_option(self, mock_clock):
         """`tailMode` is no option any more: a rule that still carries it

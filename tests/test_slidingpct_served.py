@@ -415,9 +415,23 @@ def test_slide_stages_in_metrics_trace_and_profile(mock_clock, fresh_tracer,
         assert 0 < st["slide_merge"]["cpu_us"] <= \
             st["slide_merge"]["total_us"]
         assert fused.stats.nested_stages == {
-            "slide_query", "slide_merge", "key_encode"}
+            "slide_query", "slide_merge", "key_encode", "fold_h2d"}
         assert set(fused.stats.health_sample()["stages"]) == {
-            "upload", "fold", "emit", "slide_edge", "slide_advance"}
+            "upload", "fold", "emit", "slide_edge", "slide_advance",
+            "slide_ring", "release"}
+        # the ring's bookkeeping beside the fold (PR 40): twice a
+        # micro-batch — the stamps' buckets, guard, recycle and expiry
+        # before `upload`; the row ring's append and the trigger mask after
+        # `fold`, with its rows
+        assert st["slide_ring"]["calls"] == 2 * st["fold"]["calls"]
+        assert st["slide_ring"]["rows"] == st["fold"]["rows"]
+        assert 0 < st["slide_ring"]["cpu_us"] <= st["slide_ring"]["total_us"]
+        # ... which leaves the worker under a quarter of its dispatch
+        # time in no stage (10-12 % here, at 8-row micro-batches, where
+        # what a dispatch costs whatever its rows weighs most)
+        snap = fused.stats.snapshot()
+        assert 0 <= snap["unstaged_us_total"] \
+            <= 0.25 * snap["process_time_us_total"]
         code, text = api.dispatch("GET", "/metrics", None, {})
         for stage in NEW_STAGES:
             for fam in ("us", "cpu_us", "calls", "rows"):

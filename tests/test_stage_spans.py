@@ -4,6 +4,7 @@ node fabric counts starved and blocked time between the stages; a window
 boundary is split into phases; the kernels carry stable scope names."""
 import glob
 import json
+import threading
 import time
 
 import pytest
@@ -259,6 +260,13 @@ class TestProfilerAndTrace:
         # stage that dispatched it
         jit = next(e for e in events if e[0] == "kuiper:jit:fold")
         assert fold[1] <= jit[1] and jit[1] + jit[2] <= fold[1] + fold[2]
+        # ... after the fold's staging, which is a stage inside `fold`;
+        # and the worker's dispatch brackets the whole of it (PR 40)
+        h2d = next(e for e in events if e[0] == "kuiper:fold_h2d")
+        assert fold[1] <= h2d[1] and h2d[1] + h2d[2] <= jit[1]
+        assert any(e[0] == "kuiper:dispatch" and e[3]["op"] == "window_agg"
+                   and e[1] <= fold[1]
+                   and fold[1] + fold[2] <= e[1] + e[2] for e in events)
 
         # ---- the same work in the rule's trace, on the same clock
         spans = [s for tid in fresh_tracer.rule_traces("spans1")
@@ -354,6 +362,224 @@ class TestProfilerAndTrace:
 
 
 # ------------------------------------------------------------------ (d)
+class _Ledgered(Node):
+    """10 ms in a stage (5 of them in a nested one), 5 ms in none, while
+    another thread holds a 40 ms `emit` stage open on the same node."""
+
+    def process(self, item):
+        other = threading.Thread(target=self._emit_thread)
+        other.start()
+        with self.stats.stage("work", rows=3):
+            _spin(0.005)
+            with self.stats.stage("inner", within="work"):
+                _spin(0.005)
+            with self.stats.span("piece"):
+                pass
+        _spin(0.005)
+        other.join()
+
+    def _emit_thread(self):
+        with self.stats.stage("emit"):
+            time.sleep(0.04)
+
+
+def _after_a_dispatch(stats):
+    """The worker's ledger read just after its next dispatch ends: idle is
+    added when a `get` returns and busy when a cycle closes, so between
+    dispatches up to a poll of the queue is not on the books yet; here it
+    is what passed since `process_end`."""
+    n = stats.messages_processed
+    deadline = time.time() + 10
+    while stats.messages_processed == n and time.time() < deadline:
+        time.sleep(0.0005)
+    assert stats.messages_processed > n, "no dispatch came"
+    t = time.perf_counter_ns() // 1000
+    snap = stats.snapshot()
+    return t, snap
+
+
+class TestCycleLedger:
+    def test_only_the_workers_outermost_stages_are_staged(self):
+        node = _node(_Ledgered)
+        with node.stats.stage("warm"):  # no dispatch is open: not staged
+            _spin(0.002)
+        node._dispatch("x")
+        snap = node.stats.snapshot()
+        st = snap["stage_timings"]
+        busy = snap["process_time_us_total"]
+        assert busy >= 40_000  # the join waits for the other thread
+        assert st["emit"]["total_us"] >= 40_000
+        assert "inner" in node.stats.nested_stages
+        # staged is `work` alone: not `inner` (inside it), not `emit`
+        # (another thread's), not `warm` (no dispatch), not the span
+        assert snap["unstaged_us_total"] == busy - st["work"]["total_us"]
+        assert snap["unstaged_us_total"] >= 30_000
+        cpu = snap["busy_cpu_us_total"]
+        # three 5 ms spins on the core (less, on a shared core); the join
+        # sleeps
+        assert 7_000 <= cpu <= busy - 20_000
+        assert snap["unstaged_cpu_us_total"] == cpu - st["work"]["cpu_us"]
+        assert 2_000 <= snap["unstaged_cpu_us_total"] <= cpu - 4_000
+        assert node.stats.health_sample()["unstaged_us"] == \
+            snap["unstaged_us_total"]
+
+    def test_a_stage_that_reaches_back_counts_from_its_body(self):
+        """`since_ns` starts a stage's wall at a dispatch made earlier;
+        the ledger takes the body's own time, so unstaged stays >= 0."""
+        class N(Node):
+            def process(self, item):
+                with self.stats.stage(
+                        "late", since_ns=time.perf_counter_ns() - 10**9):
+                    _spin(0.002)
+
+        node = _node(N)
+        node._dispatch("x")
+        snap = node.stats.snapshot()
+        assert snap["stage_timings"]["late"]["total_us"] >= 1_000_000
+        assert 0 <= snap["unstaged_us_total"] \
+            <= snap["process_time_us_total"] - 2_000
+
+    def test_identity_on_a_served_rule(self, tumbling_rule, mock_clock):
+        """idle + staged + unstaged = the worker's wall, on the fused node
+        of a rule created over REST; the staged part is the stages its
+        worker closed, whatever its emit thread adds to the same table."""
+        api, got = tumbling_rule
+        _drive_one_window(mock_clock, got)  # compiles, off the record
+        fused = next(n for n in api.rules.state("spans1").topo.ops
+                     if n.name == "window_agg")
+        rows = [json.dumps({"deviceId": f"d{i % 4}", "temperature": 1.0}
+                           ).encode() for i in range(64)]
+
+        def batch():
+            mem.publish("spans/in", rows)
+            mock_clock.advance(20)  # the linger flush
+            return _after_a_dispatch(fused.stats)
+
+        t0, s0 = batch()
+        calls0 = fused.fold_transfers
+        for _ in range(8):
+            time.sleep(0.1)
+            batch()
+        mock_clock.advance(1000)  # a boundary: Trigger, emit
+        time.sleep(0.3)
+        t1, s1 = batch()
+
+        def grew(key):
+            return s1[key] - s0[key]
+
+        def stage(name, key="total_us"):
+            return (s1["stage_timings"].get(name, {}).get(key, 0)
+                    - s0["stage_timings"].get(name, {}).get(key, 0))
+
+        wall = t1 - t0
+        busy, idle = grew("process_time_us_total"), grew("idle_us_total")
+        unstaged = grew("unstaged_us_total")
+        assert wall > 1_000_000 and busy > 0
+        assert abs(idle + busy - wall) <= 0.05 * wall + 5_000, (
+            idle, busy, wall)
+        assert 0 <= unstaged <= busy
+        assert 0 <= grew("unstaged_cpu_us_total") \
+            <= grew("busy_cpu_us_total") <= busy + 1_000
+        # staged = what the worker's own stages took: four are its alone,
+        # the rest of it is its share of `emit`
+        staged = busy - unstaged
+        mine = ("upload", "fold", "boundary_reset", "release")
+        own = sum(stage(n) for n in mine)
+        n_calls = sum(stage(n, "calls") for n in mine + ("emit",))
+        assert own <= staged <= own + stage("emit") + n_calls
+        # the boundary's reset and re-arm, once; a batch's release, each
+        assert stage("boundary_reset", "calls") == 1
+        assert stage("release", "calls") == 9 and stage("release", "rows") \
+            == stage("fold", "rows")
+        # the fold's staging: nested, inside `fold`, one a micro-batch,
+        # and the counter says how many runtime calls it made
+        assert "fold_h2d" in fused.stats.nested_stages
+        assert "fold_h2d" not in fused.stats.health_sample()["stages"]
+        assert stage("fold_h2d", "calls") == stage("fold", "calls") == 9
+        assert 0 < stage("fold_h2d") <= stage("fold")
+        per_fold = (fused.fold_transfers - calls0) / 9
+        # the ingest prep uploaded the column and the slots: the row
+        # count and the pane are what the worker still stages
+        assert per_fold == 2, per_fold
+        text = api.dispatch("GET", "/metrics", None, {})[1]
+        assert (f'kuiper_fold_transfers_total{{rule="spans1",'
+                f'op="window_agg"}} {fused.fold_transfers}') in text
+        for fam in ("kuiper_op_busy_us_total", "kuiper_op_busy_cpu_us_total",
+                    "kuiper_op_unstaged_us_total",
+                    "kuiper_op_unstaged_cpu_us_total"):
+            assert f"# TYPE {fam} counter" in text
+            assert f'{fam}{{rule="spans1",op="window_agg"' in text
+        status = api.rules.state("spans1").topo.status()
+        assert status["op_window_agg_0_fold_transfers"] \
+            == fused.fold_transfers
+        assert status["op_window_agg_0_unstaged_us_total"] \
+            <= status["op_window_agg_0_process_time_us_total"]
+
+    def test_the_ledger_tool_divides_the_workers_wall(
+            self, tumbling_rule, mock_clock):
+        """`tools/cycle_ledger.py` reads the same division from two
+        `/metrics` texts, as it does around a benchmark cell's window."""
+        from tools import cycle_ledger
+
+        api, got = tumbling_rule
+        _drive_one_window(mock_clock, got)
+
+        def marks():
+            return {"t": time.time(),
+                    "metrics": api.dispatch("GET", "/metrics", None, {})[1]}
+
+        m0 = marks()
+        _drive_one_window(mock_clock, got, n_before=len(got))
+        time.sleep(0.3)  # the worker's open `get` books its idle
+        led = cycle_ledger.ledger(m0, marks())
+        assert led["op"] == "window_agg" and led["micro_batches"] == 1
+        assert led["staged"] + led["unstaged"] == led["busy"] > 0
+        assert led["stages"]["fold"]["wall"] \
+            >= led["stages"]["fold_h2d"]["wall"] > 0
+        # prefinalizeLeadMs 0: the boundary's emit runs on the worker
+        assert 0 < led["emit_on_worker"] <= led["stages"]["emit"]["wall"] + 2
+        assert led["transfers"] == 2
+        assert abs(led["identity_gap_share"]) < 0.35  # one 0.2 s poll
+        text = cycle_ledger.table(led)
+        assert "fold_h2d" in text and "unstaged" in text
+
+    def test_transfers_count_what_the_worker_stages_itself(self):
+        """A fold handed host columns makes one runtime call a column, a
+        mask, the slots, the row count and the pane; the stage opener, if
+        one is handed, runs once a chunk around exactly that."""
+        import numpy as np
+
+        from ekuiper_tpu.ops.aggspec import extract_kernel_plan
+        from ekuiper_tpu.ops.groupby import DeviceGroupBy
+        from ekuiper_tpu.sql.parser import parse_select
+
+        plan = extract_kernel_plan(parse_select(
+            "SELECT avg(temp), max(hum) FROM demo "
+            "GROUP BY deviceId, TUMBLINGWINDOW(ss, 10)"))
+        gb = DeviceGroupBy(plan, capacity=16, micro_batch=8)
+        node = _node()
+        opened = []
+
+        def h2d(rows):
+            opened.append(rows)
+            return node.stats.stage("fold_h2d", rows, within="fold")
+
+        cols = {"temp": np.arange(12, dtype=np.float32),
+                "hum": np.ones(12, dtype=np.float32)}
+        slots = np.zeros(12, dtype=np.int32)
+        state = gb.fold(gb.init_state(), cols, slots)  # as a test calls it
+        assert gb.transfers_total == 2 * (2 + 1 + 2)  # two chunks of 8
+        state = gb.fold(state, cols, slots,
+                        {"temp": np.ones(12, dtype=np.bool_)}, h2d=h2d)
+        assert gb.transfers_total == 10 + 2 * (2 + 1 + 1 + 2)
+        assert opened == [8, 4]
+        assert node.stats.snapshot()["stage_timings"]["fold_h2d"][
+            "calls"] == 2
+        outs, act = gb.finalize(state, 1)
+        assert float(np.asarray(act)[0]) == 24.0
+
+
+# ------------------------------------------------------------------ (e)
 class TestKernelNames:
     def test_program_names_stay_and_ops_carry_kuiper_scopes(self):
         """`trace_kernel_roofline` matches the PROGRAM names by substring;

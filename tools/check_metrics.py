@@ -129,6 +129,8 @@ def _synthetic_scrape() -> str:
             self.sliding_tails = {"device": 3, "host": 1}
             # ... and kuiper_keytable_encode_rows_total from this method
             self.keytable_encode_rows = lambda: {"native_int": 3, "sorted": 0}
+            # ... and kuiper_fold_transfers_total from this attribute
+            self.fold_transfers = 18
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]
