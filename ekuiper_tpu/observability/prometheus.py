@@ -240,16 +240,26 @@ def render(rule_registry) -> str:
                 f'kuiper_keytable_encode_rows_total{{rule="{_esc(rule_id)}",'
                 f'op="{_esc(node.name)}",path="{path}"}} {n}')
     # host -> device staging of the window node's folds (ops/groupby.py
-    # `fold`): runtime calls made, what the `fold_h2d` stage's time buys
-    _family(out, "kuiper_fold_transfers_total", "counter",
-            "host->device runtime calls the staging of a window node's "
-            "folds made (padded columns, masks, slots, row count, pane)")
-    for rule_id, node in rows:
-        n = getattr(node, "fold_transfers", None)
-        if n is not None:
-            out.append(
-                f'kuiper_fold_transfers_total{{rule="{_esc(rule_id)}",'
-                f'op="{_esc(node.name)}"}} {n}')
+    # `fold`): runtime calls made, what the `fold_h2d` stage's time buys,
+    # and arguments the device held already; hit share = resident /
+    # (resident + transfers)
+    for family, attr, help_txt in (
+            ("kuiper_fold_transfers_total", "fold_transfers",
+             "host->device runtime calls the staging of a window node's "
+             "folds made (one a chunk with anything still on the host: "
+             "padded columns, masks, slots, a partial row count, a pane "
+             "vector)"),
+            ("kuiper_fold_resident_args_total", "fold_resident_args",
+             "fold arguments that staging took from the kernel's "
+             "device-resident scalar table (a scalar pane, a full "
+             "micro-batch's row count) and made no runtime call for")):
+        _family(out, family, "counter", help_txt)
+        for rule_id, node in rows:
+            n = getattr(node, attr, None)
+            if n is not None:
+                out.append(
+                    f'{family}{{rule="{_esc(rule_id)}",'
+                    f'op="{_esc(node.name)}"}} {n}')
     # shared pane folds (runtime/nodes_sharedfold.py): pool-level gauges —
     # members per store and the fold-dedup ratio (1 - folds run / folds N
     # private rules would have run). The store node's own op metrics (incl.

@@ -134,8 +134,17 @@ class DeviceGroupBy:
         # the placement policy's recency/frequency signal, no host sync
         self.track_touch = bool(track_touch)
         # runtime calls the folds' host -> device staging has made
-        # (kuiper_fold_transfers_total); one writer, the folding thread
+        # (kuiper_fold_transfers_total) and arguments it took from the
+        # scalar table below instead (kuiper_fold_resident_args_total);
+        # one writer, the folding thread
         self.transfers_total = 0
+        self.resident_total = 0
+        # device-resident scalars, int32[] as the executables were lowered
+        # with: the pane indices 0 .. n_panes-1, then the full row count
+        # `micro_batch`. Each made at its first use and never again; none
+        # is ever donated (only argument 0, the state, is), so one array
+        # serves every call that needs its value
+        self._scalars: List[Any] = [None] * (self.n_panes + 1)
         # component -> ordered spec indices holding a column in that array
         self.comp_specs: Dict[str, List[int]] = {}
         for i, spec in enumerate(plan.specs):
@@ -307,17 +316,43 @@ class DeviceGroupBy:
             state = self._fold(state, *staged)
         return state
 
+    def _resident(self, idx: int):
+        """Entry `idx` of the scalar table (the pane `idx`; at `n_panes`
+        the full row count), made on the device at its first use."""
+        arr = self._scalars[idx]
+        if arr is None:
+            import jax.numpy as jnp
+
+            value = self.micro_batch if idx == self.n_panes else idx
+            arr = self._scalars[idx] = jnp.asarray(value, dtype=jnp.int32)
+        return arr
+
+    def _pane_arg(self, pane_idx):
+        """A scalar pane as the executables take it: the table's device
+        array, or for a pane the table does not hold a host int32 that
+        rides the compiled call."""
+        if 0 <= pane_idx < self.n_panes:
+            return self._resident(int(pane_idx))
+        return np.int32(pane_idx)
+
     def _stage_chunk(self, cols, slots, valid, pane_idx, start: int,
                      end: int):
-        """Host -> device staging of rows [start:end): pad to the static
-        micro-batch, cast, one runtime call an array that is not on the
-        device yet. Returns the jitted fold's arguments after the state."""
+        """Host -> device staging of rows [start:end). What the device
+        holds already is handed over as it is: pre-uploaded columns and
+        slots, and from the scalar table a scalar pane and the row count
+        of a full micro-batch (`resident_total` counts those two). The
+        rest — host columns, masks and slots, a per-row pane vector, the
+        row count of a partial chunk — is padded to the static micro-batch,
+        cast, and handed on as numpy: it goes up inside the compiled call,
+        the one runtime call of a chunk that has anything on the host
+        (`transfers_total` counts it; the table's own n_panes + 1 fills
+        are once a kernel, not counted). Returns the jitted fold's
+        arguments after the state."""
         import jax
-        import jax.numpy as jnp
 
         cnt = end - start
         pad = self.micro_batch - cnt
-        calls = 2  # the row count and the pane, below
+        on_host = bool(pad)  # a partial chunk's row count, below
         dev_cols = {}
         for name in self.plan.columns:
             c = cols[name]
@@ -325,46 +360,51 @@ class DeviceGroupBy:
                 dev_cols[name] = c
                 dev_cols["__valid_" + name] = valid.get(name)
                 continue
+            on_host = True
             # kuiperlint: ignore[host-sync]: `c` is a HOST column here (device arrays took the pre-padded branch above) — this is H2D staging, not a sync
             arr = np.asarray(c[start:end],
                              dtype=col_np_dtype(self.plan, name))
             if pad:
                 arr = np.pad(arr, (0, pad))
-            dev_cols[name] = jnp.asarray(arr)
-            calls += 1
-            vmask = valid.get(name)
-            if vmask is not None:
-                vm = vmask[start:end]
+            dev_cols[name] = arr
+            vm = valid.get(name)
+            if vm is not None:
+                vm = vm[start:end]
                 if pad:
                     vm = np.pad(vm, (0, pad))
-                dev_cols["__valid_" + name] = jnp.asarray(vm)
-                calls += 1
-            else:
-                dev_cols["__valid_" + name] = None
-        if isinstance(slots, jax.Array):
-            s_dev = slots  # pre-padded + dtype-chosen by the sharer
-        else:
-            s = slots[start:end]
+            dev_cols["__valid_" + name] = vm
+        # device slots come pre-padded + dtype-chosen by the sharer
+        if not isinstance(slots, jax.Array):
+            on_host = True
+            slots = slots[start:end]
             if pad:
-                s = np.pad(s, (0, pad))
+                slots = np.pad(slots, (0, pad))
             # upload-byte diet: slots ship as uint16 when capacity
             # allows (halves the largest upload), and row validity
             # ships as ONE scalar count compared against an iota on
             # device instead of an mb-byte bool mask — HBM/link
             # bandwidth is the bottleneck, not device compute
-            s_dev = jnp.asarray(
-                s.astype(slot_dtype(self.capacity), copy=False))
-            calls += 1
+            slots = slots.astype(slot_dtype(self.capacity), copy=False)
         if isinstance(pane_idx, np.ndarray):
+            on_host = True
             pv = pane_idx[start:end]
             if pad:
                 pv = np.pad(pv, (0, pad))
-            pane_arg = jnp.asarray(pv.astype(np.uint8))  # n_panes <= 255
+            pane_arg = pv.astype(np.uint8)  # n_panes <= 255
         else:
-            pane_arg = jnp.asarray(pane_idx, dtype=jnp.int32)
-        n_valid = jnp.asarray(cnt, dtype=jnp.int32)
-        self.transfers_total += calls
-        return dev_cols, s_dev, n_valid, pane_arg
+            pane_arg = self._pane_arg(pane_idx)
+            if isinstance(pane_arg, jax.Array):
+                self.resident_total += 1
+            else:
+                on_host = True
+        if pad:
+            n_valid = np.int32(cnt)
+        else:
+            n_valid = self._resident(self.n_panes)
+            self.resident_total += 1
+        if on_host:
+            self.transfers_total += 1
+        return dev_cols, slots, n_valid, pane_arg
 
     def _fold_impl(self, state, cols, slots, n_valid, pane_idx):
         import jax
@@ -389,7 +429,7 @@ class DeviceGroupBy:
 
         return self._fold_m(state, dev_cols, slots_dev,
                             jnp.asarray(mask, dtype=jnp.bool_),
-                            jnp.asarray(pane_idx, dtype=jnp.int32))
+                            self._pane_arg(pane_idx))
 
     def _fold_core(self, state, cols, slots, base, pane_idx):
         import jax
@@ -797,9 +837,7 @@ class DeviceGroupBy:
         return state
 
     def reset_pane(self, state: Dict[str, Any], pane_idx: int) -> Dict[str, Any]:
-        import jax.numpy as jnp
-
-        return self._reset_pane(state, jnp.asarray(pane_idx, dtype=jnp.int32))
+        return self._reset_pane(state, self._pane_arg(pane_idx))
 
     def reset_all(self, state: Dict[str, Any]) -> Dict[str, Any]:
         return self.init_state()

@@ -1009,6 +1009,12 @@ class FusedWindowAggNode(Node):
         (kuiper_fold_transfers_total)."""
         return self.gb.transfers_total
 
+    @property
+    def fold_resident_args(self) -> int:
+        """Arguments that staging took from the kernel's device-resident
+        scalar table instead (kuiper_fold_resident_args_total)."""
+        return self.gb.resident_total
+
     def _fold_rows(self, sub: ColumnBatch, pane_arg) -> int:
         """Encode keys + build kernel columns + device fold for `sub`,
         folding into `pane_arg` (scalar pane or per-row pane vector).

@@ -236,12 +236,14 @@ class Topo:
             if enc is not None:
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_keytable_encode_rows"] = dict(enc)
-        # runtime calls the staging of a window node's folds has made
+        # runtime calls the staging of a window node's folds has made, and
+        # arguments it took from the device-resident scalar table instead
         for n in self.ops:
-            calls = getattr(n, "fold_transfers", None)
-            if calls is not None:
-                out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
-                    "_fold_transfers"] = calls
+            for key in ("fold_transfers", "fold_resident_args"):
+                count = getattr(n, key, None)
+                if count is not None:
+                    out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
+                        f"_{key}"] = count
         # rule-level SLO summary: the ingest→emit distribution percentiles
         out["e2e_latency_ms"] = self.e2e_hist.snapshot()
         # ... and its engine-side phases per window boundary, ms

@@ -131,6 +131,8 @@ def _synthetic_scrape() -> str:
             self.keytable_encode_rows = lambda: {"native_int": 3, "sorted": 0}
             # ... and kuiper_fold_transfers_total from this attribute
             self.fold_transfers = 18
+            # ... and kuiper_fold_resident_args_total from this one
+            self.fold_resident_args = 36
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]

@@ -87,8 +87,10 @@ def ledger(marks0: dict, marks1: dict) -> dict:
         (out["idle"] + staged + out["unstaged"]) / wall - 1.0)
     out["micro_batches"] = out["stages"]["fold"]["calls"]
     out["fold_rows_per_s"] = out["stages"]["fold"]["rows"] / (wall / 1e6)
-    if any(fam == "kuiper_fold_transfers_total" for fam, _ in s1):
-        out["transfers"] = grew("kuiper_fold_transfers_total")
+    for key, fam in (("transfers", "kuiper_fold_transfers_total"),
+                     ("resident", "kuiper_fold_resident_args_total")):
+        if any(f == fam for f, _ in s1):
+            out[key] = grew(fam)
     return out
 
 
@@ -151,9 +153,15 @@ def table(led: dict, busy_s=None, window_s=None) -> str:
                 if "identity_gap_direct" in led else ""),
              f"  senders blocked on its queue {ms(led['backpressure'])}"]
     if "transfers" in led:
-        calls = led["stages"].get("fold_h2d", {}).get("calls", 0.0)
+        calls = max(led["stages"].get("fold_h2d", {}).get("calls", 0.0), 1.0)
         rows.append(f"  runtime calls a staging "
-                    f"{led['transfers'] / max(calls, 1.0):.2f}")
+                    f"{led['transfers'] / calls:.2f}")
+        if "resident" in led:  # the parent's program has no such family
+            served = led["resident"] + led["transfers"]
+            rows.append(
+                f"  resident arguments a staging "
+                f"{led['resident'] / calls:.2f} (hit share "
+                f"{100 * led['resident'] / max(served, 1.0):.1f} %)")
     if busy_s is not None:
         rows.append(f"  device busy {busy_s:.3f} s of {window_s:.2f} s "
                     f"traced")
