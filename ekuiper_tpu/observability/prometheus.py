@@ -242,7 +242,8 @@ def render(rule_registry) -> str:
     # host -> device staging of the window node's folds (ops/groupby.py
     # `fold`): runtime calls made, what the `fold_h2d` stage's time buys,
     # and arguments the device held already; hit share = resident /
-    # (resident + transfers)
+    # (resident + transfers); and the rows the pre-issues' host shadows
+    # mirrored over the touched slots only (ops/prefinalize.py)
     for family, attr, help_txt in (
             ("kuiper_fold_transfers_total", "fold_transfers",
              "host->device runtime calls the staging of a window node's "
@@ -252,7 +253,12 @@ def render(rule_registry) -> str:
             ("kuiper_fold_resident_args_total", "fold_resident_args",
              "fold arguments that staging took from the kernel's "
              "device-resident scalar table (a scalar pane, a full "
-             "micro-batch's row count) and made no runtime call for")):
+             "micro-batch's row count) and made no runtime call for"),
+            ("kuiper_shadow_fold_compact_rows_total",
+             "shadow_fold_compact_rows",
+             "rows the host shadows of a window node's pre-issues mirrored "
+             "over the slots a micro-batch touches, not over their whole "
+             "capacity (once a shadow, as stage shadow_fold counts rows)")):
         _family(out, family, "counter", help_txt)
         for rule_id, node in rows:
             n = getattr(node, attr, None)

@@ -384,6 +384,11 @@ class FusedWindowAggNode(Node):
             (s.float_reason, next(iter(s.arg.columns)))
             for s in plan.specs
             if s.float_reason and len(s.arg.columns) == 1})
+        # rows the pre-issues' host shadows mirrored over the slots a
+        # micro-batch touches, not over their whole capacity (ops/
+        # prefinalize.py HostShadow.fold; kuiper_shadow_fold_compact_rows_
+        # total): once a shadow, as stage shadow_fold counts its rows
+        self.shadow_fold_compact_rows = 0
         # latency-hiding emit: pre-issued device finalize + host tail shadow.
         # Only for timer-driven windows (boundary known in advance), plans
         # whose expressions have numpy twins, and non-collective kernels.
@@ -1139,7 +1144,8 @@ class FusedWindowAggNode(Node):
             with self.stats.stage("shadow_fold",
                                   sub.n * len(self._pipeline)):
                 for _, shadow in self._pipeline:
-                    shadow.fold(cols, slots, valid)
+                    self.shadow_fold_compact_rows += shadow.fold(
+                        cols, slots, valid)
         return sub.n
 
     # ------------------------------------------------------------ event time

@@ -236,10 +236,12 @@ class Topo:
             if enc is not None:
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_keytable_encode_rows"] = dict(enc)
-        # runtime calls the staging of a window node's folds has made, and
-        # arguments it took from the device-resident scalar table instead
+        # runtime calls the staging of a window node's folds has made,
+        # arguments it took from the device-resident scalar table instead,
+        # and rows its host shadows mirrored over the touched slots only
         for n in self.ops:
-            for key in ("fold_transfers", "fold_resident_args"):
+            for key in ("fold_transfers", "fold_resident_args",
+                        "shadow_fold_compact_rows"):
                 count = getattr(n, key, None)
                 if count is not None:
                     out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"

@@ -177,6 +177,47 @@ def test_served_q17_equals_the_int64_reference_and_the_host_path(
         assert timings["shadow_fold"]["rows"] == N_WINDOWS * DRAIN
         assert timings["int_combine"]["calls"] == N_WINDOWS
         assert "int_combine" in fused.stats.nested_stages
+        # 4,096 key slots are two micro-batches: the shadow's full path
+        assert fused.shadow_fold_compact_rows == 0
+    finally:
+        api.rules.stop_all()
+
+
+def test_a_shadow_at_four_micro_batches_of_slots_folds_compact(mock_clock):
+    """`key_slots` four times `micro_batch_rows`, as the cell's 262,144
+    slots are eight times its 32,768 rows: the pre-issue's host shadow sums
+    its counts and int64 sums over the slots each micro-batch touches
+    (HostShadow.fold's compact path), and every window still equals the
+    host operator's int64 answers and a numpy int64 reference."""
+    mock_clock.set(1_700_000_000_000)
+    pool = seeded_pool(44)
+    api, got = _start_rule("q17_compact", {"key_slots": 4 * DRAIN})
+    _, host = _start_rule("q17_compact_host", {"use_device_kernel": False})
+    try:
+        sent = _drive(mock_clock, pool, [got, host])
+        fused = _fused(api, "q17_compact")
+        fused._drain_async_emits()
+        assert fused.kt.capacity == 4 * DRAIN
+        assert fused.emit_sources == {"device": N_WINDOWS}
+        assert len(got) == len(host) == N_WINDOWS
+        for w, (dev_msgs, host_msgs) in enumerate(zip(got, host)):
+            want = numpy_reference(
+                pool, sent[w * WINDOW_DRAINS:(w + 1) * WINDOW_DRAINS])
+            for msgs in (dev_msgs, host_msgs):
+                assert {m["auction"]: {c: m[c] for c in VALUES}
+                        for m in msgs} == want
+                assert all(type(m[c]) is int for m in msgs for c in VALUES)
+        # every row the shadows took went by the compact path
+        rows = fused.stats.snapshot()["stage_timings"]["shadow_fold"]["rows"]
+        assert rows == N_WINDOWS * DRAIN
+        assert fused.shadow_fold_compact_rows == rows
+        _, text = api.dispatch("GET", "/metrics", None, {})
+        text = text if isinstance(text, str) else text.decode()
+        assert ('kuiper_shadow_fold_compact_rows_total{rule="q17_compact",'
+                f'op="{fused.name}"}} {rows}') in text
+        _, status = api.dispatch("GET", "/rules/q17_compact/status", None, {})
+        assert [v for k, v in status.items()
+                if k.endswith("_shadow_fold_compact_rows")] == [rows]
     finally:
         api.rules.stop_all()
 

@@ -236,6 +236,141 @@ class TestTailGrow:
         np.testing.assert_array_equal(outs[0][2:], np.ones(20, dtype=np.int64))
 
 
+#: a float plan (tumbling10k's aggregates, a FILTERed count and sum, a
+#: stddev for s2) and Query 17's exact plan (FILTERed bands, BIGINT
+#: sum / avg / min / max)
+FLOAT_SQL = ("SELECT deviceId, avg(temp) AS a, count(*) AS c, min(temp) AS mn, "
+             "max(temp) AS mx, stddev(temp) AS sd, "
+             "count(*) FILTER (WHERE temp > 22) AS hot, "
+             "sum(temp) FILTER (WHERE temp > 18) AS warm "
+             "FROM s GROUP BY deviceId, TUMBLINGWINDOW(ss, 1)")
+Q17_SQL = ("SELECT auction, count(*) AS total_bids, "
+           "count(*) FILTER (WHERE price < 10000) AS rank1_bids, "
+           "count(*) FILTER (WHERE price >= 10000 AND price < 1000000) "
+           "AS rank2_bids, "
+           "count(*) FILTER (WHERE price >= 1000000) AS rank3_bids, "
+           "min(price) AS min_price, max(price) AS max_price, "
+           "avg(price) AS avg_price, sum(price) AS sum_price "
+           "FROM s GROUP BY auction, TUMBLINGWINDOW(ss, 2)")
+
+
+def _shadow_plan(kind):
+    if kind == "float":
+        return _plan(FLOAT_SQL)
+    plan = extract_kernel_plan(parse_select(Q17_SQL), int_columns={"price"})
+    assert plan.has_exact
+    return plan
+
+
+def _shadow_rows(kind, rng, slots):
+    """(cols, valid) of a micro-batch on `slots`: float values with NaNs
+    and a validity mask; or BIGINT prices in every band, negatives, some
+    null, and a few near 2^52 so a slot's sum passes 2^53 and `sum_i64`
+    takes its split path."""
+    n = len(slots)
+    if kind == "float":
+        temp = rng.normal(20, 5, n).astype(np.float32)
+        temp[rng.random(n) < 0.1] = np.nan
+        return {"temp": temp}, {"temp": rng.random(n) > 0.2}
+    price = np.round(10 ** (6 * rng.random(n)) * 100).astype(np.int64)
+    price[rng.random(n) < 0.2] *= -1
+    price[::3] = (1 << 52) + rng.integers(0, 1000, len(price[::3]))
+    return ({"price": price.astype(np.float32), "__i64__price": price},
+            {"__i64__price": rng.random(n) > 0.1})
+
+
+#: (capacity the shadow starts at, the slots of each call)
+SHADOW_BATCHES = {
+    "one_row": (64, [np.array([5])]),
+    "one_slot": (64, [np.full(50, 7)]),
+    "last_slot": (64, [np.array([63, 0, 63, 9, 63, 31, 2, 63])]),
+    "grown_between_calls": (16, [np.arange(10) % 15,
+                                 np.array([40, 3, 40, 17, 63, 3])]),
+}
+
+
+class TestCompactShadow:
+    """`HostShadow.fold` sums the row-additive components over the slots a
+    micro-batch touches where its capacity is at least COMPACT_FACTOR
+    times the rows: the same arrays as the full path, bit for bit."""
+
+    @pytest.mark.parametrize("batches", sorted(SHADOW_BATCHES))
+    @pytest.mark.parametrize("kind", ["float", "exact"])
+    def test_compact_path_equals_the_full_path_bit_for_bit(
+            self, monkeypatch, kind, batches):
+        from ekuiper_tpu.ops import prefinalize as pf
+
+        plan = _shadow_plan(kind)
+        gb = DeviceGroupBy(plan, capacity=8, micro_batch=8)
+        capacity, calls = SHADOW_BATCHES[batches]
+        shadows = {}
+        for path, factor in (("compact", 0), ("full", float("inf"))):
+            monkeypatch.setattr(pf, "COMPACT_FACTOR", factor)
+            rng = np.random.default_rng(44)
+            shadow = HostShadow(plan, gb.comp_specs, capacity)
+            for slots in calls:
+                cols, valid = _shadow_rows(kind, rng, slots)
+                took = shadow.fold(cols, slots.astype(np.int32), valid)
+                assert took == (len(slots) if path == "compact" else 0)
+            shadows[path] = shadow
+        compact, full = shadows["compact"], shadows["full"]
+        assert compact.capacity == full.capacity >= capacity
+        assert set(compact.data) == set(full.data)
+        for comp, arr in full.data.items():
+            assert compact.data[comp].dtype == arr.dtype, comp
+            assert compact.data[comp].tobytes() == arr.tobytes(), comp
+        if kind == "exact" and batches == "one_slot":
+            # the slot's sum passed 2^53: the split path was taken
+            (k,) = [j for j, s in enumerate(plan.specs) if s.kind == "sum"]
+            total = full.data["i1"][7, full.comp_specs["i1"].index(k)]
+            assert abs(int(total)) >= 1 << 53
+
+    @pytest.mark.parametrize("capacity,rows,compact", [
+        (16_384, 32_768, False),   # tumbling10k: 0.5x
+        (65_536, 32_768, False),   # nexmarkq12: 2x
+        (262_144, 32_768, True),   # nexmarkq17: 8x
+        (16_384, 2_048, True),     # a micro-batch a flush cut short
+    ], ids=["tumbling10k", "nexmarkq12", "nexmarkq17", "flush_cut"])
+    def test_the_cell_shapes_take_the_path_their_capacity_gives(
+            self, capacity, rows, compact):
+        """Through the node's counter: a shadow of `capacity` slots fed one
+        micro-batch of `rows` rows after the pre-issue takes the compact
+        path only where the capacity is at least four times the rows, and
+        the counter then grows as the `shadow_fold` stage's rows."""
+        from ekuiper_tpu.data.batch import ColumnBatch
+        from ekuiper_tpu.ops.emit import build_direct_emit
+        from ekuiper_tpu.runtime.events import PreTrigger
+        from ekuiper_tpu.runtime.nodes_fused import FusedWindowAggNode
+
+        stmt = parse_select(FLOAT_SQL)
+        plan = extract_kernel_plan(stmt)
+        node = FusedWindowAggNode(
+            "t", stmt.window, plan, dims=[d.expr for d in stmt.dimensions],
+            capacity=capacity, micro_batch=rows,
+            direct_emit=build_direct_emit(stmt, plan, ["deviceId"]),
+            prefinalize_lead_ms=250)
+        node.state = node.gb.init_state()
+        node.broadcast = lambda item: None
+        rng = np.random.default_rng(7)
+
+        def batch():
+            keys = np.array([f"d{i}" for i in rng.integers(0, 2_000, rows)],
+                            dtype=np.object_)
+            return ColumnBatch(
+                n=rows, columns={"deviceId": keys, "temp": rng.normal(
+                    20, 5, rows).astype(np.float32)},
+                timestamps=np.zeros(rows, dtype=np.int64), emitter="s")
+
+        node.process(batch())
+        node.on_pre_trigger(PreTrigger(ts=1_000))
+        (_, shadow), = node._pipeline
+        assert shadow.capacity == capacity
+        node.process(batch())
+        stages = node.stats.snapshot()["stage_timings"]
+        assert stages["shadow_fold"]["rows"] == rows
+        assert node.shadow_fold_compact_rows == (rows if compact else 0)
+
+
 class TestColumnarNulls:
     def test_null_agg_stays_explicit_none(self):
         """A NULL aggregate (empty group min) must appear as an explicit

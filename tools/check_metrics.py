@@ -133,6 +133,8 @@ def _synthetic_scrape() -> str:
             self.fold_transfers = 18
             # ... and kuiper_fold_resident_args_total from this one
             self.fold_resident_args = 36
+            # ... and kuiper_shadow_fold_compact_rows_total from this one
+            self.shadow_fold_compact_rows = 2048
             # kuiper_fold_int_exact_rows_total renders for a node with
             # exact BIGINT partials, kuiper_agg_int_fallback_total from
             # its counted reasons
