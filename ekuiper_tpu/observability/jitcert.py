@@ -129,20 +129,32 @@ def _slot_dtypes() -> Tuple[str, ...]:
 
 def _state_leaves(comps: Dict[str, Tuple[int, int]], n_panes: int,
                   capacity: int, lead: Optional[int] = None,
-                  touch: bool = False) -> List[str]:
+                  touch: bool = False, os_bufs: int = 0,
+                  rung: int = 0) -> List[str]:
     """Signature leaves of a group-by state pytree: dict keys sort, `act`
     rides along; `comps` maps component -> (n_specs, wide_size-or-0);
     `lead` prepends the multirule rule axis; `touch` appends the tiered
     state's per-slot uint32 counter (ops/tierstore.py — key axis only,
-    no pane axis, sorts last among the state keys). Each leaf carries its
+    no pane axis, sorts last among the state keys); `os_bufs` order-
+    statistic row buffers add `osf` (panes,), `oss` / `osv` (buffers,
+    panes, `rung`) (ops/orderstat.py — no key axis). Each leaf carries its
     component's device dtype (ops/groupby.py comp_dtype)."""
     from ..ops.groupby import comp_dtype, state_shape
+    from ..ops.orderstat import OS_LEAVES
 
     parts: List[str] = []
-    names = list(comps) + ["act"] + (["touch"] if touch else [])
+    names = list(comps) + ["act"] + (["touch"] if touch else []) \
+        + (list(OS_LEAVES) if os_bufs else [])
     for comp in sorted(names):
         if comp == "touch":
             parts.append(_arr("uint32", capacity))
+            continue
+        if comp == "osf":
+            parts.append(_arr("int32", n_panes))
+            continue
+        if comp in ("oss", "osv"):
+            parts.append(_arr(str(comp_dtype(comp)), os_bufs, n_panes,
+                              rung))
             continue
         if comp == "act":
             dims: Tuple[int, ...] = (n_panes, capacity)
@@ -205,6 +217,16 @@ class KernelShape:
     #: tiered key state (ops/tierstore.py): the per-slot uint32 touch
     #: column rides the state pytree of every site
     touch: bool = False
+    #: order-statistic row buffers (ops/orderstat.py): how many, and the
+    #: first rung of their power-of-two ladder (rows a pane)
+    os_bufs: int = 0
+    os_rung: int = 0
+
+
+def _rungs(ks: "KernelShape", grows: int) -> List[int]:
+    """The order-statistic buffers' rung ladder — one entry (0, no leaf)
+    for a plan without them."""
+    return _ladder(ks.os_rung, grows) if ks.os_bufs else [0]
 
 
 def _kernel_shape(kernel) -> KernelShape:
@@ -230,7 +252,15 @@ def _kernel_shape(kernel) -> KernelShape:
             getattr(kernel.plan, "col_dtypes", {}).items())
             if v != "float32"},
         touch=bool(getattr(kernel, "track_touch", False)),
+        os_bufs=int(getattr(kernel, "n_os_bufs", 0)),
+        os_rung=_first_rung(kernel.micro_batch),
     )
+
+
+def _first_rung(micro_batch: int) -> int:
+    from ..ops.orderstat import first_rung
+
+    return first_rung(int(micro_batch))
 
 
 def shape_from_plan(plan, n_panes: int, micro_batch: int,
@@ -257,6 +287,8 @@ def shape_from_plan(plan, n_panes: int, micro_batch: int,
         col_dtypes={k: v for k, v in sorted(
             getattr(plan, "col_dtypes", {}).items()) if v != "float32"},
         touch=bool(touch),
+        os_bufs=len(getattr(plan, "os_bufs", ())),
+        os_rung=_first_rung(micro_batch),
     )
 
 
@@ -316,24 +348,31 @@ def _derive_fold(ks: KernelShape, op: str, rule: Optional[str],
     if ks.touch:
         deriv.append("tiered state: uint32[capacity] touch column in the "
                      "state pytree (ops/tierstore.py)")
+    rungs = _rungs(ks, grows)
+    if ks.os_bufs:
+        deriv.append(f"order-statistic buffers: {ks.os_bufs} x panes x "
+                     f"rung, rung ladder {ks.os_rung} x2^0..{grows} "
+                     "(ops/orderstat.py rung_for)")
     for cap in _ladder(ks.base_capacity, grows):
-        state = _state_leaves(ks.comps, ks.n_panes, cap, ks.lead_rules,
-                              touch=ks.touch)
-        for subset in subsets:
-            cols = _col_leaves(ks.columns, ks.micro_batch, subset,
-                               masks_always=sharded,
-                               col_dtypes=ks.col_dtypes)
-            for sd in slot_dts:
-                for gate in row_gates:
-                    for pane in panes:
-                        sigs.append(_sig(
-                            state + cols
-                            + [_arr(sd, ks.micro_batch), gate, pane]))
+        for rung in rungs:
+            state = _state_leaves(ks.comps, ks.n_panes, cap, ks.lead_rules,
+                                  touch=ks.touch, os_bufs=ks.os_bufs,
+                                  rung=rung)
+            for subset in subsets:
+                cols = _col_leaves(ks.columns, ks.micro_batch, subset,
+                                   masks_always=sharded,
+                                   col_dtypes=ks.col_dtypes)
+                for sd in slot_dts:
+                    for gate in row_gates:
+                        for pane in panes:
+                            sigs.append(_sig(
+                                state + cols
+                                + [_arr(sd, ks.micro_batch), gate, pane]))
     truncated = trunc or len(sigs) > ENUM_CAP
     # true cardinality by the product formula, independent of the
     # enumeration caps (2^n mask-presence subsets for n columns)
     n_subsets_true = 1 if sharded else (1 << len(ks.columns))
-    full = ((grows + 1) * n_subsets_true * len(slot_dts)
+    full = ((grows + 1) * len(rungs) * n_subsets_true * len(slot_dts)
             * len(row_gates) * len(panes))
     return SiteCert(op, rule, "_derive_fold",
                     {"base_capacity": ks.base_capacity, "grows": grows,
@@ -342,6 +381,7 @@ def _derive_fold(ks: KernelShape, op: str, rule: Optional[str],
                      "sharded": sharded, "lead_rules": ks.lead_rules,
                      "col_dtypes": dict(ks.col_dtypes),
                      "touch": ks.touch,
+                     "os_bufs": ks.os_bufs, "os_rung": ks.os_rung,
                      "comps": {c: list(v) for c, v in ks.comps.items()}},
                     frozenset(sigs[:ENUM_CAP]), deriv, truncated,
                     full_count=full)
@@ -354,37 +394,49 @@ def _derive_boundary(ks: KernelShape, op: str, rule: Optional[str],
                   every caller passes panes=None on the static route;
                   subsets go through the traced-mask twin),
     pane_mask   — traced bool[n_panes] (finalize_dyn / hh_finalize),
+    pane_mask_scalar — both, the mask then a pane (orderstat_select:
+                  the pane whose held rows it counts),
     pane_scalar — scalar pane index (reset_pane)."""
     sigs: List[str] = []
     deriv = [f"capacity ladder: {ks.base_capacity} x2^0..{grows}"]
     if ks.touch:
         deriv.append("tiered state: uint32[capacity] touch column in the "
                      "state pytree (ops/tierstore.py)")
+    rungs = _rungs(ks, grows)
+    if ks.os_bufs:
+        deriv.append(f"order-statistic buffers: rung ladder {ks.os_rung} "
+                     f"x2^0..{grows}")
     for cap in _ladder(ks.base_capacity, grows):
-        state = _state_leaves(ks.comps, ks.n_panes, cap, ks.lead_rules,
-                              touch=ks.touch)
-        if tail == "static_all":
-            sigs.append(_sig(state + ["True"] * ks.n_panes))
-        elif tail == "pane_mask":
-            sigs.append(_sig(state + [_arr("bool", ks.n_panes)]))
-        elif tail == "pane_scalar":
-            sigs.append(_sig(state + [_arr("int32")]))
-        else:  # pragma: no cover - derivation bug
-            raise ValueError(f"unknown boundary tail {tail!r}")
+        for rung in rungs:
+            state = _state_leaves(ks.comps, ks.n_panes, cap, ks.lead_rules,
+                                  touch=ks.touch, os_bufs=ks.os_bufs,
+                                  rung=rung)
+            if tail == "static_all":
+                sigs.append(_sig(state + ["True"] * ks.n_panes))
+            elif tail == "pane_mask":
+                sigs.append(_sig(state + [_arr("bool", ks.n_panes)]))
+            elif tail == "pane_mask_scalar":
+                sigs.append(_sig(state + [_arr("bool", ks.n_panes),
+                                          _arr("int32")]))
+            elif tail == "pane_scalar":
+                sigs.append(_sig(state + [_arr("int32")]))
+            else:  # pragma: no cover - derivation bug
+                raise ValueError(f"unknown boundary tail {tail!r}")
     if tail == "static_all":
         deriv.append("pane mask: static all-True tuple (subset emits ride "
                      "the traced-mask twin; nodes_fused/panestore pass "
                      "panes=None here)")
-    elif tail == "pane_mask":
+    elif tail in ("pane_mask", "pane_mask_scalar"):
         deriv.append(f"pane mask: traced bool[{ks.n_panes}] — one "
                      "executable per capacity, any pane subset")
     return SiteCert(op, rule, "_derive_boundary",
                     {"base_capacity": ks.base_capacity, "grows": grows,
                      "n_panes": ks.n_panes, "tail": tail,
                      "lead_rules": ks.lead_rules, "touch": ks.touch,
+                     "os_bufs": ks.os_bufs, "os_rung": ks.os_rung,
                      "comps": {c: list(v) for c, v in ks.comps.items()}},
                     frozenset(sigs), deriv, len(sigs) > ENUM_CAP,
-                    full_count=grows + 1)
+                    full_count=(grows + 1) * len(rungs))
 
 
 def _ring_leaves(comps: Dict[str, Tuple[int, int]], capacity: int,
@@ -674,6 +726,9 @@ def _groupby_certs(kernel, prefix: str, rule: Optional[str]
     if ks.host_finalize_only:
         certs.append(_derive_boundary(ks, f"{prefix}.hh_finalize", rule,
                                       "pane_mask"))
+    if ks.os_bufs:
+        certs.append(_derive_boundary(ks, f"{prefix}.orderstat_select",
+                                      rule, "pane_mask_scalar"))
     return certs
 
 
@@ -782,6 +837,8 @@ SITE_DERIVATIONS: Dict[str, str] = {
     "groupby.components_dyn": "_derive_boundary(pane_mask)",
     "groupby.reset_pane": "_derive_boundary(pane_scalar)",
     "groupby.hh_finalize": "_derive_boundary(pane_mask)",
+    "groupby.orderstat_select": "_derive_boundary(pane_mask_scalar, "
+                                "os_bufs)",
     "multirule.fold": "_derive_fold(lead_rules)",
     "multirule.finalize": "_derive_boundary(static_all)",
     "multirule.reset_pane": "_derive_boundary(pane_scalar)",
@@ -1016,6 +1073,9 @@ def estimate_plan_certs(plan, n_panes: int, micro_batch: int,
     if ks.host_finalize_only:
         certs.append(_derive_boundary(ks, "groupby.hh_finalize", None,
                                       "pane_mask", grows=0))
+    elif ks.os_bufs:
+        certs.append(_derive_boundary(ks, "groupby.orderstat_select", None,
+                                      "pane_mask_scalar", grows=0))
     elif sliding_ring_slots > 0:
         certs.append(_derive_boundary(ks, "groupby.components_dyn", None,
                                       "pane_mask", grows=0))

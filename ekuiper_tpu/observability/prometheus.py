@@ -278,6 +278,27 @@ def render(rule_registry) -> str:
             out.append(
                 f'kuiper_fold_int_exact_rows_total{{rule="{_esc(rule_id)}",'
                 f'op="{_esc(node.name)}"}} {node.fold_int_exact_rows}')
+    # exact order statistics (ops/orderstat.py): rows the row buffers
+    # held as the device counted them, the buffers' rung (rows a pane
+    # holds) and its climbs
+    for family, mtype, attr, help_txt in (
+            ("kuiper_orderstat_rows_total", "counter", "orderstat_rows",
+             "rows a window node's order-statistic row buffers held under "
+             "a live slot, counted by the device's selection as each pane "
+             "closed (NULL, NaN or filtered rows are not taken)"),
+            ("kuiper_orderstat_buffer_rows", "gauge",
+             "orderstat_buffer_rows",
+             "rung of a window node's order-statistic row buffers: rows "
+             "one pane holds"),
+            ("kuiper_orderstat_grow_total", "counter", "orderstat_grows",
+             "rung climbs of a window node's order-statistic row buffers "
+             "(a power-of-two ladder, never a dropped row)")):
+        _family(out, family, mtype, help_txt)
+        for rule_id, node in rows:
+            if getattr(node, "_orderstat", False):
+                out.append(
+                    f'{family}{{rule="{_esc(rule_id)}",'
+                    f'op="{_esc(node.name)}"}} {getattr(node, attr)}')
     _family(out, "kuiper_agg_int_fallback_total", "counter",
             "integer rows past 2^24 that reached a float32 sum/avg/min/max,"
             " by reason: not_declared_bigint (schemaless or FLOAT column), "

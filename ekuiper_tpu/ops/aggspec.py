@@ -58,6 +58,12 @@ EXACT_INT_AGGS: Dict[str, Set[str]] = {
     "max": {"n", "imx"},
 }
 
+#: exact order statistics (eKuiper's median / percentile_cont /
+#: percentile_disc): no mergeable per-key partial, but a per-pane row
+#: buffer of (slot, value) that the boundary sorts on the device
+#: (ops/orderstat.py); a spec of these kinds has no component
+ORDERSTAT_AGGS = frozenset({"median", "percentile_cont", "percentile_disc"})
+
 ALL_COMPONENTS = ("n", "s1", "s2", "mn", "mx")
 #: the exact-integer components, with a word axis (4 for i1, 2 for imn /
 #: imx): leading in the device state (groupby.state_shape), trailing in
@@ -383,8 +389,12 @@ class AggSpec:
     #: `reason` of kuiper_agg_int_fallback_total when integer rows past
     #: 2^24 reach it); "" for every other spec
     float_reason: str = ""
-    frac: float = 0.5  # percentile_approx quantile (2nd literal arg)
+    frac: float = 0.5  # percentile_approx / order-statistic fraction
     topk: int = 3  # heavy_hitters k (2nd literal arg)
+    #: order statistics (ORDERSTAT_AGGS): the row buffer this spec reads —
+    #: specs of one argument and FILTER share one (KernelPlan.os_bufs);
+    #: -1 for every other spec
+    os_buf: int = -1
     # numpy twins of arg/filter, used by the latency-hiding tail shadow
     # (ops/prefinalize.py); None when the expr only compiles for device
     arg_host: Optional[CompiledExpr] = None
@@ -419,6 +429,9 @@ class KernelPlan:
     #: `count(*) FILTER(WHERE <rule predicate>)` activity spec a lifted
     #: member reads its group-existence from (None = the global `act`)
     act_idx: Optional[int] = None
+    #: order-statistic row buffers: one per distinct (argument, FILTER)
+    #: of the plan's ORDERSTAT_AGGS specs, in first-use order
+    os_bufs: Tuple[str, ...] = ()
 
     @property
     def has_exact(self) -> bool:
@@ -426,9 +439,18 @@ class KernelPlan:
         return any(s.exact for s in self.specs)
 
     @property
+    def has_orderstat(self) -> bool:
+        """Some spec is an exact order statistic (ORDERSTAT_AGGS)."""
+        return bool(self.os_bufs)
+
+    @property
     def host_foldable(self) -> bool:
         """True when every closure has a numpy twin, so a tail of rows can be
-        folded on host by the pre-finalize emit pipeline."""
+        folded on host by the pre-finalize emit pipeline. An order
+        statistic never is: its rows live in device buffers the host
+        shadow does not mirror."""
+        if self.has_orderstat:
+            return False
         if self.filter is not None and self.filter_host is None:
             return False
         for s in self.specs:
@@ -508,7 +530,8 @@ def _bigint_compare(expr: ast.Expr, int_columns: Set[str]) -> Optional[str]:
 
 
 def _float_reason(arg: ast.Expr, has_hh: bool) -> str:
-    """Why a sum/avg/min/max over `arg` is not given exact partials."""
+    """Why a sum/avg/min/max over `arg` is not given exact partials, or
+    an order statistic over it reads float32 values."""
     if has_hh:
         return "heavy_hitters"
     if isinstance(arg, ast.FieldRef):
@@ -583,9 +606,12 @@ def extract_kernel_plan(
     columns: Set[str] = set()
     for call in calls:
         kind = call.name[4:] if call.name.startswith("inc_") else call.name
-        if call.name not in DEVICE_AGGS:
+        if call.name not in DEVICE_AGGS and call.name not in ORDERSTAT_AGGS:
             return None
         if call.partition or call.when is not None:
+            return None
+        if call.name in ORDERSTAT_AGGS and (
+                not call.args or isinstance(call.args[0], ast.Wildcard)):
             return None
         frac = 0.5
         topk = 3
@@ -608,7 +634,8 @@ def extract_kernel_plan(
                 ):
                     return None
                 topk = int(call.args[1].val)
-            elif call.name == "percentile_approx":
+            elif call.name in ("percentile_approx", "percentile_cont",
+                               "percentile_disc"):
                 if len(call.args) != 2 or not isinstance(
                     call.args[1], (ast.NumberLiteral, ast.IntegerLiteral)
                 ):
@@ -625,6 +652,11 @@ def extract_kernel_plan(
                 exact = (isinstance(a0, ast.FieldRef)
                          and a0.name in int_columns and not has_hh)
                 float_reason = "" if exact else _float_reason(a0, has_hh)
+            elif kind in ORDERSTAT_AGGS:
+                # the row buffers hold float32 (a declared BIGINT argument
+                # keeps the rule on the host operator: planner.py
+                # orderstat_host_reason)
+                float_reason = _float_reason(a0, has_hh)
             if exact:
                 icol = I64_COL_PREFIX + a0.name
                 arg_ce = CompiledExpr(
@@ -682,8 +714,9 @@ def extract_kernel_plan(
             AggSpec(
                 call=call,
                 kind="hll" if kind == "distinct_count_approx" else kind,
-                components=set(EXACT_INT_AGGS[kind] if exact
-                               else DEVICE_AGGS[call.name]),
+                components=(set() if call.name in ORDERSTAT_AGGS
+                            else set(EXACT_INT_AGGS[kind] if exact
+                                     else DEVICE_AGGS[call.name])),
                 arg=arg_ce,
                 filter=filter_ce,
                 int_input=exact,
@@ -707,11 +740,21 @@ def extract_kernel_plan(
             stmt.condition, mode="host", want="bool", anchor_ms=anchor_ms,
             str_seed=str_seed)
         columns |= where_ce.columns
+    # order statistics of one argument under one FILTER read one buffer
+    os_bufs: List[str] = []
+    for spec in specs:
+        if spec.kind in ORDERSTAT_AGGS:
+            key = (_expr_key(spec.call.args[0])
+                   + "|f:" + _expr_key(spec.call.filter))
+            if key not in os_bufs:
+                os_bufs.append(key)
+            spec.os_buf = os_bufs.index(key)
     return KernelPlan(
         specs=specs, filter=where_ce, columns=columns,
         filter_host=where_host, col_dtypes=col_dtypes,
         derived=tuple(sorted(derived.values(), key=lambda d: d.name)),
-        expr_tag=expr_ir.ir_hash(ir_keys) if ir_keys else "")
+        expr_tag=expr_ir.ir_hash(ir_keys) if ir_keys else "",
+        os_bufs=tuple(os_bufs))
 
 
 def conj(a: Optional[ast.Expr], b: Optional[ast.Expr]) -> Optional[ast.Expr]:

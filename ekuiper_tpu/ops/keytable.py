@@ -6,7 +6,9 @@ state lives in dense device arrays, so keys must become stable integer slots.
 The key table is the host-side dictionary. Which encode serves a column is
 decided by the column itself: an object column of str/None keys takes one
 native pass over a byte-keyed table (`native_str`), an integer column one
-native pass over an int64 table (`native_int`), other hashable keys a C-level dict map per batch
+native pass over an int64 table (`native_int`), a multi-column key of
+integer columns per-column int64 tables and one int64 table over their
+packed ids (`int_multi`), other hashable keys a C-level dict map per batch
 (`hashed`), and float / fixed-width unicode / unhashable keys — or any
 column when the native module is missing — a sort-based np.unique path
 (`sorted`). The Python dict and the reverse list (emitted slots back to key
@@ -23,6 +25,9 @@ from ..utils.infra import logger
 
 
 ENCODE_PATHS = ("native_int", "native_str", "hashed", "sorted")
+#: the multi-column integer path's label: counted from its first row on,
+#: so a single-column table's tally keeps the four paths above
+INT_MULTI = "int_multi"
 _I64 = np.iinfo(np.int64)
 
 
@@ -62,6 +67,15 @@ class KeyTable:
         self._itab = None
         self._int_n = 0  # python keys already looked at for the int tab
         self._int_ok = True
+        # multi-column integer keys (`int_multi`): a KeyTable a column
+        # gives each value a small id, the ids pack into one int64, and an
+        # int64 table maps the packed code to the tuple's slot. The tuples
+        # stay the keys of `_ids` / `_keys`; tuples that came by the dict
+        # path feed the packed table lazily (`_multi_catch_up`)
+        self._parts: Optional[List["KeyTable"]] = None
+        self._mtab = None
+        self._multi_n = 0
+        self._multi_ok = True
         self.encode_rows: Dict[str, int] = dict.fromkeys(ENCODE_PATHS, 0)
         # tiered key state (ops/tierstore.py): retired (demoted) slots
         # recycle through this free list instead of forcing capacity
@@ -318,7 +332,7 @@ class KeyTable:
         represent holes, so retirement pins this table to the Python
         path. Callers must pass the keys currently holding the slots
         (the tier manager re-validates via decode before demoting)."""
-        self._native_ok = self._int_ok = False
+        self._native_ok = self._int_ok = self._multi_ok = False
         for slot, key in zip(slots, keys):
             if self._keys[slot] != key:
                 continue  # raced a re-encode; leave the slot live
@@ -393,13 +407,120 @@ class KeyTable:
             grew = True
         return uids[inverse].astype(np.int32), grew
 
+    @staticmethod
+    def _multi_pack(ids: List[np.ndarray], bits: int) -> np.ndarray:
+        """One int64 code a row: each column's id in `bits` bits."""
+        code = ids[0].astype(np.int64)
+        for x in ids[1:]:
+            code = (code << bits) | x.astype(np.int64)
+        return code
+
+    def _multi_catch_up(self, mod, d: int, bits: int) -> bool:
+        """Feed the packed table the all-int tuples that arrived by the
+        dict path, under the slots they hold. Tuples holding a str (a nil
+        key's "") are skipped: no integer row equals them. False pins the
+        table to the dict path: a key the dict may alias to an int tuple
+        (1.0, True), or a column past its id budget."""
+        start = self._multi_n
+        rows, slots = [], []
+        for slot, k in enumerate(self._keys[start:], start):
+            if type(k) is tuple and len(k) == d and \
+                    all(type(v) is int for v in k):
+                if all(_I64.min <= v <= _I64.max for v in k):
+                    rows.append(k)
+                    slots.append(slot)
+            elif type(k) is not str and not (
+                    type(k) is tuple
+                    and all(type(v) in (int, str) for v in k)):
+                self._multi_ok = False
+                return False
+        if rows:
+            cols = np.array(rows, dtype=np.int64).T
+            ids = [part.encode_column(np.ascontiguousarray(c))[0]
+                   for part, c in zip(self._parts, cols)]
+            if any(p.n_keys > (1 << bits) for p in self._parts):
+                self._multi_ok = False
+                return False
+            mod.keytab_load_i64(self._mtab, self._multi_pack(ids, bits),
+                                np.array(slots, dtype=np.int32))
+        self._multi_n = len(self._keys)
+        return True
+
+    def _encode_int_multi(self, cols: Sequence[np.ndarray]
+                          ) -> Optional[Tuple[np.ndarray, bool]]:
+        """Slots of a multi-column key whose every column is an integer
+        column, with no per-row Python object: each column's values take
+        ids in a table of their own, the ids pack into one int64 code, one
+        native pass maps codes to slots; new codes decode to the tuples
+        the dict path would hold, in first-seen order. None where it
+        cannot serve — the caller takes the dict path."""
+        d = len(cols)
+        bits = 63 // d  # of the packed code, each column's id
+        if not self._multi_ok or bits < 8 or self._free or not len(cols[0]):
+            return None
+        for c in cols:
+            if c.dtype.kind not in "iu" or (
+                    c.dtype == np.uint64 and int(c.max()) > _I64.max):
+                return None
+        mod = _native_keytab_module("keytab_encode_i64")
+        if mod is None:
+            return None
+        try:
+            if self._mtab is None:
+                self._mtab = mod.keytab_i64_new()
+                self._parts = [KeyTable(1024) for _ in range(d)]
+            if self._multi_n < len(self._keys) and \
+                    not self._multi_catch_up(mod, d, bits):
+                return None
+            ids = [part.encode_column(c)[0]
+                   for part, c in zip(self._parts, cols)]
+            if any(p.n_keys > (1 << bits) for p in self._parts):
+                self._multi_ok = False
+                return None
+            slots, appendix = mod.keytab_encode_i64(
+                self._mtab, self._multi_pack(ids, bits), len(self._keys))
+        except Exception as exc:
+            # the packed table was NOT mutated; one decision, not a retry
+            self._multi_ok = False
+            logger.warning("key table: multi-column int encode failed "
+                           "(%r); this table stays on the dict path", exc)
+            return None
+        if len(appendix):
+            mask = (1 << bits) - 1
+            shifts = [bits * (d - 1 - i) for i in range(d)]
+            parts = []
+            for part, sh in zip(self._parts, shifts):
+                keys = part._keys  # once a column, not once a new key
+                parts.append([keys[j]
+                              for j in ((appendix >> sh) & mask).tolist()])
+            new = list(zip(*parts))
+            start = len(self._keys)
+            self._ids.update(zip(new, range(start, start + len(new))))
+            self._keys.extend(new)
+            if self.track_new:
+                self._new_log.extend(
+                    zip(new, range(start, start + len(new))))
+        self._multi_n = len(self._keys)
+        grew = False
+        while len(self._keys) > self.capacity:
+            self.capacity *= 2
+            grew = True
+        return slots, grew
+
     def encode_multi(self, cols: Sequence[np.ndarray]) -> Tuple[np.ndarray, bool]:
-        """Composite key: tuple of column values per row. tolist() converts
-        numpy scalars to Python values, zip builds the tuples at C speed, and
-        the hashed path aliases raw (None-bearing) tuples to their normalized
-        slot — so steady state is still one dict lookup per row."""
+        """Composite key: tuple of column values per row. All-integer
+        columns take `int_multi` (no per-row object); otherwise tolist()
+        converts numpy scalars to Python values, zip builds the tuples at
+        C speed, and the hashed path aliases raw (None-bearing) tuples to
+        their normalized slot — so steady state is still one dict lookup
+        per row."""
         if len(cols) == 1:
             return self.encode_column(cols[0])
+        out = self._encode_int_multi(cols)
+        if out is not None:
+            self.encode_rows[INT_MULTI] = (
+                self.encode_rows.get(INT_MULTI, 0) + len(cols[0]))
+            return out
         self.encode_rows["hashed"] += len(cols[0])
         try:
             combos = list(zip(*(c.tolist() for c in cols)))
@@ -463,9 +584,10 @@ class KeyTable:
         self._keys.clear()
         # drop the native mirror; the next native encode re-feeds from
         # _keys (empty now), so both sides restart in lockstep
-        self._ntab = self._itab = None
-        self._native_n = self._int_n = 0
-        self._native_ok = self._int_ok = True
+        self._ntab = self._itab = self._mtab = None
+        self._parts = None
+        self._native_n = self._int_n = self._multi_n = 0
+        self._native_ok = self._int_ok = self._multi_ok = True
         self._free.clear()
         self._new_log.clear()
 
@@ -480,7 +602,7 @@ class KeyTable:
             self._keys.append(k)
             if k is None:
                 self._free.append(i)
-                self._native_ok = self._int_ok = False
+                self._native_ok = self._int_ok = self._multi_ok = False
             else:
                 self._ids[k] = i
         while len(self._keys) > self.capacity:

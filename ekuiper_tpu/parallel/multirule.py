@@ -115,6 +115,9 @@ def build_rule_batch(
         # hh finalize is a host-side top-k recovery, not part of the vmapped
         # device finalize program — such rules run as individual fused nodes
         raise ValueError("heavy_hitters rules do not batch")
+    if plan.has_orderstat:
+        # row buffers recycle with one node's panes (ops/orderstat.py)
+        raise ValueError("order-statistic rules do not batch")
     n_params = len(param_rows[0])
     param_names = [f"{PARAM_PREFIX}{i}" for i in range(n_params)]
     # params are injected at fold time, not uploaded as batch columns

@@ -154,6 +154,9 @@ def mesh_request(opts: RuleOptionConfig, plan=None) -> Dict[str, Any]:
     if plan is not None and getattr(plan, "has_exact", False):
         return {"mode": "single-chip", "cfg": None, "source": source,
                 "reason": EXACT_INT_SINGLE_CHIP}
+    if plan is not None and getattr(plan, "has_orderstat", False):
+        return {"mode": "single-chip", "cfg": None, "source": source,
+                "reason": ORDERSTAT_DECLINES["mesh"]}
     return {"mode": "sharded", "cfg": cfg, "source": source,
             "reason": "key-range-partitioned GROUP BY state across the "
                       "device mesh"}
@@ -164,6 +167,63 @@ def mesh_request(opts: RuleOptionConfig, plan=None) -> Dict[str, Any]:
 EXACT_INT_SINGLE_CHIP = ("exact BIGINT aggregates (int64 limb state) are "
                          "single-chip — the sharded kernel keeps float32 "
                          "partials")
+
+
+#: the placements that cannot carry an order statistic's row buffers
+#: (ops/orderstat.py), by name, and why: the explain "order_stats"
+#: section lists them for a device plan; a rule whose own placement is one
+#: of the first kind goes to the host operator (reason
+#: `orderstat_placement`), the others only lose the feature
+ORDERSTAT_DECLINES = {
+    "mesh": "exact order statistics keep their row buffers on one chip — "
+            "the sharded kernel merges per-key partials only",
+    "shared_fold": "order-statistic row buffers are rule-local — the "
+                   "shared pane store merges per-key partials only",
+    "tier_store": "the cold tier packs per-key partials; a row buffer has "
+                  "no per-key row to spill",
+    "sliding_ring": "the sliding ring combines per-key partials per "
+                    "trigger; a row buffer has none",
+    "pre_issue": "the pre-issued finalize's host shadow mirrors per-key "
+                 "partials, not the rows a selection needs",
+    "qos_checkpoint": "a qos >= 1 checkpoint does not carry the row "
+                      "buffers",
+    "window": "row buffers recycle with the panes of a processing-time "
+              "tumbling or hopping window",
+    "heavy_hitters": "a heavy-hitters boundary assembles its own finalize",
+    "exact_bigint": "exact BIGINT partials finish in their own int64 tail",
+    "bigint": "the row buffers hold float32 values, which round a BIGINT "
+              "past 2^24 — the host operator sorts the integers exactly",
+}
+
+
+def orderstat_host_reason(stmt: ast.SelectStatement, opts, plan,
+                          int_columns: Optional[Set[str]] = None
+                          ) -> Optional[str]:
+    """The placement that keeps a plan with order statistics off the
+    device path (an ORDERSTAT_DECLINES key), or None where it may take it:
+    processing-time TUMBLING / HOPPING on one chip, qos 0, with no
+    heavy_hitters or exact BIGINT spec beside it, over no column the
+    stream declares BIGINT (`int_columns`)."""
+    w = stmt.window
+    if w.window_type == ast.WindowType.SLIDING_WINDOW:
+        return "sliding_ring"
+    if opts.is_event_time or w.window_type not in (
+            ast.WindowType.TUMBLING_WINDOW, ast.WindowType.HOPPING_WINDOW):
+        return "window"
+    if (opts.plan_optimize_strategy or {}).get("mesh"):
+        return "mesh"
+    if opts.qos > 0:
+        return "qos_checkpoint"
+    if any(s.kind == "heavy_hitters" for s in plan.specs):
+        return "heavy_hitters"
+    if plan.has_exact:
+        return "exact_bigint"
+    if int_columns and any(
+            isinstance(node, ast.FieldRef) and node.name in int_columns
+            for s in plan.specs if s.os_buf >= 0
+            for node in ast.walk(s.call.args[0])):
+        return "bigint"
+    return None
 
 
 def merged_options(rule: RuleDef) -> RuleOptionConfig:
@@ -442,6 +502,17 @@ def device_path_eligible(
             "exact BIGINT aggregates are not carried by a sliding ring or "
             "an explicit mesh", reason="bigint_exact_placement"))
         return None
+    if plan is not None and plan.has_orderstat:
+        where = orderstat_host_reason(stmt, opts, plan, int_columns)
+        if where is not None:
+            # the host window operator keeps the rows and is exact
+            from ..ops.aggspec import _note_fallback
+            from ..sql.expr_ir import NotVectorizable
+
+            _note_fallback("placement", None, NotVectorizable(
+                f"{where}: {ORDERSTAT_DECLINES[where]}",
+                reason="orderstat_placement"))
+            return None
     if plan is not None and any(
         s.kind == "heavy_hitters" for s in plan.specs
     ):
@@ -1279,8 +1350,9 @@ def explain(rule: RuleDef, store) -> Dict[str, Any]:
         stmt, opts, declared_int_columns(stmt, store))
     from ..ops.aggspec import take_expr_fallbacks
 
-    int_section = _explain_int_aggregates(kernel_plan,
-                                          take_expr_fallbacks())
+    notes = take_expr_fallbacks()
+    int_section = _explain_int_aggregates(kernel_plan, notes)
+    os_section = _explain_order_stats(kernel_plan, notes, opts)
     sharing_info = None
     if kernel_plan is not None and len(stmt.sources) == 1 and not stmt.joins:
         from . import sharing as sharing_mod
@@ -1326,6 +1398,8 @@ def explain(rule: RuleDef, store) -> Dict[str, Any]:
     out: Dict[str, Any] = {"path": path, "operators": ops}
     if int_section is not None:
         out["int_aggregates"] = int_section
+    if os_section is not None:
+        out["order_stats"] = os_section
     if sharing_info is not None:
         out["sharing"] = sharing_info
     # shards section: the placement decision this rule's plan would make
@@ -1490,6 +1564,40 @@ def _explain_int_aggregates(kernel_plan, notes) -> Optional[Dict[str, Any]]:
                                 "kuiper_agg_int_fallback_total{reason}")
         aggs.append(entry)
     return {"aggregates": aggs} if aggs else None
+
+
+def _explain_order_stats(kernel_plan, notes, opts
+                         ) -> Optional[Dict[str, Any]]:
+    """The "order_stats" section of explain: for a device plan its row
+    buffers (argument, FILTER, the order statistics that read each, the
+    first rung) and every placement that cannot carry them, by name; or
+    the placement that sent the rule to the host operator. None where the
+    rule has no order statistic."""
+    from ..ops.aggspec import _call_key, _expr_key
+    from ..ops.orderstat import first_rung
+
+    host = [{"placement": n["detail"].split(":", 1)[0],
+             "reason": n["reason"], "detail": n["detail"]}
+            for n in notes if n["reason"] == "orderstat_placement"]
+    if kernel_plan is None:
+        return {"path": "host", "declined": host} if host else None
+    if not kernel_plan.has_orderstat:
+        return None
+    bufs = []
+    for b in range(len(kernel_plan.os_bufs)):
+        specs = [s for s in kernel_plan.specs if s.os_buf == b]
+        bufs.append({
+            "column": _expr_key(specs[0].call.args[0]),
+            "filter": _expr_key(specs[0].call.filter),
+            "fractions": [{"agg": _call_key(s.call), "kind": s.kind,
+                           "frac": s.frac} for s in specs],
+            "rung": first_rung(opts.micro_batch_rows),
+        })
+    return {"path": "device", "buffers": bufs,
+            "declined": [{"placement": k, "reason": v}
+                         for k, v in ORDERSTAT_DECLINES.items()
+                         if k not in ("heavy_hitters", "exact_bigint",
+                                      "window", "bigint")]}
 
 
 def _explain_source_columns(stmt, opts, store) -> Dict[str, Any]:

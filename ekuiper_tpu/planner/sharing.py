@@ -247,6 +247,10 @@ def decide(stmt: ast.SelectStatement, opts, plan: KernelPlan,
         return no("heavy_hitters state is node-local (value dictionary)")
     if getattr(plan, "has_exact", False):
         return no("exact BIGINT partials (int64 limbs) are rule-local")
+    if getattr(plan, "has_orderstat", False):
+        from .planner import ORDERSTAT_DECLINES
+
+        return no(ORDERSTAT_DECLINES["shared_fold"])
     if not has_direct_emit:
         return no("post-agg tail is not vectorizable (no direct emit)")
     length, interval = _window_ms(w)

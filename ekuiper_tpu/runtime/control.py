@@ -231,7 +231,8 @@ def _tier_price_slots(price: Dict[str, Any], plan, stmt, opts) -> int:
                 or w.window_type not in (_ast.WindowType.TUMBLING_WINDOW,
                                          _ast.WindowType.HOPPING_WINDOW,
                                          _ast.WindowType.SLIDING_WINDOW)
-                or any(s.kind == "heavy_hitters" for s in plan.specs)):
+                or any(s.kind == "heavy_hitters" for s in plan.specs)
+                or plan.has_orderstat):
             return 0
         # the SAME pane count the node derives — a hopping rule's
         # per-key state is n_panes times wider, and pricing with 1 pane

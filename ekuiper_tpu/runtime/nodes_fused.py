@@ -308,6 +308,7 @@ class FusedWindowAggNode(Node):
         self._tier_layout = None
         if tier_budget_mb and mesh is None and not self._hh_cols and \
                 not getattr(plan, "has_exact", False) and \
+                not getattr(plan, "has_orderstat", False) and \
                 self.wt in (ast.WindowType.TUMBLING_WINDOW,
                             ast.WindowType.HOPPING_WINDOW,
                             ast.WindowType.SLIDING_WINDOW):
@@ -389,6 +390,17 @@ class FusedWindowAggNode(Node):
         # prefinalize.py HostShadow.fold; kuiper_shadow_fold_compact_rows_
         # total): once a shadow, as stage shadow_fold counts its rows
         self.shadow_fold_compact_rows = 0
+        # exact order statistics (ops/orderstat.py): the rows the buffers
+        # held in each pane as it closed, as the device's selection counted
+        # them (kuiper_orderstat_rows_total; the emit worker writes it),
+        # rung climbs (kuiper_orderstat_grow_total), and each pane's fill
+        # as the host knows it without a sync — the fold appends every row
+        # of a micro-batch, so a pane's fill is the rows folded into it
+        # since its reset (the folding thread writes these two)
+        self._orderstat = bool(getattr(plan, "has_orderstat", False))
+        self.orderstat_rows = 0
+        self.orderstat_grows = 0
+        self._os_fill = [0] * int(self.n_panes)
         # latency-hiding emit: pre-issued device finalize + host tail shadow.
         # Only for timer-driven windows (boundary known in advance), plans
         # whose expressions have numpy twins, and non-collective kernels.
@@ -441,6 +453,12 @@ class FusedWindowAggNode(Node):
             and self.gb.supports_prefinalize
             and prefinalize_lead_ms > 0
         )
+        # order-statistic boundaries too (planner.py orderstat_host_reason
+        # admits processing-time tumbling and hopping windows only): the
+        # device selection is dispatched on the pre-reset snapshot, the
+        # worker waits for it (stage orderstat_select) and finishes the
+        # ranks on the host
+        self._async_os = self._orderstat
         # vmapped rule-group boundaries (MultiRuleFusedNode) also emit
         # asynchronously: one (R, S+1, keys) transfer per family is MBs,
         # and a sync fetch at the boundary stalls every rider's fold stream
@@ -1114,6 +1132,8 @@ class FusedWindowAggNode(Node):
                 self.state = self.tier.admit(self.state)
             dev = self._shared_device_inputs(sub, cols, valid, slots)
         with self.stats.stage("fold", sub.n):
+            if self._orderstat:
+                self._os_reserve(pane_arg, sub.n)
             if dev is not None:
                 # shared uploads: device columns/slots computed once
                 # serve every fan-out consumer; host copies still feed
@@ -1130,6 +1150,8 @@ class FusedWindowAggNode(Node):
                                           h2d=self._fold_h2d)
         if self._exact:
             self.fold_int_exact_rows += sub.n
+        if self._orderstat:
+            self._os_fill[pane_arg] += sub.n
         if hasattr(self.gb, "note_rows"):
             # per-shard accounting (kuiper_shard_*): the kernel counts
             # host slot vectors itself; the prep path hands it DEVICE
@@ -1147,6 +1169,29 @@ class FusedWindowAggNode(Node):
                     self.shadow_fold_compact_rows += shadow.fold(
                         cols, slots, valid)
         return sub.n
+
+    def _os_reserve(self, pane: int, rows: int) -> None:
+        """Climb the order-statistic buffers' rung before `rows` more rows
+        into `pane` would not fit (ops/orderstat.py rung_for): a row is
+        never dropped. The buffers grow on the device, every pane at once."""
+        from ..ops import orderstat
+
+        rung = self.state["osv"].shape[-1]
+        need = orderstat.rung_for(self._os_fill[pane], rows, rung,
+                                  self.gb.micro_batch)
+        if need > rung:
+            self.state = orderstat.grow_leaves(self.state, need)
+            self.orderstat_grows += 1
+
+    @property
+    def orderstat_buffer_rows(self) -> int:
+        """The order-statistic buffers' current rung: rows a pane holds
+        (kuiper_orderstat_buffer_rows)."""
+        if self.state is not None and "osv" in self.state:
+            return int(self.state["osv"].shape[-1])
+        from ..ops.orderstat import first_rung
+
+        return first_rung(self.gb.micro_batch)
 
     # ------------------------------------------------------------ event time
     def _fold_event(self, sub: ColumnBatch) -> int:
@@ -1471,6 +1516,18 @@ class FusedWindowAggNode(Node):
             "hh", lambda: self.gb._hh_fin(
                 self.state, np.ones(self.gb.n_panes, dtype=np.bool_)), wr)
 
+    def _emit_os_async(self, wr: WindowRange) -> None:
+        """Order-statistic boundary: dispatch the device selection on the
+        immutable state — it also counts the rows the closing pane's
+        buffers hold — and hand delivery to the worker."""
+        if self.kt.n_keys == 0:
+            self.last_emit_info = None
+            return
+        self._emit_async(
+            "os", lambda: self.gb._os_sel(
+                self.state, np.ones(self.gb.n_panes, dtype=np.bool_),
+                self.gb._pane_arg(self.cur_pane)), wr)
+
     def _keys_snapshot(self):
         """Slot->key decode snapshot for a DEFERRED delivery: tiered
         rules retire/recycle slots at boundaries (ops/tierstore.py), so
@@ -1606,6 +1663,22 @@ class FusedWindowAggNode(Node):
                                                           n_keys)
             self.last_emit_info = {"source": "device-ring",
                                    "fetch_ms": fetch_ms}
+            return self._emit_active(outs, act, wr)
+        if kind == "os":
+            # the selection's dispatch → landed is a stage of its own
+            # inside `emit` (one call a boundary); the ranks, midpoints
+            # and interpolations on the host are the `merge` span
+            with self.stats.stage("orderstat_select", n_keys, within="emit",
+                                  since_ns=int(t_issue * 1e9)):
+                # kuiperlint: ignore[host-sync]: emit worker thread — THE intended sync point; the fold thread already dispatched and moved on
+                arr = np.asarray(payload)
+            self.last_emit_info = {
+                "source": "device-async",
+                "fetch_ms": (time.perf_counter() - t_issue) * 1000.0,
+            }
+            self.orderstat_rows += self.gb.os_closed_rows(arr)
+            with self.stats.span("merge"):
+                outs, act = self.gb.os_assemble(arr, n_keys)
             return self._emit_active(outs, act, wr)
         # heavy hitters: dispatch → landed and the host tail below are
         # stages of their own inside `emit` (counters a reader can take:
@@ -1757,6 +1830,8 @@ class FusedWindowAggNode(Node):
         self.state = self.gb.reset_pane(self.state, pane)
         if self.tier is not None:
             self.tier.note_pane_reset(pane)
+        if self._orderstat:  # the device fill went back to 0 too
+            self._os_fill[pane] = 0
 
     def _tier_boundary(self) -> None:
         """Pane-boundary tier hook (fold thread): apply the worker's
@@ -2650,6 +2725,8 @@ class FusedWindowAggNode(Node):
         wr = WindowRange(end - self.length_ms, end)
         if self._async_hh:
             self._emit_hh_async(wr)
+        elif self._async_os:
+            self._emit_os_async(wr)
         elif self._async_mr:
             self._emit_mr_async(wr)
         else:

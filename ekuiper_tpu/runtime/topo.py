@@ -251,6 +251,12 @@ class Topo:
             if getattr(n, "_exact", False):
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_fold_int_exact_rows"] = n.fold_int_exact_rows
+            # exact order statistics: rows buffered, the rung, its climbs
+            if getattr(n, "_orderstat", False):
+                for key in ("orderstat_rows", "orderstat_buffer_rows",
+                            "orderstat_grows"):
+                    out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
+                        f"_{key}"] = getattr(n, key)
             if getattr(n, "agg_int_fallback", None):
                 out[f"{n.stats.op_type}_{n.name}_{n.stats.instance}"
                     "_agg_int_fallback"] = dict(n.agg_int_fallback)

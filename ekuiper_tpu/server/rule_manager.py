@@ -369,7 +369,18 @@ class RuleRegistry:
 
     def explain(self, rule_id: str) -> Dict[str, Any]:
         rule = self.processor.get(rule_id)
-        return plan_explain(rule, self.store)
+        out = plan_explain(rule, self.store)
+        # a running rule's order-statistic buffers report their live rung
+        # (the plan gives the first one)
+        rs = self.state(rule_id)
+        topo = rs.topo if rs is not None else None
+        bufs = (out.get("order_stats") or {}).get("buffers")
+        if bufs and topo is not None:
+            for node in topo.ops:
+                if getattr(node, "_orderstat", False):
+                    for buf in bufs:
+                        buf["rung"] = node.orderstat_buffer_rows
+        return out
 
     def topo_json(self, rule_id: str) -> Dict[str, Any]:
         rs = self._get(rule_id)

@@ -141,6 +141,12 @@ def _synthetic_scrape() -> str:
             self._exact = True
             self.fold_int_exact_rows = 4096
             self.agg_int_fallback = {"not_declared_bigint": 2}
+            # kuiper_orderstat_{rows_total,buffer_rows,grow_total} render
+            # for a node with order-statistic row buffers
+            self._orderstat = True
+            self.orderstat_rows = 4096
+            self.orderstat_buffer_rows = 131072
+            self.orderstat_grows = 1
 
     class SubTopo:
         nodes = [Node("shared_src", op_type="source", pooled=True)]

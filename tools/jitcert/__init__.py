@@ -97,7 +97,15 @@ def _battery():
     tier_store = TierStore(
         tiered_gb, TierLayout(hot_slots=16, demote_batch=4,
                               scan_interval_ms=500, min_idle_scans=1))
+    # order statistics (ops/orderstat.py): the row buffers ride every
+    # groupby site's state signature on their own rung ladder, and the
+    # selection gets its own certificate
+    orderstat = plan("SELECT deviceId, median(v) AS m, "
+                     "percentile_cont(v, 0.9) AS p, count(*) AS c FROM s "
+                     "GROUP BY deviceId, HOPPINGWINDOW(ss, 2, 1)")
     kernels = {
+        "groupby_orderstat": DeviceGroupBy(orderstat, capacity=32,
+                                           n_panes=2, micro_batch=16),
         "groupby_tumbling": DeviceGroupBy(tumbling, capacity=32,
                                           n_panes=1, micro_batch=16),
         "groupby_hopping": DeviceGroupBy(hopping, capacity=32, n_panes=4,
@@ -255,6 +263,30 @@ def _drive(kernels) -> None:
         return cols, valid, slots, pane
 
     for name, gb in kernels.items():
+        if name == "groupby_orderstat":
+            # scalar panes only (processing time); a rung climb and a
+            # capacity doubling, each re-specializing inside the ladders
+            from ekuiper_tpu.ops.orderstat import grow_leaves, rung_for
+
+            state = gb.init_state()
+            cols, valid, slots, pane = feed(gb, with_masks=False,
+                                            pane_vec=False)
+            state = gb.fold(state, cols, slots, pane_idx=0)
+            cols, valid, slots, pane = feed(gb, with_masks=True,
+                                            pane_vec=False)
+            state = gb.fold(state, cols, slots, valid=valid, pane_idx=1)
+            gb.finalize(state, 8)
+            state = gb.reset_pane(state, 0)
+            rung = state["osv"].shape[-1]
+            state = grow_leaves(state, rung_for(rung, 1, rung,
+                                                gb.micro_batch))
+            state = gb.fold(state, cols, slots, valid=valid, pane_idx=1)
+            gb.finalize(state, 8)
+            state = gb.grow(state, gb.capacity * 2)
+            state = gb.fold(state, cols, slots, pane_idx=0)
+            gb.finalize(state, 8)
+            state = gb.reset_pane(state, 1)
+            continue
         if name == "tier_store":
             # demote/promote across a capacity doubling: the gather/
             # scatter re-specialization must stay inside the certified
